@@ -5,10 +5,11 @@ step (shape-unstable inputs, a Python scalar riding where a device array
 should, a blown jit cache) silently turns the 16.7x KV-cache decode win
 into compile churn. jax already knows every lowering it performs — with
 ``jax_log_compiles`` on, ``jax._src.interpreters.pxla`` logs one
-"Compiling <name> with global shapes and types [...]" record per cache
-miss, carrying the wrapped function's name and its full shape/dtype
-signature. :class:`CompileAudit` attaches a logging handler to that seam
-for the duration of a ``with`` block and aggregates:
+"Compiling jit(<name>) with global shapes and types (<avals>). Argument
+mapping: ..." record per cache miss, carrying the wrapped function's name
+and its full shape/dtype signature. :class:`CompileAudit` attaches a
+logging handler to that seam for the duration of a ``with`` block and
+aggregates:
 
 - ``counts[fn]`` — compiles per function name;
 - ``signatures[fn][sig]`` — compiles per (function, shape signature):
@@ -19,7 +20,14 @@ for the duration of a ``with`` block and aggregates:
   (raises :class:`CompileBudgetError` with the offending functions).
 
 Works on any backend and costs one logging call per COMPILE (not per
-step), so wrapping a whole bench run is free.
+step), so wrapping a whole bench run is free. The record is logged at
+LOWERING time, before the persistent compilation cache is consulted, so
+a program served from that cache still counts.
+
+The seam is jax-private, so the audit proves it can hear before it is
+trusted: ``__enter__`` compiles a uniquely named probe program and raises
+:class:`CompileAuditDeafError` if the handler did not see it — an audit
+that cannot observe compiles must never read as "``{}`` new compiles".
 
 Attribution through the pjit seams (r12): a mesh-sharded decoder
 compiles the SAME function names with the SAME dynamic shape signatures
@@ -44,6 +52,7 @@ Usage::
 
 from __future__ import annotations
 
+import itertools
 import logging
 import re
 import threading
@@ -51,8 +60,13 @@ from collections import Counter, defaultdict
 from typing import Dict, Iterable, Optional
 
 _COMPILE_RE = re.compile(
-    r"Compiling ([^\s]+) with global shapes and types (\[.*?\])\.")
+    r"Compiling (\S+) with global shapes and types (.*?)\. "
+    r"Argument mapping: ", re.DOTALL)
 _PXLA_LOGGER = "jax._src.interpreters.pxla"
+#: name prefix of the self-test program every ``CompileAudit.__enter__``
+#: compiles; never counted, whichever (possibly enclosing) audit hears it
+_PROBE_PREFIX = "compile_audit_probe_"
+_PROBE_SEQ = itertools.count()
 #: loggers that turn chatty at WARNING while jax_log_compiles is on; muted
 #: (propagate=False + NullHandler) for the audit scope so a bench run's
 #: stderr stays clean
@@ -61,6 +75,11 @@ _MUTE_LOGGERS = ("jax._src.dispatch", "jax._src.compiler")
 
 class CompileBudgetError(AssertionError):
     """An audited region compiled more than its budget allows."""
+
+
+class CompileAuditDeafError(RuntimeError):
+    """The audit's logging seam did not report its own probe compile, so
+    nothing it counted (or failed to count) can be trusted."""
 
 
 class TransferBudgetError(AssertionError):
@@ -155,7 +174,10 @@ class _CompileLogHandler(logging.Handler):
         except Exception:       # noqa: BLE001 — a logging handler must not throw
             return
         if m:
-            self._audit._record(m.group(1), m.group(2))
+            name = m.group(1)
+            if name.startswith("jit(") and name.endswith(")"):
+                name = name[4:-1]     # module name jit(f) -> audit row f
+            self._audit._record(name, m.group(2))
 
 
 class CompileAudit:
@@ -214,8 +236,31 @@ class CompileAudit:
             self.signatures[name][signature] += 1
 
     def _ignored(self, name: str) -> bool:
-        return name in self.ignore or \
+        return name in self.ignore or name.startswith(_PROBE_PREFIX) or \
             (self.ignore_internal and name.startswith("_"))
+
+    def _probe(self) -> None:
+        """Compile a program no one has compiled before and demand the
+        handler heard it: a renamed logger, a reworded record or a
+        silenced logging tree must fail HERE, not read as zero compiles
+        for the whole audited region."""
+        import jax
+        import numpy as np
+
+        def probe(x):
+            return x
+        probe.__name__ = name = f"{_PROBE_PREFIX}{next(_PROBE_SEQ)}"
+        jax.jit(probe)(np.float32(0))   # graftlint: disable=GL005
+        with self._mutex:
+            heard = self.counts.pop(name, 0)
+            self.signatures.pop(name, None)
+        if not heard:
+            raise CompileAuditDeafError(
+                f"CompileAudit cannot observe jax's compile log: the probe "
+                f"program {name!r} compiled but no 'Compiling ... with "
+                f"global shapes and types' record reached the "
+                f"{_PXLA_LOGGER!r} handler (logger disabled, or the "
+                "record format changed with this jax version)")
 
     def __enter__(self) -> "CompileAudit":
         import jax
@@ -237,6 +282,11 @@ class CompileAudit:
         self._prev_log_compiles = bool(getattr(jax.config,
                                                "jax_log_compiles", False))
         jax.config.update("jax_log_compiles", True)
+        try:
+            self._probe()
+        except BaseException as e:
+            self.__exit__(type(e), e, e.__traceback__)
+            raise
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:

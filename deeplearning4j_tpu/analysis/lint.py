@@ -78,13 +78,13 @@ PER_FILE_RULES = frozenset({"GL001", "GL002", "GL003", "GL004", "GL005",
 PACKAGE_RULES = frozenset({"GL009", "GL010", "GL011", "GL012"})
 
 #: bump to invalidate cached per-file results when any pass changes
-LINT_VERSION = 15
+LINT_VERSION = 16
 
 #: wrappers whose function arguments are traced when called
 _TRACE_WRAPPERS = {
     "jit", "pjit", "pmap", "vmap", "grad", "value_and_grad", "scan",
     "while_loop", "fori_loop", "cond", "switch", "checkify", "remat",
-    "checkpoint", "shard_map", "shard_map_compat", "xmap", "linearize",
+    "checkpoint", "shard_map", "xmap", "linearize",
     "vjp", "jvp", "associative_scan", "map",
 }
 #: decorators that make the decorated def traced

@@ -9,7 +9,7 @@ These rules land BEFORE the sharding PR so it is born gated:
   naming an axis absent from every mesh declared in the module (or from
   the module's ``*_axis`` parameter vocabulary) shards onto an axis that
   does not exist: jax raises at dispatch time, per call site, long after
-  review. When a ``shard_map``/``shard_map_compat``/``pjit`` call site's
+  review. When a ``shard_map``/``pjit`` call site's
   ``mesh=`` argument resolves to a mesh built in the same module with
   literal axis names, its ``in_specs``/``out_specs`` are checked against
   THAT mesh's axes specifically. Name-based assignment tables
@@ -36,7 +36,7 @@ _RANK1_PARAM_NAMES = {"b", "bo", "bq", "bk", "bv", "bias", "beta",
 
 #: wrappers that open an SPMD region (their fn argument runs under trace
 #: on the mesh)
-_SPMD_WRAPPERS = {"shard_map", "shard_map_compat", "pjit"}
+_SPMD_WRAPPERS = {"shard_map", "pjit"}
 
 #: host-sync call tails inside an SPMD region
 _HOST_SYNC_TAILS = {"item", "tolist", "block_until_ready"}

@@ -2,12 +2,14 @@
 (native/dataloader.cpp): CSV/IDX record readers with a background prefetch
 ring — the native analog of the reference's DataVec record readers +
 AsyncDataSetIterator (SURVEY.md §2.3, §2.9). Auto-builds with make on first
-use if the shared library is missing; falls back to the pure-Python
-iterators when no toolchain is available."""
+use if the shared library is missing (it is never committed: ``*.so`` is
+git-ignored); falls back to the pure-Python iterators, with a logged
+warning, when the build fails."""
 
 from __future__ import annotations
 
 import ctypes
+import logging
 import subprocess
 from pathlib import Path
 from typing import Optional
@@ -30,7 +32,12 @@ def _load_lib():
         try:
             subprocess.run(["make", "-C", str(_NATIVE_DIR)], check=True,
                            capture_output=True, timeout=120)
-        except Exception:
+        except (OSError, subprocess.SubprocessError) as e:
+            # no toolchain / failed build: the Python path serves, and the
+            # log says why the native one does not
+            logging.getLogger(__name__).warning(
+                "native build failed (make -C %s): %s %s", _NATIVE_DIR, e,
+                (getattr(e, "stderr", None) or b"")[-400:])
             return None
     if not _LIB_PATH.exists():
         return None

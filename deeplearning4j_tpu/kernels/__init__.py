@@ -1,21 +1,9 @@
 """Pallas TPU kernels registered behind the nn.helpers seam (the analog of
 the reference's deeplearning4j-cuda module: cuDNN implementations discovered
 behind the Helper SPI, SURVEY.md §2.2). Import and call ``register_*`` to
-install — the moral equivalent of putting the cuda jar on the classpath.
+install — the moral equivalent of putting the cuda jar on the classpath."""
 
-Each kernel module must stay importable on its own: the helper registry's
-lazy discovery imports submodules through this package, so a missing optional
-dependency for one kernel (e.g. Pallas for the LSTM) must not take down the
-others."""
-
-try:
-    from .lstm import lstm_helper, register_lstm_helper
-except ImportError:                      # Pallas unavailable on this install
-    lstm_helper = None
-
-    def register_lstm_helper(platforms=("tpu", "cpu")) -> None:
-        raise ImportError("Pallas LSTM kernel unavailable on this install")
-
+from .lstm import lstm_helper, register_lstm_helper
 from .batchnorm import bn_train_fused, register_default as register_bn_helper
 
 __all__ = ["lstm_helper", "register_lstm_helper",

@@ -43,12 +43,10 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .pallas_attention import _interpret_default, on_device_blocks
+
 NEG = -1e30
 ROWW = 8          # row-scalar carrier width, matches pallas_attention.ROWW
-
-# jax renamed pltpu.TPUCompilerParams -> CompilerParams; accept both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
 
 #: largest T the whole-block kernel accepts (one [T, T] f32 logits tile
 #: per head must fit VMEM alongside its neighbors)
@@ -261,7 +259,7 @@ def _short_fwd_impl(q3, k3, v3, mask2, h, causal, g_heads, interpret,
                    pl.BlockSpec((g, t, ROWW), lambda i: (i, 0, 0))],
         out_shape=[jax.ShapeDtypeStruct((bh, t, d), q3.dtype),
                    jax.ShapeDtypeStruct((bh, t, ROWW), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             # "parallel": grid steps are independent (the constant-index
             # amask fetch has no cross-step ordering need), freeing Mosaic
             # to pipeline DMA against compute across steps
@@ -315,7 +313,7 @@ def _short_bwd_impl(q3, k3, v3, mask2, h, o, lse, do, causal, g_heads,
         out_specs=[_gspec(g, t, d)] * 3,
         out_shape=[jax.ShapeDtypeStruct((bh, t, d), q3.dtype)] * 3,
         scratch_shapes=scratch,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
             vmem_limit_bytes=96 * 1024 * 1024),
     )(*operands)
@@ -389,12 +387,19 @@ def short_attention(q, k, v, causal: bool = False, key_mask=None,
     exists). The attention math needs (T, D)-minor tiles, so the relayout
     must happen somewhere; XLA's explicit copies are that somewhere.
     Same −1e30 masking semantics as pallas_flash_attention."""
+    if interpret is None:
+        interpret = _interpret_default()
+    return on_device_blocks(
+        lambda q, k, v, m: _short_attention_local(
+            q, k, v, causal, m, g_heads, q_split, bool(interpret)),
+        q, k, v, key_mask)
+
+
+def _short_attention_local(q, k, v, causal, key_mask, g_heads, q_split,
+                           interpret):
     b, t, h, d = q.shape
     if t > MAX_T:
         raise ValueError(f"short_attention: T={t} > MAX_T={MAX_T}")
-    if interpret is None:
-        from .pallas_attention import _interpret_default
-        interpret = _interpret_default()
     g = g_heads or pick_g(b * h, h, key_mask is not None)
     if (b * h) % g:
         raise ValueError(f"g_heads={g} must divide B*H={b * h}")
@@ -420,8 +425,7 @@ def short_attention(q, k, v, causal: bool = False, key_mask=None,
     if key_mask is not None:
         out3 = _short_masked(fold(q), fold(k), fold(v),
                              key_mask.astype(jnp.float32), h, causal, g,
-                             bool(interpret), qs)
+                             interpret, qs)
     else:
-        out3 = _short(fold(q), fold(k), fold(v), causal, g,
-                      bool(interpret), qs)
+        out3 = _short(fold(q), fold(k), fold(v), causal, g, interpret, qs)
     return out3.reshape(b, h, t, d).transpose(0, 2, 1, 3)

@@ -37,6 +37,7 @@ autoregressive loop that dominates LM serving traffic.
 from __future__ import annotations
 
 import collections
+import functools
 import itertools
 import threading
 import uuid
@@ -51,6 +52,7 @@ from jax.sharding import NamedSharding
 from ..nn.conf.layers import (RnnOutputLayer, SelfAttentionLayer,
                               TokenAndPositionEmbedding)
 from ..nn.graph.vertices import LayerVertex
+from ..nn.helpers import attention_spmd
 from ..observability.flightrec import default_flight_recorder
 from ..observability.metrics import default_registry
 from ..observability.profiler import default_profiler
@@ -729,8 +731,16 @@ class TransformerDecoder:
         attribution."""
         if self.mesh is None:
             return jax.jit(impl, donate_argnums=donate)
-        impl.__name__ = impl.__name__ + self._impl_suffix
-        return jax.jit(impl, donate_argnums=donate,
+
+        @functools.wraps(impl)
+        def sharded_impl(*args):
+            # tracers carry no sharding: tell the attention kernels which
+            # mesh this jit partitions over (Mosaic needs a shard_map)
+            with attention_spmd(self.mesh, self._layout.data_axis,
+                                self._layout.tp_axis):
+                return impl(*args)
+        sharded_impl.__name__ = impl.__name__ + self._impl_suffix
+        return jax.jit(sharded_impl, donate_argnums=donate,
                        in_shardings=in_specs, out_shardings=out_specs)
 
     def _fn(self, name):

@@ -14,17 +14,46 @@ tests rely on (SURVEY.md §4).
 
 from __future__ import annotations
 
+import contextlib
+import threading
 from typing import Callable, Dict, Optional, Tuple
 
 import jax
 
 _HELPERS: Dict[str, Tuple[Callable, Tuple[str, ...]]] = {}
 _DISABLED: set = set()
+_SPMD = threading.local()      # per thread: engines trace on their own
+
+
+@contextlib.contextmanager
+def attention_spmd(mesh, batch_axis: Optional[str] = None,
+                   head_axis: Optional[str] = None):
+    """Declare, to the code TRACED inside, that the enclosing jit is
+    partitioned over ``mesh`` with attention's batch rows sharded over
+    ``batch_axis`` and its heads over ``head_axis``. Whoever owns the mesh
+    (a sharded TransformerDecoder, a data-parallel trainer) enters this
+    inside the function it jits; kernel helpers read it through
+    :func:`attention_spmd_context` — tracers carry no sharding, and Mosaic
+    kernels cannot lower under a multi-device jit unless wrapped in a
+    ``shard_map`` over that mesh."""
+    prev = attention_spmd_context()
+    _SPMD.ctx = (mesh, batch_axis, head_axis)
+    try:
+        yield
+    finally:
+        _SPMD.ctx = prev
+
+
+def attention_spmd_context():
+    """(mesh, batch_axis, head_axis) declared by :func:`attention_spmd` for
+    the current trace, or None under a single-device jit."""
+    return getattr(_SPMD, "ctx", None)
 
 # Lazy default discovery — the analog of the reference's reflective
 # Class.forName("...CudnnConvolutionHelper") at ConvolutionLayer.java:69-76:
-# if a kernel module providing this kind exists, it self-registers on first
-# use; otherwise the built-in path runs.
+# the kernel module providing this kind self-registers on first use. The
+# providers ship with the package, so a provider that fails to import is an
+# error that propagates — never a silent switch to the built-in path.
 _DEFAULT_PROVIDERS: Dict[str, str] = {
     "batchnorm_train": "deeplearning4j_tpu.kernels.batchnorm",
     "batchnorm_add_act_train": "deeplearning4j_tpu.kernels.batchnorm",
@@ -40,7 +69,6 @@ _DEFAULT_PROVIDERS: Dict[str, str] = {
     # char-RNN shapes in both f32 (11.5 vs 12.5 ms/step) and bf16 (8.0 vs
     # 10.6) — kernels/lstm.py stays opt-in via register_lstm_helper()
 }
-_FAILED_PROVIDERS: set = set()
 
 
 # kinds whose current registration came from lazy default discovery:
@@ -80,28 +108,13 @@ def get_helper(kind: str) -> Optional[Callable]:
     default backend platform, else None (caller falls back to pure jnp)."""
     if kind in _DISABLED:
         return None
-    if kind not in _HELPERS and kind in _DEFAULT_PROVIDERS and \
-            kind not in _FAILED_PROVIDERS:
+    if kind not in _HELPERS and kind in _DEFAULT_PROVIDERS:
         import importlib
-        try:
-            importlib.import_module(
-                _DEFAULT_PROVIDERS[kind]).register_default()
-        except ImportError as e:
-            # e.g. an optional kernel dependency missing on this install —
-            # fall back to the built-in path, but say so once
-            _FAILED_PROVIDERS.add(kind)
-            import logging
-            logging.getLogger(__name__).warning(
-                "helper provider for %r unavailable (%s); using built-in",
-                kind, e)
+        importlib.import_module(_DEFAULT_PROVIDERS[kind]).register_default()
     if kind not in _HELPERS:
         return None
     fn, platforms = _HELPERS[kind]
-    try:
-        platform = jax.default_backend().lower()
-    except Exception:
-        return None
-    return fn if platform in platforms else None
+    return fn if jax.default_backend().lower() in platforms else None
 
 
 def disable_helper(kind: str) -> None:

@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..nn.helpers import attention_spmd
 from ..ops.dataset import DataSet, MultiDataSet
 from .mesh import make_mesh
 
@@ -36,8 +37,11 @@ class GraphDataParallelTrainer:
 
         def wrapped(params, upd, state, inputs, labels, imasks, lmasks,
                     iteration):
-            return step(params, upd, state, inputs, labels, imasks, lmasks,
-                        iteration, {})
+            # tracers carry no sharding: tell the attention kernels which
+            # mesh this jit partitions over (Mosaic needs a shard_map)
+            with attention_spmd(mesh, "data"):
+                return step(params, upd, state, inputs, labels, imasks,
+                            lmasks, iteration, {})
 
         self._jit_step = jax.jit(
             wrapped,

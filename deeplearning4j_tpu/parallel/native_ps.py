@@ -30,6 +30,20 @@ _LIB_PATH = _NATIVE_DIR / "libdl4jtpu_native.so"
 _lib = None
 
 
+def _make(*targets: str) -> bool:
+    """``make -C native [targets]``; a failed build (no toolchain, compile
+    error) is logged — the Python path serves — never swallowed."""
+    try:
+        subprocess.run(["make", "-C", str(_NATIVE_DIR), *targets],
+                       check=True, capture_output=True, timeout=120)
+        return True
+    except (OSError, subprocess.SubprocessError) as e:
+        logging.getLogger(__name__).warning(
+            "native build failed (make -C %s): %s %s", _NATIVE_DIR, e,
+            (getattr(e, "stderr", None) or b"")[-400:])
+        return False
+
+
 def _load_lib():
     global _lib
     if _lib is not None:
@@ -40,10 +54,7 @@ def _load_lib():
         logging.getLogger(__name__).info(
             "building native parameter-server library: make -C %s",
             _NATIVE_DIR)
-        try:
-            subprocess.run(["make", "-C", str(_NATIVE_DIR)], check=True,
-                           capture_output=True, timeout=120)
-        except Exception:
+        if not _make():
             return None
     if not _LIB_PATH.exists():
         return None
@@ -52,12 +63,9 @@ def _load_lib():
         lib.ps_create.restype = ctypes.c_void_p
     except AttributeError:
         # stale .so from before param_server.cpp: rebuild once
-        try:
-            subprocess.run(["make", "-C", str(_NATIVE_DIR), "clean", "all"],
-                           check=True, capture_output=True, timeout=120)
-            lib = ctypes.CDLL(str(_LIB_PATH))
-        except Exception:
+        if not _make("clean", "all"):
             return None
+        lib = ctypes.CDLL(str(_LIB_PATH))
     lib.ps_create.restype = ctypes.c_void_p
     lib.ps_create.argtypes = [ctypes.POINTER(ctypes.c_float), ctypes.c_int64,
                               ctypes.c_double, ctypes.c_int, ctypes.c_int]

@@ -89,8 +89,7 @@ def pipeline_apply(block_fn: Callable, stacked_params, x_micro, mesh: Mesh,
 
     pspec = jax.tree_util.tree_map(
         lambda a: P(axis, *([None] * (a.ndim - 1))), stacked_params)
-    from ..ops.platform import shard_map_compat
-    return shard_map_compat(
+    return jax.shard_map(
         staged, mesh=mesh, in_specs=(pspec, P()), out_specs=P(),
         check_vma=False)(stacked_params, x_micro)
 

@@ -31,10 +31,8 @@ from typing import List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from ..ops.platform import shard_map_compat as shard_map
 
 from ..ops.dataset import DataSet
 from .mesh import make_mesh

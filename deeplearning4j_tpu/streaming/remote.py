@@ -992,8 +992,9 @@ class ReplicaProcessLauncher:
     def _spawn_locked(self, rid: str, row: Dict[str, Any]) -> None:
         cfg_path = self._config(rid, row["role"], row["epoch"],
                                 row.get("extra"))
+        # the worker inherits the parent's platform choice; the
+        # backend it actually landed on is in its hello frame and log
         env = dict(os.environ)
-        env.setdefault("JAX_PLATFORMS", "cpu")
         env["PYTHONPATH"] = _REPO_ROOT + os.pathsep + \
             env.get("PYTHONPATH", "")
         env.update(self.extra_env)
@@ -1852,6 +1853,12 @@ class RemoteWorker:
                                          recover_from_journal)
         from .disagg import SerializedKVTransport
 
+        import jax
+        backend = jax.default_backend()
+        device = jax.devices()[0].device_kind
+        print(f"worker {self.rid} epoch {cfg.get('epoch')}: jax backend "
+              f"{backend}, device {device} x{jax.device_count()}",
+              flush=True)        # stdout is worker-<epoch>.log
         model = cfg["model"]
         eng_cfg = dict(cfg.get("engine") or {})
         net = ComputationGraph(transformer_lm_conf(
@@ -1917,6 +1924,7 @@ class RemoteWorker:
 
             self._publish("hello", {
                 "role": self.role, "pid": os.getpid(),
+                "backend": backend, "device": device,
                 "num_slots": eng.num_slots,
                 "max_pending": eng.max_pending,
                 "recovered": recovery.to_dict()})
@@ -1970,6 +1978,8 @@ class RemoteWorker:
 def worker_main(config_path: str) -> int:
     """Entry point of a replica process (``python -m
     deeplearning4j_tpu.streaming.remote <config.json>``)."""
+    from ..ops.platform import configure_compilation_cache
+    configure_compilation_cache()
     with open(config_path, encoding="utf-8") as f:
         cfg = json.load(f)
     return RemoteWorker(cfg).run()

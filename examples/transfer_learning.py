@@ -61,4 +61,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from deeplearning4j_tpu.ops.platform import configure_compilation_cache
+    configure_compilation_cache()
     main()

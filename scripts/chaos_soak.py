@@ -102,6 +102,8 @@ import time
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO_ROOT)
 
+# a correctness soak that starts many engine PROCESSES: they would contend
+# for one chip, so it runs on the CPU unless told otherwise (ROADMAP Reach 6)
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 

@@ -48,8 +48,6 @@ import time
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO_ROOT)
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 
 def _env_int(name: str, default: int) -> int:
     return int(os.environ.get(name, default))
@@ -296,4 +294,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from deeplearning4j_tpu.ops.platform import configure_compilation_cache
+    configure_compilation_cache()
     sys.exit(main())

@@ -760,6 +760,8 @@ def main() -> int:
 
 
 if __name__ == "__main__":
+    from deeplearning4j_tpu.ops.platform import configure_compilation_cache
+    configure_compilation_cache()
     if "--block-sweep" in sys.argv[1:]:
         sys.exit(block_sweep())
     if "--mesh-sweep" in sys.argv[1:]:

@@ -18,6 +18,9 @@ import jax.numpy as jnp
 from deeplearning4j_tpu.models import lm_batch, lm_batch_sparse, transformer_lm_conf
 from deeplearning4j_tpu.nn.graph import ComputationGraph
 from deeplearning4j_tpu.ops.dataset import DataSet
+from deeplearning4j_tpu.ops.platform import configure_compilation_cache
+
+configure_compilation_cache()
 
 if os.environ.get("LM_PROFILE_PALLAS"):
     from deeplearning4j_tpu.kernels.pallas_attention import \

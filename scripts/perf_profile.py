@@ -11,6 +11,9 @@ import jax, jax.numpy as jnp
 
 from deeplearning4j_tpu.models import resnet50_conf
 from deeplearning4j_tpu.nn.graph import ComputationGraph
+from deeplearning4j_tpu.ops.platform import configure_compilation_cache
+
+configure_compilation_cache()
 
 BATCH = int(sys.argv[1]) if len(sys.argv) > 1 else 128
 LOGDIR = "/tmp/jaxprof"

@@ -1039,14 +1039,14 @@ class TestShardingRules:
 
     def test_shard_map_site_axis_mismatch_flags(self, tmp_path):
         out = _lint_src(tmp_path, """
+            import jax
             from jax.sharding import Mesh, PartitionSpec as P
-            from deeplearning4j_tpu.ops.platform import shard_map_compat
 
             def run(devs, f, xs):
                 mesh = Mesh(devs, ("data",))
-                g = shard_map_compat(f, mesh=mesh,
-                                     in_specs=(P("model"),),
-                                     out_specs=P("data"))
+                g = jax.shard_map(f, mesh=mesh,
+                                  in_specs=(P("model"),),
+                                  out_specs=P("data"))
                 return g(xs)
         """, rules=["GL013"])
         assert len(out) == 1
@@ -1116,7 +1116,7 @@ class TestShardingRules:
 
     def test_host_sync_inside_shard_map_flags(self, tmp_path):
         out = _lint_src(tmp_path, """
-            from deeplearning4j_tpu.ops.platform import shard_map_compat
+            import jax
 
             def kernel(x, hist):
                 v = x.item()
@@ -1125,23 +1125,23 @@ class TestShardingRules:
                 return x
 
             def run(mesh, xs):
-                f = shard_map_compat(kernel, mesh=mesh, in_specs=None,
-                                     out_specs=None)
+                f = jax.shard_map(kernel, mesh=mesh, in_specs=None,
+                                  out_specs=None)
                 return f(xs)
         """, rules=["GL014"])
         assert _rules(out) == ["GL014"] and len(out) == 3
 
     def test_pure_lax_shard_map_body_is_fine(self, tmp_path):
         out = _lint_src(tmp_path, """
+            import jax
             import jax.numpy as jnp
-            from deeplearning4j_tpu.ops.platform import shard_map_compat
 
             def kernel(x):
                 return jnp.sum(x * 2.0)
 
             def run(mesh, xs):
-                f = shard_map_compat(kernel, mesh=mesh, in_specs=None,
-                                     out_specs=None)
+                f = jax.shard_map(kernel, mesh=mesh, in_specs=None,
+                                  out_specs=None)
                 return f(xs)
         """, rules=["GL014"])
         assert out == []
@@ -1700,6 +1700,51 @@ class TestCompileAudit:
         with CompileAudit():
             pass
         assert bool(getattr(jax.config, "jax_log_compiles", False)) == prev
+
+    def test_handler_parses_the_jax_0_9_record(self):
+        """The exact record jax 0.9.0 logs (pxla.py lower_sharding_
+        computation): a ``jit(...)``-wrapped module name and a TUPLE of
+        avals — the audit row is the bare function name."""
+        import logging
+
+        from deeplearning4j_tpu.analysis.compile_audit import \
+            _CompileLogHandler
+        audit = CompileAudit()
+        _CompileLogHandler(audit).emit(logging.LogRecord(
+            "jax._src.interpreters.pxla", logging.WARNING, "pxla.py", 1943,
+            "Compiling %s with global shapes and types %s. "
+            "Argument mapping: %s.",
+            ("jit(stable)", "(ShapedArray(float32[7]),)",
+             "(UnspecifiedValue,)"), None))
+        assert dict(audit.counts) == {"stable": 1}
+        assert dict(audit.signatures["stable"]) == \
+            {"(ShapedArray(float32[7]),)": 1}
+
+    def test_deaf_seam_raises_instead_of_reading_empty(self):
+        """A silenced pxla logger must fail the audit at entry — never
+        report ``{}`` compiles for a region it could not observe — and
+        the failed entry must leave no handler or config behind."""
+        import logging
+
+        import jax
+
+        from deeplearning4j_tpu.analysis.compile_audit import \
+            CompileAuditDeafError
+        logger = logging.getLogger("jax._src.interpreters.pxla")
+        prev = bool(getattr(jax.config, "jax_log_compiles", False))
+        handlers = list(logger.handlers)
+        logger.disabled = True
+        try:
+            with pytest.raises(CompileAuditDeafError):
+                with CompileAudit():
+                    pytest.fail("a deaf audit must not be entered")
+        finally:
+            logger.disabled = False
+        assert logger.handlers == handlers
+        assert bool(getattr(jax.config, "jax_log_compiles", False)) == prev
+        with CompileAudit() as audit:       # hears again once unsilenced
+            jax.jit(lambda x: x + 1)(1.0)
+        assert audit.report()["per_function"] == {}    # probe never counted
 
 
 def _tiny_lm(vocab=37, d=16, heads=2, layers=1, t_max=32):

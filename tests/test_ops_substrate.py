@@ -249,3 +249,54 @@ class TestDataSet:
         assert [b.num_examples() for b in batches] == [4, 4, 2]
         merged = DataSet.merge(batches)
         np.testing.assert_allclose(merged.features, ds.features)
+
+
+class TestCompilationCacheConfig:
+    """ops.platform.configure_compilation_cache: where JAX_COMPILATION_
+    CACHE_DIR is set the code sets no directory; otherwise ONE fixed path
+    inside the checkout — identical across calls and processes, because a
+    cache whose path moves never hits. Each case runs in a fresh
+    interpreter (the configuration is once-per-process state)."""
+
+    _PROBE = (
+        "import json, jax\n"
+        "updates = []\n"
+        "real = jax.config.update\n"
+        "jax.config.update = lambda k, v: (updates.append(k), real(k, v))\n"
+        "from deeplearning4j_tpu.ops.platform import "
+        "configure_compilation_cache as c\n"
+        "a, b = c(), c(min_compile_secs=0.0)\n"
+        "print(json.dumps({'a': a, 'b': b, 'updates': updates, 'cfg': "
+        "jax.config.jax_compilation_cache_dir, 'floor': "
+        "jax.config.jax_persistent_cache_min_compile_time_secs}))\n")
+
+    def _run(self, env_dir):
+        import json
+        import os
+        import subprocess
+        import sys
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        env = {k: v for k, v in os.environ.items()
+               if k != "JAX_COMPILATION_CACHE_DIR"}
+        if env_dir is not None:
+            env["JAX_COMPILATION_CACHE_DIR"] = env_dir
+        env["PYTHONPATH"] = root + os.pathsep + env.get("PYTHONPATH", "")
+        r = subprocess.run([sys.executable, "-c", self._PROBE], env=env,
+                           cwd="/", capture_output=True, text=True,
+                           timeout=120, check=True)
+        return root, json.loads(r.stdout.strip().splitlines()[-1])
+
+    def test_env_dir_is_left_to_jax(self, tmp_path):
+        _, out = self._run(str(tmp_path / "cc"))
+        assert out["a"] == out["b"] == out["cfg"] == str(tmp_path / "cc")
+        assert "jax_compilation_cache_dir" not in out["updates"]
+        assert out["floor"] == 0.0            # the floor may only lower
+
+    def test_default_is_one_fixed_path_in_the_checkout(self):
+        import os
+        root, first = self._run(None)
+        _, second = self._run(None)           # a second process: same path
+        want = os.path.join(root, ".jax_cache")
+        for out in (first, second):
+            assert out["a"] == out["b"] == out["cfg"] == want
+            assert out["updates"].count("jax_compilation_cache_dir") == 1

@@ -862,3 +862,62 @@ class TestShortSeqAttention:
         want = self._ref(q, q, q, key_mask=jnp.asarray(km))
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-5, atol=1e-5)
+
+    def test_declared_mesh_runs_the_kernels_per_device_block(self, rng_np):
+        """Mosaic refuses automatic partitioning on a real multi-chip
+        host, so under a declared mesh (nn.helpers.attention_spmd) the
+        kernels run inside a shard_map over it — per (batch, head) block,
+        the LOCAL block resolving its own heads-per-step — and outputs and
+        gradients equal the single-device ones, for the short-T and the
+        flash kernel, masked and unmasked. A batch the data axis does not
+        divide is repeated, not split."""
+        import jax
+        import jax.numpy as jnp
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+        from deeplearning4j_tpu.kernels.pallas_attention import \
+            pallas_flash_attention
+        from deeplearning4j_tpu.kernels.pallas_shortseq import \
+            short_attention
+        from deeplearning4j_tpu.nn.helpers import (attention_spmd,
+                                                   attention_spmd_context)
+        mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2),
+                    ("data", "tp"))
+        qkv_sh = NamedSharding(mesh, P("data", None, "tp", None))
+        kernels = {
+            "short": lambda q, k, v, m: short_attention(
+                q, k, v, causal=True, key_mask=m, interpret=True),
+            "flash": lambda q, k, v, m: pallas_flash_attention(
+                q, k, v, causal=True, q_block=64, k_block=64,
+                key_mask=m, interpret=True)}
+        for b in (4, 3):                  # 3: the data axis does not divide
+            q, k, v, km = self._data(rng_np, b=b, t=128, h=4, d=8)
+            for name, kern in kernels.items():
+                for mask in (None, km):
+                    def loss(q, k, v):
+                        out = kern(q, k, v, mask)
+                        return jnp.sum(out ** 2), out
+                    step = jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                              has_aux=True)
+
+                    def declared(q, k, v):
+                        with attention_spmd(mesh, "data", "tp"):
+                            return step(q, k, v)
+                    (_, want), gwant = jax.jit(step)(q, k, v)
+                    sharded = jax.jit(
+                        declared,
+                        in_shardings=(qkv_sh,) * 3 if b == 4 else None)
+                    assert "shard_map" in str(jax.make_jaxpr(declared)(
+                        q, k, v)), name
+                    (_, got), ggot = sharded(q, k, v)
+                    if b == 4:
+                        assert got.addressable_shards[0].data.shape == \
+                            (2, 128, 2, 8), (name, got.sharding)
+                    np.testing.assert_allclose(
+                        np.asarray(got), np.asarray(want),
+                        rtol=1e-5, atol=1e-5)
+                    for a, b_ in zip(ggot, gwant):
+                        np.testing.assert_allclose(
+                            np.asarray(a), np.asarray(b_),
+                            rtol=1e-4, atol=1e-5)
+        assert attention_spmd_context() is None      # nothing leaks
