@@ -112,6 +112,11 @@ _OBS_HINTED_METHODS = {"set", "dec", "event", "finish", "labels",
                        "annotate"}
 _OBS_NAME_HINTS = ("metric", "gauge", "counter", "hist", "trace", "span",
                    "registry", "telemetry")
+#: GL008 — the engine loop's and ``fit_batch``'s one stamp source
+#: (observability.tracing.Seam, the engine's ``_seam``): it takes host
+#: interval-clock stamps and feeds the sinks above, so it is host-only
+#: like them, by whatever receiver it is reached
+_SEAM_CALLS = {"Seam", "_seam"}
 #: GL015 — the ISSUE 9 sinks: flight-recorder / devstats / SLO recording
 #: must stay host-side exactly like GL008's metric/trace calls (same
 #: receiver-hint machinery, its own rule id so the new subsystems get
@@ -137,7 +142,7 @@ _GL015_REGISTRY_HINTS = ("registry", "reg")
 _GL016_NAME_HINTS = ("profiler", "prof", "phase", "timeline")
 _GL016_RECORD_METHODS = {"record_block", "record_admission",
                          "record_chunk", "record_spec", "channel",
-                         "attach_decoder"}
+                         "mark_idle"}
 #: callees whose results are NOT "just-dispatched device work" for GL007:
 #: python builtins and host-side helpers a loop legitimately materializes
 _GL007_SAFE_CALLEES = {"range", "len", "list", "tuple", "dict", "set",
@@ -425,7 +430,15 @@ class ModuleLint:
                                "sync")
             if isinstance(node, ast.Call) and "GL008" in enabled:
                 f = node.func
-                if isinstance(f, ast.Attribute):
+                if _dotted_tail(f) in _SEAM_CALLS:
+                    self._emit(out, "GL008", node, qual,
+                               f"{_dotted_tail(f)}() stamps a seam of the "
+                               "host loop under trace — its interval-"
+                               "clock stamps would be trace-time "
+                               "constants and its sinks would record "
+                               "once per compile; take seams outside "
+                               "the jitted region")
+                elif isinstance(f, ast.Attribute):
                     recv = _dotted_name(f.value).lower()
                     hinted = any(w in recv for w in _OBS_NAME_HINTS)
                     if f.attr in _OBS_RECORD_METHODS or \

@@ -51,7 +51,7 @@ _OBS_NAME_HINTS = ("metric", "gauge", "counter", "hist", "trace", "span",
                    "registry", "telemetry")
 
 
-from .lint import (_GL016_NAME_HINTS, _GL016_RECORD_METHODS,
+from .lint import (_GL016_NAME_HINTS, _GL016_RECORD_METHODS, _SEAM_CALLS,
                    _dotted_name, _dotted_tail)
 
 
@@ -275,6 +275,12 @@ class ShardingLint:
                          "print() inside a shard_map/pjit region "
                          "observes tracers and runs once per COMPILE — "
                          "use jax.debug.print or log on the host side")
+                elif tail in _SEAM_CALLS:
+                    emit("GL014", node.lineno, qual,
+                         f"{tail}() stamps a seam of the host loop "
+                         "inside a shard_map/pjit region — seams and "
+                         "their sinks must stay host-side (GL008 "
+                         "generalized to the SPMD seams)")
                 elif isinstance(f, ast.Attribute):
                     recv = _dotted_name(f.value).lower()
                     hinted = any(w in recv for w in _OBS_NAME_HINTS)

@@ -107,6 +107,7 @@ def _pallas_forward(xw_t, R, h0, c0, peep):
     interpret = _interpret_default()
     out = pl.pallas_call(
         _make_kernel(peephole),
+        name="lstm_cell",
         grid=(T,),
         in_specs=in_specs,
         out_specs=[

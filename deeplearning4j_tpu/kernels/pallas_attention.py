@@ -22,7 +22,12 @@ keys' logits by −1e30 in ``_scores`` — shared by forward and both backward
 kernels — so ragged long-context batches keep the kernel's speed. A fully
 masked row degrades to the same uniform average as the materialized and
 jnp blockwise paths (arbitrary-but-finite; such rows are excluded by loss
-masks)."""
+masks).
+
+Each ``pallas_call`` has a ``name=`` (``flash_fwd``, ``flash_bwd_dq``,
+``flash_bwd_dkv``): the compiler makes it the kernel's instruction name, so
+a device trace tells the three apart by name (``%flash_bwd_dq.3 = ...``)
+and not by operand shapes."""
 
 from __future__ import annotations
 
@@ -261,6 +266,7 @@ def _flash_fwd_impl(q3, k3, v3, mask2, h, causal, qb, kb, interpret):
         operands.append(mask2[:, None, :])
     o, lse = pl.pallas_call(
         kern,
+        name="flash_fwd",
         grid=grid,
         interpret=interpret,
         in_specs=in_specs,
@@ -308,6 +314,7 @@ def _flash_bwd_impl(q3, k3, v3, mask2, h, o, lse, do, causal, qb, kb,
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, causal=causal, scale=scale,
                           kb=kb, qb=qb, masked=masked),
+        name="flash_bwd_dq",
         grid=(bh, t // qb, t // kb),
         interpret=interpret,
         in_specs=common,
@@ -337,6 +344,7 @@ def _flash_bwd_impl(q3, k3, v3, mask2, h, o, lse, do, causal, qb, kb,
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, causal=causal, scale=scale,
                           kb=kb, qb=qb, masked=masked),
+        name="flash_bwd_dkv",
         grid=(bh, t // kb, t // qb),
         interpret=interpret,
         in_specs=kv_specs,

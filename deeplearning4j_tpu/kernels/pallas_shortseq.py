@@ -252,6 +252,7 @@ def _short_fwd_impl(q3, k3, v3, mask2, h, causal, g_heads, interpret,
         operands.append(mask2[:, None, :])
     o, lse = pl.pallas_call(
         kern,
+        name="shortseq_fwd",
         grid=(bh // g,),
         interpret=interpret,
         in_specs=in_specs,
@@ -307,6 +308,7 @@ def _short_bwd_impl(q3, k3, v3, mask2, h, o, lse, do, causal, g_heads,
                    pltpu.VMEM((t, d), jnp.float32)] if nq_eff > 1 else []
     dq, dk, dv = pl.pallas_call(
         kern,
+        name="shortseq_bwd",
         grid=(bh // g,),
         interpret=interpret,
         in_specs=in_specs,

@@ -51,13 +51,16 @@ from jax.sharding import NamedSharding
 
 from ..nn.conf.layers import (RnnOutputLayer, SelfAttentionLayer,
                               TokenAndPositionEmbedding)
+from ..nn.graph.computation_graph import scoped
 from ..nn.graph.vertices import LayerVertex
 from ..nn.helpers import attention_spmd
 from ..observability.flightrec import default_flight_recorder
 from ..observability.metrics import default_registry
 from ..observability.profiler import default_profiler
 from ..observability.slo import default_slo_tracker
-from ..observability.tracing import Trace, default_trace_ring, interval_now
+from ..observability import tracing
+from ..observability.tracing import (Seam, Trace, default_trace_ring,
+                                     interval_now)
 from ..ops.platform import train_donate_argnums
 from ..ops.transfer import device_fetch
 from ..parallel.faults import (Cancelled, DeadlineExceeded, NULL_INJECTOR,
@@ -358,7 +361,7 @@ class TransformerDecoder:
         acts = {self.input_name: tokens}
         new_caches = {}
         logits = None
-        for name in conf.topological_order:
+        for name in scoped(conf.topological_order):
             v = conf.vertices[name]
             xs = [acts[i] for i in conf.vertex_inputs[name]]
             if isinstance(v, LayerVertex) and \
@@ -386,7 +389,7 @@ class TransformerDecoder:
         acts = {self.input_name: ids}
         new_caches = {}
         logits = None
-        for name in conf.topological_order:
+        for name in scoped(conf.topological_order):
             v = conf.vertices[name]
             xs = [acts[i] for i in conf.vertex_inputs[name]]
             if isinstance(v, LayerVertex) and \
@@ -416,7 +419,7 @@ class TransformerDecoder:
         acts = {self.input_name: tokens}
         new_caches = {}
         logits = None
-        for name in conf.topological_order:
+        for name in scoped(conf.topological_order):
             v = conf.vertices[name]
             xs = [acts[i] for i in conf.vertex_inputs[name]]
             if isinstance(v, LayerVertex) and \
@@ -447,7 +450,7 @@ class TransformerDecoder:
         acts = {self.input_name: ids}
         new_caches = {}
         logits = None
-        for name in conf.topological_order:
+        for name in scoped(conf.topological_order):
             v = conf.vertices[name]
             xs = [acts[i] for i in conf.vertex_inputs[name]]
             if isinstance(v, LayerVertex) and \
@@ -480,7 +483,7 @@ class TransformerDecoder:
         acts = {self.input_name: tokens}
         new_caches = {}
         logits = None
-        for name in conf.topological_order:
+        for name in scoped(conf.topological_order):
             v = conf.vertices[name]
             xs = [acts[i] for i in conf.vertex_inputs[name]]
             if isinstance(v, LayerVertex) and \
@@ -522,7 +525,7 @@ class TransformerDecoder:
         acts = {self.input_name: tokens}
         new_caches = {}
         logits = None
-        for name in conf.topological_order:
+        for name in scoped(conf.topological_order):
             v = conf.vertices[name]
             xs = [acts[i] for i in conf.vertex_inputs[name]]
             if isinstance(v, LayerVertex) and \
@@ -553,7 +556,7 @@ class TransformerDecoder:
         acts = {self.input_name: tokens}
         new_caches = {}
         logits = None
-        for name in conf.topological_order:
+        for name in scoped(conf.topological_order):
             v = conf.vertices[name]
             xs = [acts[i] for i in conf.vertex_inputs[name]]
             if isinstance(v, LayerVertex) and \
@@ -585,7 +588,7 @@ class TransformerDecoder:
                  lengths[:, None]).astype(jnp.float32)
         acts = {self.input_name: tokens}
         logits = None
-        for name in conf.topological_order:
+        for name in scoped(conf.topological_order):
             v = conf.vertices[name]
             xs = [acts[i] for i in conf.vertex_inputs[name]]
             if name == self.output_name:
@@ -641,12 +644,13 @@ class TransformerDecoder:
         logits: its argmax gaps are macroscopic for any trained model,
         and the r6 contract (greedy == teacher-forced reference) must
         not move."""
-        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        t = jnp.maximum(temps, 1e-6)[:, None]
-        ql = logits.astype(jnp.bfloat16).astype(jnp.float32)
-        sampled = jax.random.categorical(key, ql / t,
-                                         axis=-1).astype(jnp.int32)
-        return jnp.where(temps <= 0, greedy, sampled)
+        with jax.named_scope("sample"):
+            greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            t = jnp.maximum(temps, 1e-6)[:, None]
+            ql = logits.astype(jnp.bfloat16).astype(jnp.float32)
+            sampled = jax.random.categorical(key, ql / t,
+                                             axis=-1).astype(jnp.int32)
+            return jnp.where(temps <= 0, greedy, sampled)
 
     # graftlint: traced
     def _fault_of(self, logits, stop=None):
@@ -1595,6 +1599,12 @@ class GenerationRequest:
         self._created_t = self._submit_t
         self._admitted_t: Optional[float] = None
         self._first_token_t: Optional[float] = None
+        self._done_t: Optional[float] = None
+        # (interval-clock time, tokens) each time tokens became visible
+        # in ``generated``: one entry a prefill or block retire, on or
+        # off tracing; like the SLO clocks it rides the request through
+        # requeue
+        self._emissions: List[Tuple[float, int]] = []
         self._slo = None                   # SLOTracker, set at submit
         self._slo_done = False             # an observe_request happened
         self._slo_labels: Dict = {}
@@ -1606,10 +1616,27 @@ class GenerationRequest:
         self.journal_id: Optional[str] = None
         self._journal_hooked = False
 
+    def clocks(self) -> Dict[str, Optional[float]]:
+        """The request's clocks on ``interval_now()``: ``created`` (the
+        original submission), ``admitted`` (its prefill's dispatch),
+        ``first_token`` (that prefill's readback) and ``done`` (terminal
+        state reached); None where not reached yet. Written once each —
+        a takeover or migration never resets them."""
+        return {"created": self._created_t, "admitted": self._admitted_t,
+                "first_token": self._first_token_t, "done": self._done_t}
+
+    def emissions(self) -> List[Tuple[float, int]]:
+        """``(t, n_tokens)`` for each time tokens became visible in
+        ``generated`` — one entry a prefill or retired block, ``t`` the
+        readback's stamp on ``interval_now()``; the ``n`` sum to
+        ``len(generated)``."""
+        return list(self._emissions)
+
     def _complete(self):
         self._result = np.concatenate(
             [self.prompt, np.asarray(self.generated, np.int32)])
         self._running = False
+        self._done_t = interval_now()
         if self.trace is not None:
             self.trace.finish("ok", tokens=len(self.generated))
         self._done.set()
@@ -1619,6 +1646,7 @@ class GenerationRequest:
     def _fail(self, exc: BaseException):
         self._error = exc
         self._running = False
+        self._done_t = interval_now()
         if self.trace is not None:
             self.trace.finish(f"failed:{type(exc).__name__}",
                               tokens=len(self.generated))
@@ -2066,20 +2094,24 @@ class SlotGenerationEngine:
         self._flightrec = flight_recorder if flight_recorder is not None \
             else default_flight_recorder()
         # hot-loop phase profiler (ISSUE 13): per-block phase/bubble
-        # decomposition + measured steady durations for the roofline,
-        # recorded from the readback thread only — ``profiling``
-        # defaults to the tracing flag (the telemetry-off A/B baseline
-        # records nothing), and the channel is keyed by the STABLE
-        # slo_label, so a supervisor-rebuilt engine continues the same
-        # phase account and the timeline ring survives the takeover
+        # decomposition + measured steady durations per impl, recorded
+        # from the serve thread only — ``profiling`` defaults to the
+        # tracing flag (the telemetry-off baseline records nothing),
+        # and the channel is keyed by the STABLE slo_label, so a
+        # supervisor-rebuilt engine continues the same phase account
+        # and the timeline ring survives the takeover
         self._profiling = self._tracing if profiling is None \
             else bool(profiling)
         self._profiler = profiler if profiler is not None \
             else default_profiler()
         self._prof = self._profiler.channel(
-            self.slo_label, num_slots=self.num_slots,
-            decoder=self.decoder) if self._profiling else None
+            self.slo_label, num_slots=self.num_slots) \
+            if self._profiling else None
         self._prof_impl_names: Dict = {}
+        # the loop's seams (observability.tracing.Seam) always take
+        # their two stamps — EWMAs and SLO clocks need them — and mirror
+        # them onto a profiler session's trace unless telemetry is off
+        self._mirror = self._tracing or self._profiling
         reg = self._registry
         self._m = {key: reg.counter(f"generation_{key}_total", desc,
                                     ("engine",)).labels(self.engine_id)
@@ -3227,12 +3259,16 @@ class SlotGenerationEngine:
             max(0.0, 1.0 - written / span), 4)
         return st
 
+    def _seam(self, name: str, block: Optional[int] = None,
+              lanes: Optional[int] = None, k: Optional[int] = None) -> Seam:
+        """One seam of this engine's loop: ``with self._seam(...) as s``
+        takes the two stamps (``s.t0``, ``s.t1``) the sinks then get."""
+        return Seam(name, block, lanes, k, self._mirror)
+
     def _prof_impl(self, kind: str, k: Optional[int] = None) -> str:
-        """Audit-keyed impl name for the profiler's roofline join
+        """Audit-keyed impl name for the profiler's per-impl account
         (memoized — one dict hit per record in steady state): the same
-        per-K, per-mesh key devstats and CompileAudit use, so the
-        measured-duration table lines up with the cost table row for
-        row."""
+        per-K, per-mesh key CompileAudit uses."""
         name = self._prof_impl_names.get((kind, k))
         if name is None:
             if kind == "block":
@@ -3450,8 +3486,7 @@ class SlotGenerationEngine:
             req._running = True
             self._m["prefills"].inc()
         if req.trace is not None:
-            req.trace.add_span("queued", req._submit_t,
-                               interval_now())
+            req.trace.add_span("queued", req._submit_t)
         return True
 
     def _admit(self):
@@ -3515,17 +3550,22 @@ class SlotGenerationEngine:
                              # quarantine/shutdown drain owns it now
                 self._m["prefills"].inc(m)
                 batch_no = self._m["prefill_batches"].inc()
-            t_pre0 = interval_now()
-            self._faults.fire("engine.prefill")
-            nxt, _, self._caches = self.decoder._fn("prefill_slots")(
-                self.decoder._device_params(),
-                self.decoder.net._inference_state(), self._caches,
-                jnp.asarray(tokens), jnp.asarray(lengths),
-                jnp.asarray(slot_idx), jnp.asarray(temps),
-                jax.random.fold_in(self._key,
-                                   PREFILL_BATCH_SALT | batch_no))
-            toks = device_fetch(nxt, tag="engine.prefill")  # ONE readback
-            t_pre1 = interval_now()
+            bid = tracing.next_block_id()
+            # a decode block in flight: the prefill queues behind it on
+            # the device, which is then not idle before this dispatch
+            overlapped = self._inflight is not None
+            with self._seam(tracing.ADMIT, bid, m) as adm:
+                self._faults.fire("engine.prefill")
+                nxt, _, self._caches = self.decoder._fn("prefill_slots")(
+                    self.decoder._device_params(),
+                    self.decoder.net._inference_state(), self._caches,
+                    jnp.asarray(tokens), jnp.asarray(lengths),
+                    jnp.asarray(slot_idx), jnp.asarray(temps),
+                    jax.random.fold_in(self._key,
+                                       PREFILL_BATCH_SALT | batch_no))
+            with self._seam(tracing.PREFILL_READBACK, bid, m) as rb:
+                toks = device_fetch(nxt, tag="engine.prefill")  # ONE
+            t_pre0, t_pre1 = adm.t0, rb.t1                  # readback
             fault_col = None
             if self._sentinel_on:
                 # verdict packed with the sampled ids: [M, 2] → split
@@ -3535,7 +3575,7 @@ class SlotGenerationEngine:
             scrub_slots: List[int] = []
             jlog: List[Tuple] = []       # journal appends, written
             #                              OUTSIDE the engine lock below
-            with self._lock:
+            with self._seam(tracing.RETIRE, bid, m) as ret, self._lock:
                 if self._shutdown or self._quarantined:
                     # a drain harvested the batch while we were in the
                     # device call; it owns the requests now — drop our
@@ -3565,6 +3605,7 @@ class SlotGenerationEngine:
                         jlog.append((req.journal_id, len(req.generated),
                                      (tok,)))
                     req.generated.append(tok)
+                    req._emissions.append((t_pre1, 1))
                     # SLO clocks: admitted/first-token stamped ONCE — a
                     # recovered request re-admitting after takeover keeps
                     # its original queue-wait and TTFT
@@ -3577,7 +3618,7 @@ class SlotGenerationEngine:
                         req.trace.add_span("queued", req._submit_t, t_pre0)
                         req.trace.add_span("prefill", t_pre0, t_pre1,
                                            batch=m, bucket=mb, tp=tp,
-                                           ctx=len(ctx))
+                                           ctx=len(ctx), block=bid)
                     if self._req_finished(req, tok):
                         self._m["completed"].inc()
                         finishers.append(req)   # done at the first token
@@ -3591,28 +3632,28 @@ class SlotGenerationEngine:
                 # slot contents changed: the block-decode pipeline must
                 # resync its device carry from host state
                 self._carry = None
-            if self._tracing:       # outside the engine lock (flightrec
-                self._flightrec.record(   # owns its own lock)
-                    "admission", engine=self.engine_id, batch=m,
-                    bucket=mb, tp=tp,
-                    wait_ms=round((t_pre1 - t_pre0) * 1e3, 3))
-            prof = self._prof
-            t_host = interval_now() if prof is not None else t_pre1
-            if jlog:
-                # first tokens journaled BEFORE the finishers complete,
-                # outside the engine lock (GL010) — a done record never
-                # races ahead of the tokens it summarizes
-                self._journal.retired(jlog)
-            t_journal = interval_now() if prof is not None else t_host
-            self._scrub_slots(scrub_slots)
-            self._fail_faulted(faulted, where="prefill")
-            for req in finishers:
-                req._complete()
-            if prof is not None:
-                prof.record_admission(
-                    impl=self._prof_impl("prefill"), count=m,
-                    t_dispatch=t_pre0, t_fetched=t_pre1, t_host=t_host,
-                    t_journal=t_journal, t_publish=interval_now())
+            with self._seam(tracing.JOURNAL, bid) as jn:
+                if self._tracing:   # outside the engine lock (flightrec
+                    self._flightrec.record(   # owns its own lock)
+                        "admission", engine=self.engine_id, batch=m,
+                        bucket=mb, tp=tp,
+                        wait_ms=round((t_pre1 - t_pre0) * 1e3, 3))
+                if jlog:
+                    # first tokens journaled BEFORE the finishers
+                    # complete, outside the engine lock (GL010) — a done
+                    # record never races ahead of the tokens it summarizes
+                    self._journal.retired(jlog)
+            with self._seam(tracing.PUBLISH, bid) as pub:
+                self._scrub_slots(scrub_slots)
+                self._fail_faulted(faulted, where="prefill")
+                for req in finishers:
+                    req._complete()
+            if self._prof is not None:
+                self._prof.record_admission(
+                    impl=self._prof_impl("prefill"), count=m, block=bid,
+                    overlapped=overlapped, t_dispatch=t_pre0,
+                    t_dispatched=adm.t1, t_fetched=t_pre1, t_host=ret.t1,
+                    t_journal=jn.t1, t_publish=pub.t1)
             if drained:
                 return
 
@@ -3769,14 +3810,17 @@ class SlotGenerationEngine:
                     temps[i] = req.temperature
                 self._m["prefills"].inc(m)
                 batch_no = self._m["prefill_batches"].inc()
-            t_pre0 = interval_now()
-            self._faults.fire("engine.prefill")
-            nxt, self._caches = self.decoder.paged_prefill(
-                self._caches, tokens, pos0, valid, ptab, temps,
-                key=jax.random.fold_in(self._key,
-                                       PREFILL_BATCH_SALT | batch_no))
-            toks = device_fetch(nxt, tag="engine.prefill")  # ONE readback
-            t_pre1 = interval_now()
+            bid = tracing.next_block_id()
+            overlapped = self._inflight is not None
+            with self._seam(tracing.ADMIT, bid, m) as adm:
+                self._faults.fire("engine.prefill")
+                nxt, self._caches = self.decoder.paged_prefill(
+                    self._caches, tokens, pos0, valid, ptab, temps,
+                    key=jax.random.fold_in(self._key,
+                                           PREFILL_BATCH_SALT | batch_no))
+            with self._seam(tracing.PREFILL_READBACK, bid, m) as rb:
+                toks = device_fetch(nxt, tag="engine.prefill")  # ONE
+            t_pre0, t_pre1 = adm.t0, rb.t1                  # readback
             fault_col = None
             if self._sentinel_on:
                 fault_col, toks = toks[:, 1], toks[:, 0]
@@ -3787,7 +3831,7 @@ class SlotGenerationEngine:
             to_sum: List[Tuple[np.ndarray, int]] = []
             registered_ctx: Optional[np.ndarray] = None
             jlog: List[Tuple] = []
-            with self._lock:
+            with self._seam(tracing.RETIRE, bid, m) as ret, self._lock:
                 if self._shutdown or self._quarantined:
                     return   # the drain harvested the batch (and
                              # released its page mappings) mid-dispatch
@@ -3820,6 +3864,7 @@ class SlotGenerationEngine:
                         jlog.append((req.journal_id, len(req.generated),
                                      (tok,)))
                     req.generated.append(tok)
+                    req._emissions.append((t_pre1, 1))
                     if req._admitted_t is None:
                         req._admitted_t = t_pre0
                     if req._first_token_t is None:
@@ -3829,7 +3874,8 @@ class SlotGenerationEngine:
                         req.trace.add_span("queued", req._submit_t, t_pre0)
                         req.trace.add_span("prefill", t_pre0, t_pre1,
                                            batch=m, bucket=mb, tp=c,
-                                           ctx=len(ctx), prefix=start)
+                                           ctx=len(ctx), prefix=start,
+                                           block=bid)
                     # publish the context's FULL pages (never written
                     # again: decode lands past the context end) into
                     # the prefix index — the next identical prefix
@@ -3863,38 +3909,38 @@ class SlotGenerationEngine:
                 # slot contents changed: the block-decode pipeline must
                 # resync its device carry from host state
                 self._carry = None
-            if self._tracing:
-                self._flightrec.record(
-                    "admission", engine=self.engine_id, batch=m,
-                    bucket=mb, tp=c, paged=True,
-                    wait_ms=round((t_pre1 - t_pre0) * 1e3, 3))
-            prof = self._prof
-            t_host = interval_now() if prof is not None else t_pre1
-            if jlog:
-                self._journal.retired(jlog)
-            t_journal = interval_now() if prof is not None else t_host
-            self._scrub_pages(scrub)
-            self._fail_faulted(faulted, where="paged_prefill")
-            if to_sum:
-                self._record_page_sums(to_sum)
-            # scripted at-rest corruption (device.corrupt_page, site
-            # "registered"): poison the first page of the chain this
-            # wave just published — the next prefix-cache hit (sampled
-            # verification) or the golden canary must catch it before
-            # any new stream attends the bytes
-            if registered_ctx is not None:
-                plan = self._faults.corruption("device.corrupt_page",
-                                               where="registered")
-                if plan is not None:
-                    self._corrupt_registered_page(registered_ctx,
-                                                  plan["mode"])
-            for req in finishers:
-                req._complete()
-            if prof is not None:
-                prof.record_admission(
-                    impl=self._prof_impl("prefill"), count=m,
-                    t_dispatch=t_pre0, t_fetched=t_pre1, t_host=t_host,
-                    t_journal=t_journal, t_publish=interval_now())
+            with self._seam(tracing.JOURNAL, bid) as jn:
+                if self._tracing:
+                    self._flightrec.record(
+                        "admission", engine=self.engine_id, batch=m,
+                        bucket=mb, tp=c, paged=True,
+                        wait_ms=round((t_pre1 - t_pre0) * 1e3, 3))
+                if jlog:
+                    self._journal.retired(jlog)
+            with self._seam(tracing.PUBLISH, bid) as pub:
+                self._scrub_pages(scrub)
+                self._fail_faulted(faulted, where="paged_prefill")
+                if to_sum:
+                    self._record_page_sums(to_sum)
+                # scripted at-rest corruption (device.corrupt_page, site
+                # "registered"): poison the first page of the chain this
+                # wave just published — the next prefix-cache hit
+                # (sampled verification) or the golden canary must catch
+                # it before any new stream attends the bytes
+                if registered_ctx is not None:
+                    plan = self._faults.corruption("device.corrupt_page",
+                                                   where="registered")
+                    if plan is not None:
+                        self._corrupt_registered_page(registered_ctx,
+                                                      plan["mode"])
+                for req in finishers:
+                    req._complete()
+            if self._prof is not None:
+                self._prof.record_admission(
+                    impl=self._prof_impl("prefill"), count=m, block=bid,
+                    overlapped=overlapped, t_dispatch=t_pre0,
+                    t_dispatched=adm.t1, t_fetched=t_pre1, t_host=ret.t1,
+                    t_journal=jn.t1, t_publish=pub.t1)
             # prefill-role handoffs run AFTER the wave's bookkeeping,
             # still on this serve-loop thread: each export gathers the
             # slot's pages, releases them, and hands the request to the
@@ -3997,39 +4043,42 @@ class SlotGenerationEngine:
                     "nothing in flight to free a page — request shed"))
                 return
         chunk_no = self._m["prefill_chunks"].inc()
-        t0 = interval_now()
+        bid = tracing.next_block_id()
+        overlapped = self._inflight is not None
+        tok = None
+        fault = False
+        with self._seam(tracing.PREFILL_CHUNK, bid, 1) as win:
+            self._faults.fire("engine.prefill")
+            fault_arr = fdev if fdev is not None \
+                else jnp.zeros(1, jnp.int32)
+            if self._pager is not None:
+                nxt, self._caches = self.decoder.paged_prefill(
+                    self._caches, tokens, np.asarray([pos0], np.int32),
+                    np.asarray([valid], np.int32), ptab,
+                    np.asarray([req.temperature], np.float32),
+                    key=jax.random.fold_in(self._key,
+                                           CHUNK_SALT | chunk_no),
+                    fault_in=fault_arr)
+            else:
+                nxt, self._caches = self.decoder._fn(("chunk", c))(
+                    self.decoder._device_params(),
+                    self.decoder.net._inference_state(), self._caches,
+                    jnp.asarray(tokens), jnp.asarray([pos0], jnp.int32),
+                    jnp.asarray([valid], jnp.int32),
+                    jnp.asarray([s], jnp.int32),
+                    jnp.asarray([req.temperature], jnp.float32),
+                    jax.random.fold_in(self._key, CHUNK_SALT | chunk_no),
+                    fault_arr)
+            if final:
+                arr = device_fetch(nxt, tag="engine.prefill")
+                if self._sentinel_on:
+                    tok, fault = int(arr[0, 0]), bool(arr[0, 1])
+                else:
+                    tok = int(arr[0])
+        t0, t1 = win.t0, win.t1
         if req._admitted_t is None:
             req._admitted_t = t0          # SLO queue-wait ends at the
         #                                   FIRST window's dispatch
-        self._faults.fire("engine.prefill")
-        fault_arr = fdev if fdev is not None \
-            else jnp.zeros(1, jnp.int32)
-        if self._pager is not None:
-            nxt, self._caches = self.decoder.paged_prefill(
-                self._caches, tokens, np.asarray([pos0], np.int32),
-                np.asarray([valid], np.int32), ptab,
-                np.asarray([req.temperature], np.float32),
-                key=jax.random.fold_in(self._key, CHUNK_SALT | chunk_no),
-                fault_in=fault_arr)
-        else:
-            nxt, self._caches = self.decoder._fn(("chunk", c))(
-                self.decoder._device_params(),
-                self.decoder.net._inference_state(), self._caches,
-                jnp.asarray(tokens), jnp.asarray([pos0], jnp.int32),
-                jnp.asarray([valid], jnp.int32),
-                jnp.asarray([s], jnp.int32),
-                jnp.asarray([req.temperature], jnp.float32),
-                jax.random.fold_in(self._key, CHUNK_SALT | chunk_no),
-                fault_arr)
-        tok = None
-        fault = False
-        if final:
-            arr = device_fetch(nxt, tag="engine.prefill")
-            if self._sentinel_on:
-                tok, fault = int(arr[0, 0]), bool(arr[0, 1])
-            else:
-                tok = int(arr[0])
-        t1 = interval_now()
         if self._tracing:
             self._flightrec.record(
                 "prefill_chunk", engine=self.engine_id, slot=s,
@@ -4041,7 +4090,8 @@ class SlotGenerationEngine:
             # still anchors the bubble account — it keeps the device
             # busy between decode blocks either way
             self._prof.record_chunk(t_dispatch=t0, t_done=t1,
-                                    final=final)
+                                    final=final, block=bid,
+                                    overlapped=overlapped)
         jlog: List[Tuple] = []
         finish = None
         faulted: List[GenerationRequest] = []
@@ -4085,6 +4135,7 @@ class SlotGenerationEngine:
                     jlog.append((req.journal_id, len(req.generated),
                                  (tok,)))
                 req.generated.append(tok)
+                req._emissions.append((t1, 1))
                 if req._first_token_t is None:
                     req._first_token_t = t1
                 self._m["emitted_tokens"].inc()
@@ -4115,7 +4166,7 @@ class SlotGenerationEngine:
                     self._carry = None
         if req.trace is not None:
             req.trace.add_span("prefill_chunk", t0, t1, pos0=pos0,
-                               valid=valid, final=final)
+                               valid=valid, final=final, block=bid)
         if jlog:
             # first token journaled before the finisher completes,
             # outside the engine lock (GL010) — same contract as _admit
@@ -4180,28 +4231,25 @@ class SlotGenerationEngine:
             step_no = self._step_no
         if not active:
             return                # lifecycle enforcement freed every slot
-        t_disp = interval_now()
-        self._faults.fire("engine.step")
-        nxt, _, self._caches = self.decoder.decode_step(
-            self._caches, self._last_ids,
-            np.minimum(self._positions, self.t_max - 1), self._temps,
-            key=jax.random.fold_in(self._key, ENGINE_KEY_SALT | step_no))
-        nxt_host = device_fetch(nxt, tag="engine.decode")
-        t_ret = interval_now()
-        with self._lock:
-            self._ewma_locked("_est_step", t_ret - t_disp)
-        if self._tracing:
-            self._h_block.observe(t_ret - t_disp)
-            self._flightrec.record("block_retire", engine=self.engine_id,
-                                   k=1, ms=round((t_ret - t_disp) * 1e3,
-                                                 3))
+        bid = tracing.next_block_id()
+        with self._seam(tracing.DISPATCH_BLOCK, bid, k=1) as disp:
+            self._faults.fire("engine.step")
+            nxt, _, self._caches = self.decoder.decode_step(
+                self._caches, self._last_ids,
+                np.minimum(self._positions, self.t_max - 1), self._temps,
+                key=jax.random.fold_in(self._key,
+                                       ENGINE_KEY_SALT | step_no))
+        with self._seam(tracing.BLOCK_READBACK, bid, k=1) as rb:
+            nxt_host = device_fetch(nxt, tag="engine.decode")
+        t_disp, t_ret = disp.t0, rb.t1
         finished: List[GenerationRequest] = []
         jlog: List[Tuple] = []
         # token appends and slot frees are one critical section: a
         # concurrent quarantine() either runs before (we see empty slots
         # and append nothing) or after (it harvests the post-append
         # state) — a recovered request never loses or duplicates a token
-        with self._lock:
+        with self._seam(tracing.RETIRE, bid, k=1) as ret, self._lock:
+            self._ewma_locked("_est_step", t_ret - t_disp)
             self._m["host_readbacks"].inc()
             emitted = 0
             qdepth = len(self._pending)
@@ -4215,33 +4263,39 @@ class SlotGenerationEngine:
                     jlog.append((req.journal_id, len(req.generated),
                                  (tok,)))
                 req.generated.append(tok)
+                req._emissions.append((t_ret, 1))
                 emitted += 1
                 self._positions[s] += 1
                 self._last_ids[s] = tok
                 if req.trace is not None:
-                    req.trace.add_span("decode_block", t_disp, t_ret, k=1)
+                    req.trace.add_span("decode_block", t_disp, t_ret, k=1,
+                                       tokens=1, block=bid)
                 if self._req_finished(req, tok):
                     self._slots[s] = None
                     self._m["completed"].inc()
                     finished.append(req)
             self._m["emitted_tokens"].inc(emitted)
             self._first_step_done = True
-        # phase stamps (ISSUE 13) ride the readback thread, outside the
-        # engine lock, like flightrec — telescoping interval-clock
-        # anchors so the recorded phases sum to the block wall time
-        prof = self._prof
-        t_host = interval_now() if prof is not None else t_ret
-        if jlog:
-            self._journal.retired(jlog)   # one batched append, no locks
-        t_journal = interval_now() if prof is not None else t_host
-        for req in finished:
-            req._complete()
-        if prof is not None:
-            prof.record_block(
+        # the seams' stamps telescope (ISSUE 13): dispatch → fetched →
+        # host → journal → publish, so the recorded phases sum to the
+        # block wall time; sinks are fed outside the engine lock
+        with self._seam(tracing.JOURNAL, bid) as jn:
+            if self._tracing:
+                self._h_block.observe(t_ret - t_disp)
+                self._flightrec.record(
+                    "block_retire", engine=self.engine_id, k=1,
+                    ms=round((t_ret - t_disp) * 1e3, 3))
+            if jlog:
+                self._journal.retired(jlog)   # one batched append
+        with self._seam(tracing.PUBLISH, bid) as pub:
+            for req in finished:
+                req._complete()
+        if self._prof is not None:
+            self._prof.record_block(
                 impl=self._prof_impl("step"), k=1, lanes=emitted,
-                queued=qdepth, t_dispatch=t_disp, t_fetched=t_ret,
-                t_host=t_host, t_journal=t_journal,
-                t_publish=interval_now())
+                queued=qdepth, block=bid, t_dispatch=t_disp,
+                t_dispatched=disp.t1, t_fetched=t_ret, t_host=ret.t1,
+                t_journal=jn.t1, t_publish=pub.t1)
 
     def _step_block(self):
         """One pipelined block cycle (block_size=K): dispatch the next
@@ -4323,25 +4377,30 @@ class SlotGenerationEngine:
             plan = self._faults.corruption("device.corrupt_logits")
             if plan is not None:
                 self._inject_corrupt_logits(plan["mode"], snapshot[0][0])
-            t_disp = interval_now()
-            self._faults.fire("engine.step")
-            if self._pager is not None:
-                toks, ids_d, pos_d, stop_d, self._caches = \
-                    self.decoder.paged_decode_block(
-                        self._caches, ptab, ids, pos, temps,
-                        key=self._key, block_size=k, eos_ids=eos,
-                        stopped=stop, step0=step0,
-                        key_salt=ENGINE_KEY_SALT)
-            else:
-                toks, ids_d, pos_d, stop_d, self._caches = \
-                    self.decoder.decode_block(
-                        self._caches, ids, pos, temps, key=self._key,
-                        block_size=k, eos_ids=eos, stopped=stop,
-                        step0=step0, key_salt=ENGINE_KEY_SALT)
+            bid = tracing.next_block_id()
+            with self._seam(tracing.DISPATCH_BLOCK, bid, len(snapshot),
+                            k) as disp:
+                self._faults.fire("engine.step")
+                if self._pager is not None:
+                    toks, ids_d, pos_d, stop_d, self._caches = \
+                        self.decoder.paged_decode_block(
+                            self._caches, ptab, ids, pos, temps,
+                            key=self._key, block_size=k, eos_ids=eos,
+                            stopped=stop, step0=step0,
+                            key_salt=ENGINE_KEY_SALT)
+                else:
+                    toks, ids_d, pos_d, stop_d, self._caches = \
+                        self.decoder.decode_block(
+                            self._caches, ids, pos, temps, key=self._key,
+                            block_size=k, eos_ids=eos, stopped=stop,
+                            step0=step0, key_salt=ENGINE_KEY_SALT)
             with self._lock:
                 if not (self._quarantined or self._shutdown):
                     self._carry = (ids_d, pos_d, stop_d)
-                    self._inflight = (toks, snapshot, k, t_disp, qdepth)
+                    # the dispatch's seam rides along (its stamps and
+                    # block id); prev still in flight: it overlapped it
+                    self._inflight = (toks, snapshot, k, disp, qdepth,
+                                      prev is not None)
         # prev was dispatched LAST cycle and has been computing since;
         # its fetch + bookkeeping overlap the block dispatched above.
         # With no active lanes left, prev's tokens are pure overshoot
@@ -4424,9 +4483,10 @@ class SlotGenerationEngine:
                                    generated=len(req.generated))
             if self._journal is not None and req.journal_id is not None:
                 self._journal.requeued(req)
-        t_draft = interval_now()
+        bid = tracing.next_block_id()
         dispatch = None
-        with self._lock:
+        with self._seam(tracing.SPEC_DRAFT, bid, k=kd) as drafting, \
+                self._lock:
             if self._quarantined or self._shutdown:
                 return
             snapshot = [(s, self._slots[s]) for s in range(self.num_slots)
@@ -4457,22 +4517,25 @@ class SlotGenerationEngine:
         plan = self._faults.corruption("device.corrupt_logits")
         if plan is not None:
             self._inject_corrupt_logits(plan["mode"], snapshot[0][0])
-        t_disp = interval_now()
-        self._faults.fire("engine.step")
-        if self._pager is not None:
-            toks, _, _, _, self._caches = self.decoder.paged_verify_block(
-                self._caches, ptab, ids, pos, draft, temps,
-                key=self._key, eos_ids=eos, stopped=stop, step0=step0,
-                key_salt=ENGINE_KEY_SALT)
-        else:
-            toks, _, _, _, self._caches = self.decoder.verify_block(
-                self._caches, ids, pos, draft, temps, key=self._key,
-                eos_ids=eos, stopped=stop, step0=step0,
-                key_salt=ENGINE_KEY_SALT)
-        self._retire_spec(toks, snapshot, kd, t_draft, t_disp, qdepth)
+        with self._seam(tracing.DISPATCH_BLOCK, bid, len(snapshot),
+                        kd) as disp:
+            self._faults.fire("engine.step")
+            if self._pager is not None:
+                toks, _, _, _, self._caches = \
+                    self.decoder.paged_verify_block(
+                        self._caches, ptab, ids, pos, draft, temps,
+                        key=self._key, eos_ids=eos, stopped=stop,
+                        step0=step0, key_salt=ENGINE_KEY_SALT)
+            else:
+                toks, _, _, _, self._caches = self.decoder.verify_block(
+                    self._caches, ids, pos, draft, temps, key=self._key,
+                    eos_ids=eos, stopped=stop, step0=step0,
+                    key_salt=ENGINE_KEY_SALT)
+        self._retire_spec(toks, snapshot, kd, drafting.t0, disp.t0, qdepth,
+                          bid)
 
     def _retire_spec(self, toks_dev, snapshot, kd, t_draft, t_disp,
-                     qdepth):
+                     qdepth, bid):
         """Ragged retire of one verify block: fetch the fused [S, K+1
         tokens | emit | (fault)] matrix (ONE host readback) and append
         each lane's accepted prefix — per-lane VARIABLE lengths, with
@@ -4481,23 +4544,20 @@ class SlotGenerationEngine:
         time. Open lanes' positions advance by exactly what they
         emitted (the slab rewind IS this clamp); paged lanes then
         truncate their page tables back to the accepted length."""
-        host = device_fetch(toks_dev, tag="engine.decode")
-        t_ret = interval_now()
+        lanes = len(snapshot)
+        with self._seam(tracing.BLOCK_READBACK, bid, lanes, kd) as rb:
+            host = device_fetch(toks_dev, tag="engine.decode")
+        t_ret = rb.t1
         fault_col = host[:, kd + 2] if self._sentinel_on else None
         emit_col = host[:, kd + 1]
-        if self._tracing:
-            self._h_block.observe(t_ret - t_disp)
-            self._flightrec.record("block_retire", engine=self.engine_id,
-                                   k=kd + 1, lanes=len(snapshot),
-                                   spec=True,
-                                   ms=round((t_ret - t_disp) * 1e3, 3))
         finished: List[GenerationRequest] = []
         faulted: List[GenerationRequest] = []
         scrub: List[int] = []
         scrub_slots: List[int] = []
         jlog: List[Tuple] = []
         drafted = accepted = 0
-        with self._lock:
+        with self._seam(tracing.SPEC_REWIND, bid, lanes, kd) as rew, \
+                self._lock:
             if self._quarantined or self._shutdown:
                 return   # the drain owns the requests; recovery
                          # re-prefills and regenerates these tokens
@@ -4542,13 +4602,14 @@ class SlotGenerationEngine:
                         finished.append(req)
                         closed = True
                         break
+                req._emissions.append((t_ret, took))
                 if self._journal is not None and \
                         req.journal_id is not None and took:
                     jlog.append((req.journal_id, base,
                                  req.generated[base:base + took]))
                 if req.trace is not None:
                     req.trace.add_span("verify_block", t_disp, t_ret,
-                                       k=kd, tokens=took)
+                                       k=kd, tokens=took, block=bid)
                 if not closed:
                     # the accepted length IS the rewind on the slab:
                     # rejected cells sit past the new write-head and are
@@ -4573,53 +4634,55 @@ class SlotGenerationEngine:
             # is that the divisor grows with acceptance
             self._ewma_locked("_est_step",
                               (t_ret - t_disp) / max(1, emitted))
-        t_rewind = interval_now()
-        prof = self._prof
-        t_host = interval_now() if prof is not None else t_rewind
-        if jlog:
-            self._journal.retired(jlog)
-        t_journal = interval_now() if prof is not None else t_host
-        self._scrub_pages(scrub)
-        self._scrub_slots(scrub_slots)
-        self._fail_faulted(faulted, where=f"verify_block{kd}")
-        for req in finished:
-            req._complete()
-        if self._tracing:
-            self._h_spec_draft.observe(max(0.0, t_disp - t_draft))
-        if prof is not None:
-            prof.record_spec(
-                impl=self._prof_impl("verify", kd), k=kd,
-                lanes=len(snapshot), queued=qdepth, accepted=accepted,
-                drafted=drafted, t_draft=t_draft, t_dispatch=t_disp,
-                t_fetched=t_ret, t_rewind=t_rewind, t_host=t_host,
-                t_journal=t_journal, t_publish=interval_now())
+        # spec_rewind holds the whole ragged retire under the lock — the
+        # accepted-length clamp and the page-table rollback are most of
+        # it — so the host phase ends where it does
+        with self._seam(tracing.JOURNAL, bid) as jn:
+            if self._tracing:
+                self._h_block.observe(t_ret - t_disp)
+                self._flightrec.record(
+                    "block_retire", engine=self.engine_id, k=kd + 1,
+                    lanes=lanes, spec=True,
+                    ms=round((t_ret - t_disp) * 1e3, 3))
+                self._h_spec_draft.observe(max(0.0, t_disp - t_draft))
+            if jlog:
+                self._journal.retired(jlog)
+        with self._seam(tracing.PUBLISH, bid) as pub:
+            self._scrub_pages(scrub)
+            self._scrub_slots(scrub_slots)
+            self._fail_faulted(faulted, where=f"verify_block{kd}")
+            for req in finished:
+                req._complete()
+        if self._prof is not None:
+            self._prof.record_spec(
+                impl=self._prof_impl("verify", kd), k=kd, lanes=lanes,
+                queued=qdepth, accepted=accepted, drafted=drafted,
+                block=bid, t_draft=t_draft, t_dispatch=t_disp,
+                t_fetched=t_ret, t_rewind=rew.t1, t_host=rew.t1,
+                t_journal=jn.t1, t_publish=pub.t1)
 
     def _retire_block(self, block):
         """Fetch one block's [S, K] token matrix (ONE host readback) and
         run its host bookkeeping: per-lane appends until a stop, slot
         frees, request completions."""
-        toks_dev, snapshot, k, t_disp, qdepth = block
-        host = device_fetch(toks_dev, tag="engine.decode")
-        t_ret = interval_now()
+        toks_dev, snapshot, k, disp, qdepth, overlapped = block
+        bid, t_disp, lanes = disp.block, disp.t0, len(snapshot)
+        with self._seam(tracing.BLOCK_READBACK, bid, lanes, k) as rb:
+            host = device_fetch(toks_dev, tag="engine.decode")
+        t_ret = rb.t1
         fault_col = None
         if self._sentinel_on:
             # the sentinel verdict is column K of the SAME fetched
             # matrix — still exactly one readback per block
             fault_col = host[:, k]
             host = host[:, :k]
-        with self._lock:
-            self._ewma_locked("_est_step", (t_ret - t_disp) / max(1, k))
-        if self._tracing:
-            self._h_block.observe(t_ret - t_disp)
-            self._flightrec.record("block_retire", engine=self.engine_id,
-                                   k=k, lanes=len(snapshot),
-                                   ms=round((t_ret - t_disp) * 1e3, 3))
         finished: List[GenerationRequest] = []
         faulted: List[GenerationRequest] = []
         scrub: List[int] = []
         scrub_slots: List[int] = []
         jlog: List[Tuple] = []
-        with self._lock:
+        with self._seam(tracing.RETIRE, bid, lanes, k) as ret, self._lock:
+            self._ewma_locked("_est_step", (t_ret - t_disp) / max(1, k))
             if self._quarantined or self._shutdown:
                 return   # the drain owns the requests; recovery
                          # re-prefills and regenerates these tokens
@@ -4668,13 +4731,14 @@ class SlotGenerationEngine:
                         finished.append(req)
                         closed = True
                         break
+                req._emissions.append((t_ret, took))
                 if self._journal is not None and \
                         req.journal_id is not None and took:
                     jlog.append((req.journal_id, base,
                                  req.generated[base:base + took]))
                 if req.trace is not None:
                     req.trace.add_span("decode_block", t_disp, t_ret,
-                                       k=k, tokens=took)
+                                       k=k, tokens=took, block=bid)
                 if not closed:
                     self._positions[s] += k
                     self._last_ids[s] = int(host[s, k - 1])
@@ -4684,32 +4748,37 @@ class SlotGenerationEngine:
                 # freed lanes must not keep decoding from the device
                 # carry: resync (and let _admit refill) next dispatch
                 self._carry = None
-        # phase stamps (ISSUE 13), readback thread, outside the engine
-        # lock: dispatch → fetched → host → journal → publish telescope,
-        # so the per-phase account sums exactly to the block wall time
-        prof = self._prof
-        t_host = interval_now() if prof is not None else t_ret
-        if jlog:
-            # batched per block on the readback thread, OUTSIDE the
-            # engine lock (GL010-clean): one buffer write (and at most
-            # one fsync per the journal's policy) per decode block
-            self._journal.retired(jlog)
-        t_journal = interval_now() if prof is not None else t_host
-        # faulted lanes' pages/cells carry potentially non-finite
-        # residue: zero them before reuse (serve thread — nothing can
-        # map the freed pages / refill the slot until the next
-        # admission on this same thread)
-        self._scrub_pages(scrub)
-        self._scrub_slots(scrub_slots)
-        self._fail_faulted(faulted, where=f"decode_block{k}")
-        for req in finished:
-            req._complete()
-        if prof is not None:
-            prof.record_block(
-                impl=self._prof_impl("block", k), k=k,
-                lanes=len(snapshot), queued=qdepth, t_dispatch=t_disp,
-                t_fetched=t_ret, t_host=t_host, t_journal=t_journal,
-                t_publish=interval_now())
+        # the seams' stamps telescope (ISSUE 13), serve thread, outside
+        # the engine lock: dispatch → fetched → host → journal →
+        # publish, so the per-phase account sums exactly to the block
+        # wall time
+        with self._seam(tracing.JOURNAL, bid) as jn:
+            if self._tracing:
+                self._h_block.observe(t_ret - t_disp)
+                self._flightrec.record(
+                    "block_retire", engine=self.engine_id, k=k,
+                    lanes=lanes, ms=round((t_ret - t_disp) * 1e3, 3))
+            if jlog:
+                # batched per block, OUTSIDE the engine lock
+                # (GL010-clean): one buffer write (and at most one fsync
+                # per the journal's policy) per decode block
+                self._journal.retired(jlog)
+        with self._seam(tracing.PUBLISH, bid) as pub:
+            # faulted lanes' pages/cells carry potentially non-finite
+            # residue: zero them before reuse (serve thread — nothing
+            # can map the freed pages / refill the slot until the next
+            # admission on this same thread)
+            self._scrub_pages(scrub)
+            self._scrub_slots(scrub_slots)
+            self._fail_faulted(faulted, where=f"decode_block{k}")
+            for req in finished:
+                req._complete()
+        if self._prof is not None:
+            self._prof.record_block(
+                impl=self._prof_impl("block", k), k=k, lanes=lanes,
+                queued=qdepth, block=bid, overlapped=overlapped,
+                t_dispatch=t_disp, t_dispatched=disp.t1, t_fetched=t_ret,
+                t_host=ret.t1, t_journal=jn.t1, t_publish=pub.t1)
 
     # -------------------------------------------------------- preemption
     def begin_drain(self) -> None:
@@ -4856,8 +4925,13 @@ class SlotGenerationEngine:
                 if not self._any_active():
                     self._admit()
                 if not self._any_active():
-                    self._work.wait(timeout=0.05)
+                    with self._seam(tracing.IDLE_WAIT) as idle:
+                        self._work.wait(timeout=0.05)
                     self._work.clear()
+                    if self._prof is not None:
+                        # idle for lack of work, not for the host: the
+                        # next dispatch's bubble counts from the wake-up
+                        self._prof.mark_idle(idle.t1)
                     continue
                 self._step()
                 if self.refill:

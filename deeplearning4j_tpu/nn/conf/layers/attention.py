@@ -145,13 +145,16 @@ class SelfAttentionLayer(BaseRecurrentLayerConf):
         attends to them. Returns (out [B, T, n_out], new_cache)."""
         q, k, v = self._project_qkv(params, x)
         out = self._attend(q, k, v, mask, x.dtype)
-        new_cache = {
-            "k": jax.lax.dynamic_update_slice(
-                cache["k"], k.transpose(0, 2, 1, 3).astype(cache["k"].dtype),
-                (0, 0, 0, 0)),
-            "v": jax.lax.dynamic_update_slice(
-                cache["v"], v.transpose(0, 2, 1, 3).astype(cache["v"].dtype),
-                (0, 0, 0, 0))}
+        with jax.named_scope("cache_update"):
+            new_cache = {
+                "k": jax.lax.dynamic_update_slice(
+                    cache["k"],
+                    k.transpose(0, 2, 1, 3).astype(cache["k"].dtype),
+                    (0, 0, 0, 0)),
+                "v": jax.lax.dynamic_update_slice(
+                    cache["v"],
+                    v.transpose(0, 2, 1, 3).astype(cache["v"].dtype),
+                    (0, 0, 0, 0))}
         return self._project_out(params, out), new_cache
 
     # graftlint: traced
@@ -177,13 +180,14 @@ class SelfAttentionLayer(BaseRecurrentLayerConf):
         zero = jnp.zeros((), jnp.int32)   # match pos dtype under x64 mode
         upd = lambda c, u, p: jax.lax.dynamic_update_slice(c, u,
                                                            (zero, p, zero))
-        new_cache = {
-            "k": jax.vmap(upd)(cache["k"],
-                               k.transpose(0, 2, 1, 3).astype(
-                                   cache["k"].dtype), pos),
-            "v": jax.vmap(upd)(cache["v"],
-                               v.transpose(0, 2, 1, 3).astype(
-                                   cache["v"].dtype), pos)}
+        with jax.named_scope("cache_update"):
+            new_cache = {
+                "k": jax.vmap(upd)(cache["k"],
+                                   k.transpose(0, 2, 1, 3).astype(
+                                       cache["k"].dtype), pos),
+                "v": jax.vmap(upd)(cache["v"],
+                                   v.transpose(0, 2, 1, 3).astype(
+                                       cache["v"].dtype), pos)}
         ck, cv = new_cache["k"], new_cache["v"]
         helper = get_helper("decode_attention")
         out = helper(self, q, ck, cv, pos) if helper is not None else None
@@ -237,13 +241,14 @@ class SelfAttentionLayer(BaseRecurrentLayerConf):
             zero = jnp.zeros((), jnp.int32)
             upd = lambda cc, u, p: jax.lax.dynamic_update_slice(
                 cc, u, (zero, p, zero))
-            new_cache = {
-                "k": jax.vmap(upd)(cache["k"],
-                                   k.transpose(0, 2, 1, 3).astype(
-                                       cache["k"].dtype), p0),
-                "v": jax.vmap(upd)(cache["v"],
-                                   v.transpose(0, 2, 1, 3).astype(
-                                       cache["v"].dtype), p0)}
+            with jax.named_scope("cache_update"):
+                new_cache = {
+                    "k": jax.vmap(upd)(cache["k"],
+                                       k.transpose(0, 2, 1, 3).astype(
+                                           cache["k"].dtype), p0),
+                    "v": jax.vmap(upd)(cache["v"],
+                                       v.transpose(0, 2, 1, 3).astype(
+                                           cache["v"].dtype), p0)}
             qpos = p0[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]
         else:
             p0 = jnp.asarray(pos0, jnp.int32).reshape(-1)   # UNclamped
@@ -255,11 +260,12 @@ class SelfAttentionLayer(BaseRecurrentLayerConf):
             # (the slab twin of the paged path's null-page redirect)
             wpos = jnp.where(keep_w, w, t_max)
             rows = jnp.arange(x.shape[0], dtype=jnp.int32)[:, None]
-            new_cache = {
-                "k": cache["k"].at[rows, :, wpos, :].set(
-                    k.astype(cache["k"].dtype), mode="drop"),
-                "v": cache["v"].at[rows, :, wpos, :].set(
-                    v.astype(cache["v"].dtype), mode="drop")}
+            with jax.named_scope("cache_update"):
+                new_cache = {
+                    "k": cache["k"].at[rows, :, wpos, :].set(
+                        k.astype(cache["k"].dtype), mode="drop"),
+                    "v": cache["v"].at[rows, :, wpos, :].set(
+                        v.astype(cache["v"].dtype), mode="drop")}
             qpos = w
         ck, cv = new_cache["k"], new_cache["v"]
         hs = self._head_size()
@@ -338,11 +344,12 @@ class SelfAttentionLayer(BaseRecurrentLayerConf):
         # lands as [B, H, Dh]. Freed/frozen lanes' tables are redirected
         # to the null page — duplicate trash-cell writes race only with
         # each other and the cell is never attended.
-        new_pool = {
-            "k": pool["k"].at[pids, :, offs, :].set(
-                k[:, 0].astype(pool["k"].dtype)),
-            "v": pool["v"].at[pids, :, offs, :].set(
-                v[:, 0].astype(pool["v"].dtype))}
+        with jax.named_scope("cache_update"):
+            new_pool = {
+                "k": pool["k"].at[pids, :, offs, :].set(
+                    k[:, 0].astype(pool["k"].dtype)),
+                "v": pool["v"].at[pids, :, offs, :].set(
+                    v[:, 0].astype(pool["v"].dtype))}
         ck = self._paged_gather(new_pool["k"], ptable)
         cv = self._paged_gather(new_pool["v"], ptable)
         helper = get_helper("paged_decode_attention")
@@ -394,11 +401,12 @@ class SelfAttentionLayer(BaseRecurrentLayerConf):
                                    axis=1)                         # [B,C]
         pids = jnp.where(keep_w, pids, 0)           # null-page redirect
         offs = jnp.where(keep_w, w % ps, 0)
-        new_pool = {
-            "k": pool["k"].at[pids, :, offs, :].set(
-                k.astype(pool["k"].dtype)),
-            "v": pool["v"].at[pids, :, offs, :].set(
-                v.astype(pool["v"].dtype))}
+        with jax.named_scope("cache_update"):
+            new_pool = {
+                "k": pool["k"].at[pids, :, offs, :].set(
+                    k.astype(pool["k"].dtype)),
+                "v": pool["v"].at[pids, :, offs, :].set(
+                    v.astype(pool["v"].dtype))}
         ck = self._paged_gather(new_pool["k"], ptable)
         cv = self._paged_gather(new_pool["v"], ptable)
         hs = self._head_size()

@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ...observability import tracing
+from ...observability.tracing import Seam
 from ...ops import rng as rngmod
 from ..helpers import get_helper
 from ..multilayer import _nz
@@ -23,6 +25,18 @@ from ...ops.updaters import make_updater, normalize_gradient, schedule_lr
 from .fusion import build_fusion_plan
 from .graph_config import ComputationGraphConfiguration
 from .vertices import LayerVertex
+
+
+def scoped(names):
+    """Each vertex name in turn, inside ``jax.named_scope(name)`` while the
+    caller's loop body runs: a walk over the graph then stamps every
+    operation with its vertex (``embed``, ``attn3``, ``ffn3``, ``lnf``,
+    ``out``) in the HLO metadata — op_name, which the device trace carries
+    as each operation's tf_op — forward and backward. Metadata only: the
+    compiled instructions are the same."""
+    for name in names:
+        with jax.named_scope(name):
+            yield name
 
 
 class ComputationGraph:
@@ -147,7 +161,7 @@ class ComputationGraph:
         out_set = set(self.conf.network_outputs) if output_preout else set()
         fusion_plan, fusion_skip = self._get_fusion_plan() if train \
             else ({}, set())
-        for idx, name in enumerate(self.conf.topological_order):
+        for idx, name in enumerate(scoped(self.conf.topological_order)):
             if name in fusion_skip:
                 # computed by a fused pattern at its activation vertex
                 new_state.setdefault(name, state[name])
@@ -321,8 +335,9 @@ class ComputationGraph:
                 x = last_in[out_name]
                 if lmask is None and x.ndim == 3:
                     lmask = masks.get(out_name)
-                score = score + fused_sparse_ce_score(params[out_name], x, y,
-                                                      lmask)
+                with jax.named_scope("loss"):
+                    score = score + fused_sparse_ce_score(
+                        params[out_name], x, y, lmask)
                 continue
             from ...kernels.fused_ce import (_MCXENT_LOSSES,
                                              sparse_shaped)
@@ -339,8 +354,9 @@ class ComputationGraph:
             pre = preouts[out_name]
             if lmask is None and pre.ndim == 3:
                 lmask = masks.get(out_name)
-            score = score + v.layer.compute_score(params[out_name], y, pre,
-                                                  lmask)
+            with jax.named_scope("loss"):
+                score = score + v.layer.compute_score(params[out_name], y,
+                                                      pre, lmask)
         return score, new_state
 
     def _make_train_step(self, with_rnn_carry: bool = False):
@@ -369,24 +385,27 @@ class ComputationGraph:
                     continue
                 v = conf.vertices[name]
                 layer = v.layer if isinstance(v, LayerVertex) else None
-                if layer is not None:
-                    g = normalize_gradient(
-                        g, layer.gradient_normalization,
-                        _nz(layer.gradient_normalization_threshold, 1.0))
-                lr = schedule_lr(
-                    _nz(layer.learning_rate if layer else None, 0.1),
-                    conf.lr_policy, it_f,
-                    decay_rate=conf.lr_policy_decay_rate,
-                    steps=conf.lr_policy_steps, power=conf.lr_policy_power,
-                    max_iterations=float(conf.max_iterations or 1),
-                    schedule=conf.learning_rate_schedule)
-                upd = self.updaters[name]
-                np_, nu = {}, {}
-                for pname, grad in g.items():
-                    step, nstate = upd.update(grad, upd_state[name][pname],
-                                              lr, it_f)
-                    np_[pname] = params[name][pname] - step
-                    nu[pname] = nstate
+                with jax.named_scope("updater"), jax.named_scope(name):
+                    if layer is not None:
+                        g = normalize_gradient(
+                            g, layer.gradient_normalization,
+                            _nz(layer.gradient_normalization_threshold,
+                                1.0))
+                    lr = schedule_lr(
+                        _nz(layer.learning_rate if layer else None, 0.1),
+                        conf.lr_policy, it_f,
+                        decay_rate=conf.lr_policy_decay_rate,
+                        steps=conf.lr_policy_steps,
+                        power=conf.lr_policy_power,
+                        max_iterations=float(conf.max_iterations or 1),
+                        schedule=conf.learning_rate_schedule)
+                    upd = self.updaters[name]
+                    np_, nu = {}, {}
+                    for pname, grad in g.items():
+                        step, nstate = upd.update(
+                            grad, upd_state[name][pname], lr, it_f)
+                        np_[pname] = params[name][pname] - step
+                        nu[pname] = nstate
                 new_params[name] = np_
                 new_upd[name] = nu
             return new_params, new_upd, new_state, score
@@ -432,25 +451,36 @@ class ComputationGraph:
         return self._jit_cache[key]
 
     def fit_batch(self, ds):
+        """One training step. Its seams (observability.tracing.Seam) land
+        on a profiler session's trace: ``dl4j.train.stage`` (the batch to
+        device arrays), ``dl4j.train.step`` (a StepTraceAnnotation around
+        the step's dispatch, numbered by the iteration) and
+        ``dl4j.train.readback`` (listeners, which read the loss)."""
         self._ensure_init()
         self.last_input_batch = ds    # probe data for flow/debug listeners
-        inputs = self._inputs_dict(ds.features)
-        if self.conf.backprop_type == "truncated_bptt" and \
+        with Seam(tracing.TRAIN_STAGE, self.iteration):
+            inputs = self._inputs_dict(ds.features)
+            tbptt = self.conf.backprop_type == "truncated_bptt" and \
                 (self.conf.tbptt_fwd_length or 0) > 0 and \
-                any(v.ndim == 3 for v in inputs.values()):
+                any(v.ndim == 3 for v in inputs.values())
+            if not tbptt:
+                labels = self._labels_dict(ds.labels)
+                imasks, lmasks = self._masks_of(ds)
+        if tbptt:
             self._fit_tbptt(ds)
             return
-        labels = self._labels_dict(ds.labels)
-        imasks, lmasks = self._masks_of(ds)
-        step = self._get_train_step(False)
-        self.params, self.updater_state, new_states, score = step(
-            self.params, self.updater_state, self.state, inputs, labels,
-            imasks, lmasks, self.iteration, {})
-        self.state = self._strip_rnn_carry(new_states)
-        self.score_value = score  # device scalar; sync deferred to reader
-        self.iteration += 1
-        for lst in self.listeners:
-            lst.iteration_done(self, self.iteration)
+        with Seam(tracing.TRAIN_STEP, self.iteration, step=True):
+            step = self._get_train_step(False)
+            self.params, self.updater_state, new_states, score = step(
+                self.params, self.updater_state, self.state, inputs, labels,
+                imasks, lmasks, self.iteration, {})
+            self.state = self._strip_rnn_carry(new_states)
+            self.score_value = score  # device scalar; sync deferred
+            self.iteration += 1
+        if self.listeners:
+            with Seam(tracing.TRAIN_READBACK, self.iteration):
+                for lst in self.listeners:
+                    lst.iteration_done(self, self.iteration)
 
     @staticmethod
     def _slice_time(d: Optional[Dict], start: int, end: int,

@@ -16,17 +16,29 @@ hot path and everything around it:
   threaded through consume → admission → prefill → decode blocks →
   publish, carried ACROSS EngineSupervisor takeovers (one trace per
   request, a ``takeover`` span marking each restart), with a fixed
-  :class:`TraceRing` of completed traces.
+  :class:`TraceRing` of completed traces (``rolled_past(t)`` says when
+  it no longer reaches back to ``t``). And :class:`Seam`, the ONE stamp
+  source of the engine loop and of ``fit_batch``: each seam of the fixed
+  list :data:`SEAMS` (``dl4j.engine.admit``, ``.prefill_readback``,
+  ``.prefill_chunk``, ``.dispatch_block``, ``.block_readback``,
+  ``.retire``, ``.journal``, ``.publish``, ``.idle_wait``,
+  ``.spec_draft``, ``.spec_rewind``; ``dl4j.train.step``, ``.stage``,
+  ``.readback``) takes ``interval_now()`` once at entry and once at
+  exit, and feeds two sinks — in process the profiler's phase account
+  and the requests' spans (each naming the engine ``block`` that
+  produced it), and under a ``jax.profiler`` session a
+  ``TraceAnnotation`` of the same name on ``/host:CPU`` of the xplane,
+  on the device events' clock. A request's own clocks are public:
+  ``GenerationRequest.clocks()`` (created / admitted / first_token /
+  done) and ``.emissions()`` (when how many tokens became visible).
 - :mod:`.slo` — :class:`SLOTracker`: per-request deadline headroom,
   queue-wait, and TTFT accounting with rolling short/long-window
   attainment and burn rate, per-route and per-replica, riding on the
   request clocks the engine stamps (which survive takeovers and
   migrations — the clock never resets).
 - :mod:`.devstats` — device-side cost accounting sampled off the hot
-  path: device memory / live-array census, exact per-engine KV-cache
-  bytes from the live cache leaves, and per-impl XLA cost analysis
-  (flops/bytes per ``prefill``/``decode_block{K}``/``prefill_slots``,
-  per mesh tag).
+  path: device memory / live-array census and exact per-engine
+  KV-cache bytes from the live cache leaves.
 - :mod:`.flightrec` — :class:`FlightRecorder`: a bounded structured
   event ring (admission, block retire, shed, takeover, migration,
   reconnect, fault) with post-mortem JSON artifacts bundling events +
@@ -35,11 +47,10 @@ hot path and everything around it:
 - :mod:`.profiler` — :class:`PhaseProfiler`: hot-loop phase/bubble
   accounting (device/host/journal/publish decomposition per decode
   block — phases sum to block wall time — plus pipeline-bubble and
-  lane-bubble measures) and the roofline join of devstats' theoretical
-  flops/bytes with MEASURED steady block durations: attained GFLOP/s,
-  GB/s, arithmetic intensity, and a memory-/compute-bound verdict per
-  impl per mesh tag, with a bounded :class:`PhaseTimeline` ring that
-  survives supervisor engine rebuilds.
+  lane-bubble measures), with a bounded :class:`PhaseTimeline` ring
+  (8192 records) that survives supervisor engine rebuilds and is summed
+  over any window by ``PhaseProfiler.between(t0, t1)`` (``truncated``
+  when the ring no longer reaches back to ``t0``).
 - :mod:`.telemetry` — :class:`TelemetryServer`, a background HTTP
   endpoint (``/metrics``, ``/snapshot``, ``/slo``, ``/profile``,
   ``/traces/recent``) reusing the training UI's HTTP plumbing.
@@ -56,8 +67,7 @@ metric/trace/SLO/flight-recorder record call that drifts into
 jit-traced code.
 """
 
-from .devstats import (DeviceStats, device_memory_snapshot,
-                       impl_cost_analysis, kv_cache_stats)
+from .devstats import DeviceStats, device_memory_snapshot, kv_cache_stats
 from .flightrec import FlightRecorder, default_flight_recorder
 from .integrity import (GoldenCanary, IntegrityConfig, NumericalFault,
                         PageVerifier)
@@ -67,17 +77,17 @@ from .profiler import (EngineChannel, PhaseProfiler, PhaseTimeline,
                        default_profiler)
 from .slo import SLORecord, SLOTracker, default_slo_tracker
 from .telemetry import TelemetryServer
-from .tracing import (Span, Trace, TraceRing, default_trace_ring,
-                      interval_now)
+from .tracing import (SEAMS, Seam, Span, Trace, TraceRing,
+                      default_trace_ring, interval_now)
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
     "DEFAULT_LATENCY_BUCKETS", "default_registry", "percentiles",
-    "Span", "Trace", "TraceRing", "default_trace_ring", "interval_now",
+    "SEAMS", "Seam", "Span", "Trace", "TraceRing", "default_trace_ring",
+    "interval_now",
     "EngineChannel", "PhaseProfiler", "PhaseTimeline", "default_profiler",
     "SLORecord", "SLOTracker", "default_slo_tracker",
-    "DeviceStats", "device_memory_snapshot", "impl_cost_analysis",
-    "kv_cache_stats",
+    "DeviceStats", "device_memory_snapshot", "kv_cache_stats",
     "FlightRecorder", "default_flight_recorder",
     "GoldenCanary", "IntegrityConfig", "NumericalFault", "PageVerifier",
     "TelemetryServer",

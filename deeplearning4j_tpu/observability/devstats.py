@@ -1,6 +1,6 @@
 """Device-side cost accounting, sampled OFF the serving hot path.
 
-Three accounts the adaptive policies (ROADMAP items 2-3) need before
+Two accounts the adaptive policies (ROADMAP items 2-3) need before
 they can size anything:
 
 - **Device memory** — per-device allocator stats from
@@ -18,21 +18,6 @@ they can size anything:
   (addressable) bytes, and the shard count, so a (data, tp) mesh's
   dominant allocation is attributable per chip — the number the paged
   KV cache (ROADMAP item 2) must fit under.
-
-- **Per-impl static cost** — flops / bytes-accessed from XLA's cost
-  analysis for every compiled decode impl (``prefill`` /
-  ``decode_block{K}`` / ``prefill_slots`` / ``decode_step``, per mesh
-  tag): the measured-cost table μ-cuDNN-style block-size policies read
-  instead of guessing, and the THEORETICAL side of the roofline join —
-  ``observability/profiler.py`` divides these flops/bytes by its
-  measured steady per-step durations to report attained GFLOP/s / GB/s
-  and the bound-class verdict at ``GET /profile`` (note: XLA counts a
-  ``lax.scan`` body once, so ``decode_block{K}`` rows are per STEP). The decoder captures each impl's abstract arg
-  signature at its FIRST dispatch (one dict lookup per call, host-side);
-  cost extraction then lowers from those specs on demand. Lowering logs
-  one compile record per impl the first time (cached after), so cost
-  capture belongs OUTSIDE compile-audited steady-state windows — call
-  it once after warmup, as the telemetry server does.
 
 Everything here is host-side observation: nothing dispatches device
 work, nothing runs under jit (graftlint GL015 rejects devstats calls in
@@ -140,58 +125,9 @@ def kv_cache_stats(engine) -> dict:
     return out
 
 
-def impl_cost_analysis(decoder, refresh: bool = False) -> Dict[str, dict]:
-    """flops / bytes-accessed per compiled impl, from XLA cost analysis
-    over each impl's first-dispatch signature (the decoder's
-    ``_cost_seam``). Memoized on the seam: the lowering (one logged
-    compile record per impl, cached by jax afterwards) happens at most
-    once per impl per process — run this after warmup, outside any
-    steady-state compile-audit window."""
-    seam = getattr(decoder, "_cost_seam", None)
-    if not seam:
-        return {}
-    out: Dict[str, dict] = {}
-    for name, entry in sorted(seam.items()):
-        jitted, specs, cost = entry
-        if specs is None:
-            continue                      # never dispatched: nothing real
-        if cost is None or refresh:
-            cost = _cost_from_specs(jitted, specs)
-            entry[2] = cost
-        out[name] = cost
-    return out
-
-
-def _cost_from_specs(jitted, specs) -> dict:
-    try:
-        lowered = jitted.lower(*specs)
-    except Exception as e:   # noqa: BLE001 — cost is best-effort telemetry
-        return {"error": f"lower: {type(e).__name__}: {e}"[:200]}
-    ca = None
-    try:
-        ca = lowered.compile().cost_analysis()
-    except Exception:   # noqa: BLE001 — fall back to the pre-compile view
-        try:
-            ca = lowered.cost_analysis()
-        except Exception as e:   # noqa: BLE001
-            return {"error": f"cost_analysis: {type(e).__name__}"[:200]}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else None
-    if not isinstance(ca, dict):
-        return {"error": "cost_analysis unavailable on this backend"}
-    out = {}
-    for key, label in (("flops", "flops"),
-                       ("bytes accessed", "bytes_accessed"),
-                       ("transcendentals", "transcendentals")):
-        v = ca.get(key)
-        if v is not None:
-            out[label] = int(v)
-    return out
-
-
 class DeviceStats:
     """Aggregating view: engines attach once; ``snapshot()`` assembles
-    device memory + per-engine KV bytes + per-impl cost on demand.
+    device memory + per-engine KV bytes on demand.
 
     Registry integration: ``devstats_live_array_bytes`` /
     ``devstats_live_arrays`` gauges (collection-time callbacks) and a
@@ -227,7 +163,6 @@ class DeviceStats:
     def snapshot(self) -> dict:
         out = device_memory_snapshot()
         kv = {}
-        costs = {}
         with self._lock:
             engines = dict(self._engines)
         for name, wref in sorted(engines.items()):
@@ -238,15 +173,7 @@ class DeviceStats:
                 kv[name] = kv_cache_stats(eng)
             except Exception as e:   # noqa: BLE001 — degrade per engine
                 kv[name] = {"error": f"{type(e).__name__}: {e}"[:200]}
-            dec = getattr(eng, "decoder", None)
-            if dec is not None:
-                try:
-                    costs.update(impl_cost_analysis(dec))
-                except Exception as e:   # noqa: BLE001
-                    costs[name] = {"error":
-                                   f"{type(e).__name__}: {e}"[:200]}
         out["kv_cache"] = kv
-        out["impl_cost"] = costs
         return out
 
 

@@ -1,66 +1,71 @@
-"""Hot-loop phase profiler: pipeline phase/bubble accounting + roofline
-attainment for the decode serving path.
+"""Hot-loop phase profiler: pipeline phase/bubble accounting for the
+decode serving path — the in-process sink of the engine loop's seams
+(``observability.tracing.Seam``), beside their mirror onto the device
+trace's clock. An operator reads it at ``GET /profile`` in production,
+where a profiler trace cannot be taken (stopping one holds the
+interpreter for some 50 s a traced second, PERF.md); the benchmark reads
+a window of it through :meth:`PhaseProfiler.between`.
 
-r14's devstats reports *theoretical* per-impl flops/bytes from XLA cost
-analysis; nothing measured where decode wall-clock actually goes. Every
-ROADMAP perf item (speculative decoding, disaggregation, the quantized/
-Pallas fast path) gates on exactly that measurement — µ-cuDNN's lesson
-is that kernel-level choices only pay off when utilization is measured
-per primitive. This module is the instrument:
+- **Phase decomposition** — the engine hands the seams' stamps of each
+  decode-block retire cycle (dispatch → ``device_fetch`` returns → host
+  bookkeeping done → journal append done → completion publishes done) to
+  :meth:`EngineChannel.record_block`, which turns them into a
+  telescoping decomposition: ``device`` (dispatch → data ready — the
+  block_until_ready delta on the retired carry), ``host``, ``journal``,
+  ``publish``. The four phases sum EXACTLY to the block's wall time
+  (t_publish − t_dispatch) by construction — the exactness tests pin
+  that. Batched/paged admission and chunked-prefill windows get the same
+  treatment (``kind="admission"`` / ``"chunk"``).
 
-- **Phase decomposition** — the engine stamps interval-clock times at
-  the natural seams of each decode-block retire cycle (dispatch →
-  ``device_fetch`` returns → host bookkeeping done → journal append done
-  → completion publishes done) and :meth:`EngineChannel.record_block`
-  turns them into a telescoping decomposition: ``device`` (dispatch →
-  data ready — the block_until_ready delta on the retired carry),
-  ``host``, ``journal``, ``publish``. The four phases sum EXACTLY to the
-  block's wall time (t_publish − t_dispatch) by construction — the
-  exactness tests pin that. Batched/paged admission and chunked-prefill
-  windows get the same treatment (``kind="admission"`` / ``"chunk"``).
-
-- **Pipeline bubble** — ``max(0, t_dispatch − t_last_device_done)``:
-  the gap between the previous device completion (block retire, prefill
-  readback, chunk dispatch) and the next dispatch, i.e. time the device
-  certainly sat idle waiting on the host. The r9 double buffer exists
-  to drive this to zero (block t+1 is dispatched BEFORE block t's
-  readback): K>1 steady decode shows ~0 bubble, the K=1 legacy loop
-  shows one host-bookkeeping bubble per step. Recorded per block into
-  its own histogram; ``bubble_pct = bubble / (bubble + device)``.
+- **Pipeline bubble** — time the device CERTAINLY sat idle before a
+  dispatch, waiting on the host: ``max(0, t_dispatch − t_done)`` where
+  ``t_done`` is the readback of the work dispatched LAST before it, and
+  0 where other work was still in flight at the dispatch
+  (``overlapped``: the double buffer dispatches block t+1 before block
+  t's readback; an admission's prefill queues behind the block in
+  flight). A serve loop that slept for lack of work re-anchors at its
+  wake-up (:meth:`EngineChannel.mark_idle`), so an empty queue is not a
+  bubble. Each record also says what the device had done last
+  (``after``: ``block`` / ``admission`` / ``chunk`` / ``idle``), so the
+  idle stretch that follows an admission — prefill read back, its
+  bookkeeping, the stale block's retire, the next dispatch — is told
+  apart from the one after a freed lane. The dispatch call's own
+  duration rides alongside (``dispatch_ms``): with nothing in flight the
+  device cannot start before the call returns, and a dispatch from host
+  state spends 2–3 ms converting its arguments (PERF.md), so a reader
+  adds it to the bubble for the idle stretch as the host saw it. K>1
+  steady decode shows ~0 bubble, the K=1 legacy loop one
+  host-bookkeeping bubble per step. ``bubble_pct = bubble / (bubble +
+  device)``.
 
 - **Lane bubble** — idle cache slots × block device time while work was
   QUEUED, over total slot-time: the continuous-batching waste measure
   (``refill=False`` static waves strand finished lanes until the wave
   drains, so their lane-bubble is strictly higher — gated in tests).
 
-- **Roofline attainment** — joins devstats' per-impl ``cost_analysis``
-  flops/bytes with the MEASURED steady block durations: attained
-  GFLOP/s, GB/s, arithmetic intensity, and a memory-/compute-bound
-  verdict per impl per mesh tag (impl keys carry the ``__m<data>x<tp>``
-  suffix, so the join lines up with devstats and CompileAudit row for
-  row). Peaks come from ``DL4J_TPU_PEAK_GFLOPS`` / ``DL4J_TPU_PEAK_GBS``
-  (or constructor args); without them the verdict falls back to
-  comparing arithmetic intensity against an assumed ridge point.
-
 - **PhaseTimeline** — a bounded ring of per-block phase records (newest
-  last): the forensic view ``GET /profile?timeline=N`` serves. The ring
-  lives on the PROFILER, not the engine, so it survives a supervisor
-  engine rebuild (the supervisor passes the profiler through, exactly
-  like the SLO tracker) — chaos_soak ``--profile`` asserts that.
+  last; 8192 entries, 13 minutes of 95 ms blocks): the forensic view
+  ``GET /profile?timeline=N`` serves, and what
+  :meth:`PhaseProfiler.between` sums over a window, saying ``truncated``
+  when the ring no longer reaches back to the window's start. Every
+  entry carries the ``block`` id of its dispatch
+  (``tracing.next_block_id``), which the requests' spans name too. The
+  ring lives on the PROFILER, not the engine, so it survives a
+  supervisor engine rebuild (the supervisor passes the profiler
+  through, exactly like the SLO tracker) — chaos_soak ``--profile``
+  asserts that.
 
-Overhead contract (the ≤5% A/B bar, gated in tests): recording is
-host-side interval-clock stamps plus O(#phases) histogram observes per
-BLOCK (not per token), the ring is bounded, and nothing here touches
-the device or runs under jit — graftlint GL016 statically rejects
-profiler/phase-stamp recording calls inside jit-traced or shard_map
-code, the same gate GL008/GL015 give the other sinks.
+Overhead contract (exact-count tests): recording is the seams' stamps
+plus O(#phases) histogram observes per BLOCK (not per token), the ring
+is bounded, and nothing here touches the device or runs under jit —
+graftlint GL016 statically rejects profiler/phase-stamp recording calls
+inside jit-traced or shard_map code, the same gate GL008/GL015 give the
+other sinks.
 """
 
 from __future__ import annotations
 
-import os
 import threading
-import weakref
 from collections import deque
 from typing import Dict, List, Optional
 
@@ -70,17 +75,15 @@ from .metrics import MetricsRegistry, default_registry
 #: the block's wall time); ``bubble`` rides alongside, not inside
 PHASES = ("device", "host", "journal", "publish")
 
-#: assumed roofline ridge point (flops/byte) when no hardware peaks are
-#: configured: below it a kernel is called memory-bound. ~8 flops/byte
-#: is a conservative accelerator-class ridge (TPUv4 ~240, H100 ~295,
-#: a desktop CPU ~5-10) — configure real peaks for a real verdict.
-DEFAULT_RIDGE_FLOPS_PER_BYTE = 8.0
-
 #: fine-grained phase buckets (seconds): decode phases live in the
 #: 10µs..1s decade; the registry default ladder starts at 100µs
 PHASE_BUCKETS = (1e-5, 2.5e-5, 5e-5, 1e-4, 2.5e-4, 5e-4, 1e-3, 2.5e-3,
                  5e-3, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0,
                  10.0, 30.0)
+
+
+def _ms(t0: float, t1: Optional[float]) -> float:
+    return 0.0 if t1 is None else (t1 - t0) * 1e3
 
 
 class PhaseTimeline:
@@ -89,16 +92,59 @@ class PhaseTimeline:
     ever recorded, so a ring that survived an engine rebuild shows
     continuity even after old entries rotate out."""
 
-    def __init__(self, capacity: int = 256):
+    def __init__(self, capacity: int = 8192):
         self.capacity = int(capacity)
         self._lock = threading.Lock()
         self._ring: deque = deque(maxlen=self.capacity)
         self._added = 0
+        self._lost_until: Optional[float] = None   # newest evicted "t"
 
     def add(self, entry: dict) -> None:
         with self._lock:
+            if len(self._ring) == self.capacity:
+                lost = self._ring[0]["t"]
+                if self._lost_until is None or lost > self._lost_until:
+                    self._lost_until = lost
             self._ring.append(entry)
             self._added += 1
+
+    def between(self, t0: float, t1: float,
+                engine: Optional[str] = None) -> dict:
+        """Sums over the records whose dispatch fell in ``[t0, t1)`` on
+        the interval clock (one engine's, or every engine's): per record
+        kind the count, each phase, the bubble and the dispatch calls'
+        time; and the last two again by what the device had done last
+        (``bubble_after``: only dispatches with nothing in flight, so
+        bubble + dispatch is there the idle stretch as the host saw it).
+        ``truncated`` is true when a record dispatched at or after ``t0``
+        has rotated out: the sums are then of a part of the window."""
+        with self._lock:
+            items = list(self._ring)
+            truncated = self._lost_until is not None and \
+                self._lost_until >= t0
+        kinds: Dict[str, dict] = {}
+        after: Dict[str, dict] = {}
+        for e in items:
+            if not t0 <= e["t"] < t1 or \
+                    (engine is not None and e["engine"] != engine):
+                continue
+            acc = kinds.setdefault(e["kind"], {
+                "n": 0, "bubble_seconds": 0.0, "dispatch_seconds": 0.0,
+                "phase_seconds": {}})
+            for p, v in e["phases_ms"].items():
+                acc["phase_seconds"][p] = \
+                    acc["phase_seconds"].get(p, 0.0) + v / 1e3
+            accs = [acc]
+            if e.get("after") is not None:
+                accs.append(after.setdefault(e["after"], {
+                    "n": 0, "bubble_seconds": 0.0,
+                    "dispatch_seconds": 0.0}))
+            for acc in accs:
+                acc["n"] += 1
+                acc["bubble_seconds"] += e["bubble_ms"] / 1e3
+                acc["dispatch_seconds"] += e.get("dispatch_ms", 0.0) / 1e3
+        return {"t0": t0, "t1": t1, "truncated": truncated,
+                "kinds": kinds, "bubble_after": after}
 
     def recent(self, n: Optional[int] = None) -> List[dict]:
         """Last ``n`` entries (all when None; empty for n <= 0 — a
@@ -140,9 +186,15 @@ class EngineChannel:
         self.name = str(name)
         self.num_slots = int(num_slots)
         self._lock = threading.Lock()
-        # bubble anchor: interval-clock time of the last KNOWN device
-        # completion (block retire / prefill readback / chunk dispatch)
-        self._last_done: Optional[float] = None
+        # bubble anchor: the work dispatched LAST, as (dispatch stamp,
+        # stamp at which it was known done — block retire / prefill
+        # readback / chunk dispatch / the serve loop's wake-up —, kind),
+        # and the one before it. Records arrive in readback order, which
+        # an admission behind an in-flight block reverses: only a later
+        # dispatch moves the anchor, and the overtaken block is judged
+        # against the anchor before
+        self._anchor: Optional[tuple] = None
+        self._anchor_prev: Optional[tuple] = None
         # last block retire per impl, for steady pipelined spacing
         self._last_retire: Dict[str, float] = {}
         # plain accumulators (summary() reads these; the registry
@@ -164,7 +216,6 @@ class EngineChannel:
         # outcomes, and the draft/verify/rewind sub-phase sums
         self._spec = {"blocks": 0, "accepted": 0, "drafted": 0,
                       "draft_s": 0.0, "verify_s": 0.0, "rewind_s": 0.0}
-        self._decoders: List[weakref.ref] = []
         reg = profiler.registry
         self._h_phase = {
             p: reg.histogram(
@@ -183,28 +234,50 @@ class EngineChannel:
                         for kind in ("block", "admission", "chunk",
                                      "spec")}
 
-    def attach_decoder(self, decoder) -> None:
-        """Weakly remember a decoder whose ``_cost_seam`` the roofline
-        join reads at snapshot time (never from the hot path)."""
+    def _bubble_locked(self, kind: str, t_dispatch: float, t_done: float,
+                       overlapped: bool):
+        """(bubble seconds before this dispatch, kind of the work the
+        device had done last — None where other work was in flight);
+        moves the anchor. Caller holds the lock."""
+        cur = self._anchor
+        newest = cur is None or t_dispatch >= cur[0]
+        ref = cur if newest else self._anchor_prev
+        if overlapped or ref is None:
+            bubble, after = 0.0, None
+        else:
+            bubble, after = max(0.0, t_dispatch - ref[1]), ref[2]
+        if newest:
+            self._anchor_prev = cur
+            self._anchor = (t_dispatch, t_done, kind)
+        return bubble, after
+
+    def mark_idle(self, t: float) -> None:
+        """The serve loop woke at ``t`` from a wait for work: the device
+        was idle for lack of work, not for the host — the next dispatch's
+        bubble counts from here."""
         with self._lock:
-            if all(w() is not decoder for w in self._decoders):
-                self._decoders.append(weakref.ref(decoder))
+            self._anchor_prev, self._anchor = self._anchor, (t, t, "idle")
 
     # ---------------------------------------------------------- recording
     def record_block(self, *, impl: str, k: int, lanes: int, queued: int,
                      t_dispatch: float, t_fetched: float, t_host: float,
-                     t_journal: float, t_publish: float) -> None:
+                     t_journal: float, t_publish: float,
+                     block: Optional[int] = None,
+                     overlapped: bool = False,
+                     t_dispatched: Optional[float] = None) -> None:
         """One retired decode block. The five stamps are interval-clock
         times at the retire cycle's seams; phases telescope so they sum
-        to ``t_publish - t_dispatch`` exactly."""
+        to ``t_publish - t_dispatch`` exactly. ``overlapped``: another
+        block was in flight at the dispatch (the double buffer);
+        ``t_dispatched``: the dispatch call's return (``dispatch_ms`` of
+        the timeline entry)."""
         phases = {"device": t_fetched - t_dispatch,
                   "host": t_host - t_fetched,
                   "journal": t_journal - t_host,
                   "publish": t_publish - t_journal}
         with self._lock:
-            bubble = 0.0 if self._last_done is None else \
-                max(0.0, t_dispatch - self._last_done)
-            self._last_done = t_fetched
+            bubble, after = self._bubble_locked("block", t_dispatch,
+                                                t_fetched, overlapped)
             for p, v in phases.items():
                 self._phase_s[p] += v
             self._bubble_s += bubble
@@ -218,7 +291,7 @@ class EngineChannel:
             self._lane_busy_s += lanes * span
             if queued > 0:
                 self._lane_idle_queued_s += (self.num_slots - lanes) * span
-            # steady duration for the roofline: in pipelined steady
+            # steady duration per impl (impl_measured): in pipelined steady
             # state (zero bubble) consecutive retirements are spaced by
             # the true per-block device time, which the dispatch→ready
             # delta OVERSTATES (it spans the overlapped host work);
@@ -248,8 +321,9 @@ class EngineChannel:
         # cost on the readback thread; JSON renders them fine
         self._profiler.timeline.add({
             "engine": self.name, "kind": "block", "impl": impl,
-            "k": k, "lanes": lanes, "queued": queued,
-            "t": t_dispatch, "bubble_ms": bubble * 1e3,
+            "block": block, "k": k, "lanes": lanes, "queued": queued,
+            "t": t_dispatch, "bubble_ms": bubble * 1e3, "after": after,
+            "dispatch_ms": _ms(t_dispatch, t_dispatched),
             "phases_ms": {p: v * 1e3 for p, v in phases.items()},
         })
 
@@ -257,7 +331,8 @@ class EngineChannel:
                     accepted: int, drafted: int, t_draft: float,
                     t_dispatch: float, t_fetched: float, t_rewind: float,
                     t_host: float, t_journal: float,
-                    t_publish: float) -> None:
+                    t_publish: float,
+                    block: Optional[int] = None) -> None:
         """One retired speculative verify block (ISSUE 16). The generic
         telescoping account is unchanged — device/host/journal/publish
         still sum to ``t_publish - t_dispatch`` exactly, so every
@@ -276,9 +351,8 @@ class EngineChannel:
         draft_s = max(0.0, t_dispatch - t_draft)
         rewind_s = max(0.0, t_rewind - t_fetched)
         with self._lock:
-            bubble = 0.0 if self._last_done is None else \
-                max(0.0, t_draft - self._last_done)
-            self._last_done = t_fetched
+            bubble, after = self._bubble_locked("block", t_draft,
+                                                t_fetched, False)
             for p, v in phases.items():
                 self._phase_s[p] += v
             self._bubble_s += bubble
@@ -318,9 +392,9 @@ class EngineChannel:
         self._m_kind["spec"].inc()
         self._profiler.timeline.add({
             "engine": self.name, "kind": "spec", "impl": impl,
-            "k": k, "lanes": lanes, "queued": queued,
+            "block": block, "k": k, "lanes": lanes, "queued": queued,
             "accepted": int(accepted), "drafted": int(drafted),
-            "t": t_dispatch, "bubble_ms": bubble * 1e3,
+            "t": t_dispatch, "bubble_ms": bubble * 1e3, "after": after,
             "draft_ms": draft_s * 1e3, "rewind_ms": rewind_s * 1e3,
             "phases_ms": {p: v * 1e3 for p, v in phases.items()},
         })
@@ -328,19 +402,23 @@ class EngineChannel:
     def record_admission(self, *, impl: str, count: int,
                          t_dispatch: float, t_fetched: float,
                          t_host: float, t_journal: float,
-                         t_publish: float) -> None:
+                         t_publish: float, block: Optional[int] = None,
+                         overlapped: bool = False,
+                         t_dispatched: Optional[float] = None) -> None:
         """One batched admission wave (slab or paged): same telescoping
         decomposition; the prefill readback becomes the new bubble
         anchor (prefill IS device work — a decode block dispatched
-        right after it shows only the host gap as bubble)."""
+        right after it shows only the host gap as bubble, marked
+        ``after: "admission"``). ``overlapped``: a decode block was in
+        flight at the dispatch, so the prefill queued behind it and the
+        device was not idle."""
         phases = {"device": t_fetched - t_dispatch,
                   "host": t_host - t_fetched,
                   "journal": t_journal - t_host,
                   "publish": t_publish - t_journal}
         with self._lock:
-            bubble = 0.0 if self._last_done is None else \
-                max(0.0, t_dispatch - self._last_done)
-            self._last_done = t_fetched
+            bubble, after = self._bubble_locked("admission", t_dispatch,
+                                                t_fetched, overlapped)
             for p, v in phases.items():
                 self._phase_s[p] += v
             self._bubble_s += bubble
@@ -361,22 +439,23 @@ class EngineChannel:
         self._m_kind["admission"].inc()
         self._profiler.timeline.add({
             "engine": self.name, "kind": "admission", "impl": impl,
-            "count": count, "t": t_dispatch,
-            "bubble_ms": bubble * 1e3,
+            "block": block, "count": count, "t": t_dispatch,
+            "bubble_ms": bubble * 1e3, "after": after,
+            "dispatch_ms": _ms(t_dispatch, t_dispatched),
             "phases_ms": {p: v * 1e3 for p, v in phases.items()},
         })
 
     def record_chunk(self, *, t_dispatch: float, t_done: float,
-                     final: bool) -> None:
+                     final: bool, block: Optional[int] = None,
+                     overlapped: bool = False) -> None:
         """One chunked-prefill window. Non-final windows never sync
         (t_done is dispatch-return), so only the device phase is
         attributed; the window still moves the bubble anchor — the
         device is busy with it either way."""
         d = t_done - t_dispatch
         with self._lock:
-            bubble = 0.0 if self._last_done is None else \
-                max(0.0, t_dispatch - self._last_done)
-            self._last_done = t_done
+            bubble, after = self._bubble_locked("chunk", t_dispatch, t_done,
+                                                overlapped)
             self._phase_s["device"] += d
             self._bubble_s += bubble
             self._chunks += 1
@@ -385,7 +464,8 @@ class EngineChannel:
         self._m_kind["chunk"].inc()
         self._profiler.timeline.add({
             "engine": self.name, "kind": "chunk", "final": bool(final),
-            "t": t_dispatch, "bubble_ms": bubble * 1e3,
+            "block": block, "t": t_dispatch, "bubble_ms": bubble * 1e3,
+            "after": after,
             "phases_ms": {"device": d * 1e3},
         })
 
@@ -440,29 +520,9 @@ class EngineChannel:
             }
         return out
 
-    def _measured_impls(self) -> Dict[str, List[float]]:
-        with self._lock:
-            return {k: list(v) for k, v in self._impl.items()}
-
-    def _live_decoders(self) -> List:
-        with self._lock:
-            return [d for d in (w() for w in self._decoders)
-                    if d is not None]
-
-
-def _env_peak(name: str) -> Optional[float]:
-    """Best-effort hardware-peak env parse: an empty/garbage value
-    degrades to the no-peaks verdict path — it must never crash engine
-    construction (every engine touches the default profiler)."""
-    try:
-        v = float(os.environ.get(name, "") or 0.0)
-    except ValueError:
-        return None
-    return v if v > 0 else None
-
 
 class PhaseProfiler:
-    """Process-wide phase/bubble/roofline account over N engines.
+    """Process-wide phase/bubble account over N engines.
 
     Engines call :meth:`channel` once at construction (keyed by their
     stable ``slo_label``); the telemetry server serves
@@ -471,21 +531,14 @@ class PhaseProfiler:
     like every other observability sink."""
 
     def __init__(self, registry: Optional[MetricsRegistry] = None,
-                 timeline_capacity: int = 256,
-                 peak_gflops: Optional[float] = None,
-                 peak_gbs: Optional[float] = None):
+                 timeline_capacity: int = 8192):
         self.registry = registry if registry is not None \
             else default_registry()
         self.timeline = PhaseTimeline(timeline_capacity)
-        self.peak_gflops = peak_gflops if peak_gflops is not None else \
-            _env_peak("DL4J_TPU_PEAK_GFLOPS")
-        self.peak_gbs = peak_gbs if peak_gbs is not None else \
-            _env_peak("DL4J_TPU_PEAK_GBS")
         self._lock = threading.Lock()
         self._channels: Dict[str, EngineChannel] = {}
 
-    def channel(self, name: str, num_slots: int = 0,
-                decoder=None) -> EngineChannel:
+    def channel(self, name: str, num_slots: int = 0) -> EngineChannel:
         """Get-or-create the channel for one engine label. Idempotent:
         a supervisor-rebuilt engine re-enters ITS channel (same
         ``slo_label``) and keeps accumulating — the timeline ring and
@@ -497,96 +550,25 @@ class PhaseProfiler:
                 self._channels[str(name)] = ch
             elif num_slots:
                 ch.num_slots = int(num_slots)
-        if decoder is not None:
-            ch.attach_decoder(decoder)
         return ch
 
     def channels(self) -> Dict[str, EngineChannel]:
         with self._lock:
             return dict(self._channels)
 
-    # ----------------------------------------------------------- roofline
-    def roofline(self) -> Dict[str, dict]:
-        """Measured-vs-theoretical table per impl (per mesh tag — the
-        impl key carries the ``__m<data>x<tp>`` suffix): attained
-        GFLOP/s and GB/s from the measured steady block duration joined
-        with XLA cost analysis, arithmetic intensity, and the bound
-        verdict. Cost extraction is memoized on the decoder's cost seam
-        (devstats discipline: lowering happens at most once per impl,
-        outside any steady-state compile-audit window)."""
-        from .devstats import impl_cost_analysis
-        costs: Dict[str, dict] = {}
-        measured: Dict[str, List[float]] = {}
-        for ch in self.channels().values():
-            for dec in ch._live_decoders():
-                try:
-                    costs.update(impl_cost_analysis(dec))
-                except Exception:   # noqa: BLE001 — degrade per decoder
-                    pass
-            for impl, (n, tot, mn, k) in ch._measured_impls().items():
-                ent = measured.get(impl)
-                if ent is None:
-                    measured[impl] = [n, tot, mn, k]
-                else:
-                    ent[0] += n
-                    ent[1] += tot
-                    ent[2] = min(ent[2], mn)
-                    ent[3] = max(ent[3], k)
-        out: Dict[str, dict] = {}
-        for impl, (n, tot, mn, k) in sorted(measured.items()):
-            # n counts post-warmup blocks (the compile-laden first
-            # dispatch is excluded); with only the warmup seen, fall
-            # back to its duration and say so
-            mean_s = tot / n if n else mn
-            row = {"n": int(n), "measured_mean_s": round(mean_s, 6),
-                   "measured_min_s": round(mn, 6),
-                   "steps_per_dispatch": int(k)}
-            if not n:
-                row["warmup_only"] = True
-            cost = costs.get(impl)
-            if not cost or "flops" not in cost:
-                row["cost"] = cost or {
-                    "error": "no cost_analysis for this impl"}
-                out[impl] = row
-                continue
-            # XLA cost_analysis counts a lax.scan BODY once, while a
-            # decode_block{K} dispatch runs K steps — join on the
-            # per-step duration so K=1/4/8 rows are comparable and the
-            # attained numbers are per executed step
-            step_s = mean_s / max(1, k)
-            step_min = mn / max(1, k)
-            flops = float(cost["flops"])
-            nbytes = float(cost.get("bytes_accessed", 0.0))
-            row["measured_step_s"] = round(step_s, 6)
-            row["flops"] = int(flops)
-            row["bytes_accessed"] = int(nbytes)
-            row["attained_gflops"] = round(flops / step_s / 1e9, 3)
-            # best-case (min duration) attainment rides along: the mean
-            # absorbs scheduler noise the device never saw
-            row["attained_gflops_best"] = round(flops / step_min / 1e9, 3)
-            if nbytes > 0:
-                row["attained_gbs"] = round(nbytes / step_s / 1e9, 3)
-                intensity = flops / nbytes
-                row["intensity_flops_per_byte"] = round(intensity, 3)
-                if self.peak_gflops and self.peak_gbs:
-                    f_frac = (flops / step_s / 1e9) / self.peak_gflops
-                    b_frac = (nbytes / step_s / 1e9) / self.peak_gbs
-                    row["flops_attainment"] = round(f_frac, 4)
-                    row["bandwidth_attainment"] = round(b_frac, 4)
-                    row["bound"] = "memory_bound" if b_frac >= f_frac \
-                        else "compute_bound"
-                else:
-                    row["ridge_assumed"] = DEFAULT_RIDGE_FLOPS_PER_BYTE
-                    row["bound"] = "memory_bound" if intensity < \
-                        DEFAULT_RIDGE_FLOPS_PER_BYTE else "compute_bound"
-            out[impl] = row
-        return out
+    def between(self, t0: float, t1: float,
+                engine: Optional[str] = None) -> dict:
+        """The timeline's sums over ``[t0, t1)`` on the interval clock
+        (:meth:`PhaseTimeline.between`): what a reader of one window — the
+        benchmark after its run, an operator at ``/profile?since=`` —
+        gets instead of the process-lifetime totals of :meth:`summary`."""
+        return self.timeline.between(t0, t1, engine)
 
     # -------------------------------------------------------------- views
     def summary(self) -> dict:
-        """The lightweight per-engine summary ``/snapshot`` embeds (no
-        cost lowering): phase/bubble/lane accounting plus a headline
-        the fleet scrape's bubble-% column reads."""
+        """The lightweight per-engine summary ``/snapshot`` embeds:
+        phase/bubble/lane accounting plus a headline the fleet scrape's
+        bubble-% column reads."""
         engines = {name: ch.summary()
                    for name, ch in sorted(self.channels().items())}
         headline = {}
@@ -604,21 +586,19 @@ class PhaseProfiler:
                              "total_recorded":
                                  self.timeline.total_added}}
 
-    def snapshot(self, timeline_n: Optional[int] = None) -> dict:
+    def snapshot(self, timeline_n: Optional[int] = None,
+                 since_s: Optional[float] = None) -> dict:
         """The full ``GET /profile`` document: per-engine phase
-        decomposition + bubble accounting, the roofline join (attained
-        vs theoretical per impl per mesh tag), and optionally the last
-        N timeline entries."""
+        decomposition + bubble accounting, optionally the last N
+        timeline entries, and optionally (``since_s``) the sums over the
+        last that many seconds (:meth:`between`)."""
         out = self.summary()
-        try:
-            out["roofline"] = self.roofline()
-        except Exception as e:   # noqa: BLE001 — degrade, never 500
-            out["roofline"] = {"error": f"{type(e).__name__}: {e}"[:200]}
-        if self.peak_gflops or self.peak_gbs:
-            out["peaks"] = {"gflops": self.peak_gflops,
-                            "gbs": self.peak_gbs}
         if timeline_n:
             out["timeline"]["recent"] = self.timeline.recent(timeline_n)
+        if since_s is not None:
+            from .tracing import interval_now
+            now = interval_now()
+            out["window"] = self.between(now - float(since_s), now)
         return out
 
 

@@ -11,21 +11,24 @@ serving process:
   TransferAudit view over ``ops.transfer.device_fetch``), the
   CompileAudit report (per-function XLA compiles + delta since start,
   when ``audit_compiles=True``), the device-cost stats (device memory,
-  per-engine KV-cache bytes, per-impl XLA cost analysis — next to the
-  compile audit), the flight-recorder summary, the SLO summary, and
-  every registered source (engine/supervisor ``stats()`` dicts, broker
+  per-engine KV-cache bytes — next to the compile audit), the
+  flight-recorder summary, the SLO summary, and every registered source (engine/supervisor ``stats()`` dicts, broker
   counters, ...);
 - ``GET /slo``            — the SLO tracker's full document: rolling
   short/long-window attainment + burn rate, deadline-headroom /
   TTFT / queue-wait quantiles, per-route and per-replica splits;
 - ``GET /profile``        — the hot-loop phase profiler: per-engine
   decode-block phase decomposition (device/host/journal/publish +
-  pipeline bubble, lane bubble), the roofline join (attained GFLOP/s /
-  GB/s / arithmetic intensity / bound verdict per impl per mesh tag),
-  and ``?timeline=N`` for the last N PhaseTimeline entries;
+  pipeline bubble, lane bubble), ``?timeline=N`` for the last N
+  PhaseTimeline entries (each with its ``block`` id and what the
+  device had done last, ``after``) and ``?since=S`` for the sums over
+  the last S seconds with ``truncated`` (``PhaseProfiler.between``);
 - ``GET /traces/recent``  — the completed-trace ring as JSON timelines
   (``?n=`` limits the count, ``?status=`` filters — ``failed`` matches
-  every ``failed:*`` status, any exact status works);
+  every ``failed:*`` status, any exact status works; ``?since=S`` adds
+  ``rolled_past``: whether a trace finished in the last S seconds has
+  already rotated out); each ``prefill`` / ``decode_block`` /
+  ``verify_block`` span names the engine ``block`` that produced it;
 - ``GET /healthz``        — liveness probe.
 
 Reading is free for the serving hot path: every endpoint renders from
@@ -41,18 +44,25 @@ dying engine must degrade the snapshot, not the endpoint.
 
 from __future__ import annotations
 
-import threading
 import time
 from typing import Callable, Dict, Optional
 from urllib.parse import parse_qs, urlparse
 
 from ..ui.server import BackgroundHTTPServer, JsonHTTPHandler
-from .devstats import DeviceStats, impl_cost_analysis
+from .devstats import DeviceStats
 from .flightrec import FlightRecorder, default_flight_recorder
 from .metrics import MetricsRegistry, default_registry
 from .profiler import PhaseProfiler, default_profiler
 from .slo import SLOTracker, default_slo_tracker
-from .tracing import TraceRing, default_trace_ring
+from .tracing import TraceRing, default_trace_ring, interval_now
+
+
+def _since(query: Dict) -> Optional[float]:
+    """``?since=S`` as seconds, or None where absent or not a number."""
+    try:
+        return float(query["since"][0])
+    except (KeyError, IndexError, ValueError):
+        return None
 
 
 class _TelemetryHandler(JsonHTTPHandler):
@@ -81,7 +91,8 @@ class _TelemetryHandler(JsonHTTPHandler):
                 tl = int(q.get("timeline", ["0"])[0]) or None
             except ValueError:
                 tl = None
-            self._json(srv.profiler.snapshot(timeline_n=tl))
+            self._json(srv.profiler.snapshot(timeline_n=tl,
+                                             since_s=_since(q)))
         elif url.path == "/traces/recent":
             q = parse_qs(url.query)
             try:
@@ -100,9 +111,14 @@ class _TelemetryHandler(JsonHTTPHandler):
                           (t.status or "").startswith(status + ":")]
                 if n is not None:
                     traces = traces[-n:]
-            self._json({"count": len(traces),
-                        "total_completed": srv.trace_store.total_added,
-                        "traces": [t.to_dict() for t in traces]})
+            doc = {"count": len(traces),
+                   "total_completed": srv.trace_store.total_added,
+                   "traces": [t.to_dict() for t in traces]}
+            since = _since(q)
+            if since is not None:
+                doc["rolled_past"] = srv.trace_store.rolled_past(
+                    interval_now() - since)
+            self._json(doc)
         elif url.path == "/healthz":
             self._json({"ok": True, "uptime_s": round(srv.uptime, 3)})
         else:
@@ -162,22 +178,10 @@ class TelemetryServer:
 
     def add_engine(self, name: str, engine) -> "TelemetryServer":
         """One-call engine wiring: ``stats()`` as a snapshot source plus
-        device-stats attachment (KV-cache bytes gauge, per-impl cost in
-        ``/snapshot``). Per-impl cost extraction lowers each impl once
-        (sub-second when XLA's caches hit, but seconds cold on an
-        accelerator) — warm it here, off the HTTP thread, so the first
-        scrape reads memoized numbers instead of paying the lowering."""
+        device-stats attachment (KV-cache bytes gauge in
+        ``/snapshot``)."""
         self.add_source(name, engine.stats)
         self.devstats.attach_engine(name, engine)
-        dec = getattr(engine, "decoder", None)
-        if dec is not None:
-            def _warm():
-                try:
-                    impl_cost_analysis(dec)
-                except Exception:   # noqa: BLE001 — best-effort warmup;
-                    pass            # /snapshot degrades per entry anyway
-            threading.Thread(target=_warm, daemon=True,
-                             name=f"telemetry-cost-warm-{name}").start()
         return self
 
     def start(self) -> "TelemetryServer":
@@ -257,9 +261,9 @@ class TelemetryServer:
             out["flightrec"] = self.flight_recorder.stats()
         except Exception as e:   # noqa: BLE001
             out["flightrec"] = {"error": f"{type(e).__name__}: {e}"[:200]}
-        # lightweight profiler summary (no cost lowering — the full
-        # roofline join lives at /profile): the fleet scrape's
-        # bubble-% column reads the headline straight from /snapshot
+        # lightweight profiler summary (the timeline lives at /profile):
+        # the fleet scrape's bubble-% column reads the headline straight
+        # from /snapshot
         try:
             out["profiler"] = self.profiler.summary()
         except Exception as e:   # noqa: BLE001

@@ -27,6 +27,16 @@ acceptance gate):
   ring (:class:`TraceRing`) — memory is O(ring × max_spans) forever;
 - nothing here may run under jit: graftlint GL008 flags trace/metric
   record calls in traced contexts.
+
+Engine-loop and training-step seams (:class:`Seam`): ONE helper stamps
+each seam of the serve loop and of ``fit_batch`` — ``interval_now()``
+once at entry, once at exit — and the caller hands those two stamps to
+the in-process sinks (``EngineChannel.record_*``, the requests'
+``Trace.add_span``). The same interval is mirrored as a
+``jax.profiler.TraceAnnotation`` under the seam's fixed ``dl4j.*`` name
+(:data:`SEAMS`), so under a profiler session it lands on ``/host:CPU``
+of the xplane, on the device events' clock, on the thread that did the
+work. With no session active the mirror costs one ``is_enabled()`` call.
 """
 
 from __future__ import annotations
@@ -37,7 +47,30 @@ import time
 from collections import deque
 from typing import Dict, List, Optional
 
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
 _TRACE_IDS = itertools.count(1)
+_BLOCK_IDS = itertools.count(1)
+
+# ---- the seams: a fixed list, not free text at call sites ---------------
+ADMIT = "dl4j.engine.admit"                  # select, pack, dispatch prefill
+PREFILL_READBACK = "dl4j.engine.prefill_readback"   # device_fetch of it
+PREFILL_CHUNK = "dl4j.engine.prefill_chunk"  # one chunked-prefill window
+DISPATCH_BLOCK = "dl4j.engine.dispatch_block"
+BLOCK_READBACK = "dl4j.engine.block_readback"       # the device_fetch wait
+RETIRE = "dl4j.engine.retire"                # host bookkeeping, engine lock
+JOURNAL = "dl4j.engine.journal"
+PUBLISH = "dl4j.engine.publish"              # completions, done-callbacks
+IDLE_WAIT = "dl4j.engine.idle_wait"          # serve loop waiting for work
+SPEC_DRAFT = "dl4j.engine.spec_draft"
+SPEC_REWIND = "dl4j.engine.spec_rewind"
+TRAIN_STEP = "dl4j.train.step"               # a StepTraceAnnotation
+TRAIN_STAGE = "dl4j.train.stage"
+TRAIN_READBACK = "dl4j.train.readback"
+
+SEAMS = (ADMIT, PREFILL_READBACK, PREFILL_CHUNK, DISPATCH_BLOCK,
+         BLOCK_READBACK, RETIRE, JOURNAL, PUBLISH, IDLE_WAIT, SPEC_DRAFT,
+         SPEC_REWIND, TRAIN_STEP, TRAIN_STAGE, TRAIN_READBACK)
 
 
 def interval_now() -> float:
@@ -50,6 +83,61 @@ def interval_now() -> float:
     for human display, and NEVER in interval math — a backwards
     wall-clock step cannot corrupt a histogram (regression-tested)."""
     return time.perf_counter()
+
+
+def next_block_id() -> int:
+    """Process-wide, monotonically increasing id of one engine dispatch
+    (a decode block, a verify block, an admission's prefill, a prefill
+    window). The engine's seam spans and the profiler's timeline entry
+    carry it, and each request span the dispatch produced names it in
+    ``attrs["block"]``: request span -> engine block -> (through the
+    dispatch order) the device trace's ``XLA Modules`` event."""
+    return next(_BLOCK_IDS)
+
+
+class Seam:
+    """``with Seam(DISPATCH_BLOCK, block=b, lanes=n, k=4) as s:`` — the one
+    way the engine loop and ``fit_batch`` take a stamp. ``name`` is one of
+    :data:`SEAMS`; ``t0``/``t1`` are THE two ``interval_now()`` stamps of
+    the interval, and the caller passes them on to the in-process sinks.
+    Host-only: graftlint GL008/GL015/GL016 reject it under jit, like the
+    sinks it feeds. Under an active ``jax.profiler``
+    session the same interval is written as a ``TraceAnnotation`` named
+    ``name`` with ``block``/``lanes``/``k`` as its stats (``step`` makes
+    it a ``StepTraceAnnotation`` numbered ``block``); without one, no
+    annotation object and no attrs dict is built."""
+
+    __slots__ = ("name", "block", "lanes", "k", "t0", "t1", "_mirror",
+                 "_step", "_ann")
+
+    def __init__(self, name: str, block: Optional[int] = None,
+                 lanes: Optional[int] = None, k: Optional[int] = None,
+                 mirror: bool = True, step: bool = False):
+        self.name = name
+        self.block, self.lanes, self.k = block, lanes, k
+        self._mirror, self._step = mirror, step
+        self._ann = None
+        self.t0 = self.t1 = 0.0
+
+    def __enter__(self) -> "Seam":
+        if self._mirror and TraceAnnotation.is_enabled():
+            if self._step:
+                ann = StepTraceAnnotation(self.name, step_num=self.block)
+            else:
+                stats = {key: val for key, val in (
+                    ("block", self.block), ("lanes", self.lanes),
+                    ("k", self.k)) if val is not None}
+                ann = TraceAnnotation(self.name, **stats)
+            ann.__enter__()
+            self._ann = ann
+        self.t0 = interval_now()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        self.t1 = interval_now()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+            self._ann = None
 
 
 class Span:
@@ -109,9 +197,11 @@ class Trace:
     # ---------------------------------------------------------- recording
     def add_span(self, name: str, t0: Optional[float] = None,
                  t1: Optional[float] = None, **attrs) -> None:
-        now = interval_now()
-        span = Span(name, now if t0 is None else t0,
-                    now if t1 is None else t1, attrs or None)
+        if t0 is None or t1 is None:
+            now = interval_now()
+            t0 = now if t0 is None else t0
+            t1 = now if t1 is None else t1
+        span = Span(name, t0, t1, attrs or None)
         with self._lock:
             if len(self._spans) >= self.max_spans:
                 self.dropped_spans += 1
@@ -212,16 +302,29 @@ class TraceRing:
     ``/traces/recent`` endpoint serves from here; memory is bounded by
     capacity × max_spans regardless of uptime."""
 
-    def __init__(self, capacity: int = 256):
+    def __init__(self, capacity: int = 1024):
         self.capacity = int(capacity)
         self._lock = threading.Lock()
         self._ring: deque = deque(maxlen=self.capacity)
         self._added = 0
+        self._lost_until: Optional[float] = None
 
     def add(self, trace: Trace) -> None:
         with self._lock:
+            if len(self._ring) == self.capacity:
+                lost = self._ring[0].finished_at
+                if lost is not None and (self._lost_until is None
+                                         or lost > self._lost_until):
+                    self._lost_until = lost
             self._ring.append(trace)
             self._added += 1
+
+    def rolled_past(self, t: float) -> bool:
+        """Whether a trace that finished at or after interval-clock time
+        ``t`` has already rotated out: a reader of everything since ``t``
+        would then see only part of it."""
+        with self._lock:
+            return self._lost_until is not None and self._lost_until >= t
 
     def recent(self, n: Optional[int] = None) -> List[Trace]:
         with self._lock:
@@ -247,10 +350,11 @@ _DEFAULT: Optional[TraceRing] = None
 
 
 def default_trace_ring() -> TraceRing:
-    """Process-default completed-trace ring (capacity 256). Injectable
-    per component for test isolation, like the metrics registry."""
+    """Process-default completed-trace ring (capacity 1024: minutes of
+    serving). Injectable per component for test isolation, like the
+    metrics registry."""
     global _DEFAULT
     with _DEFAULT_LOCK:
         if _DEFAULT is None:
-            _DEFAULT = TraceRing(256)
+            _DEFAULT = TraceRing()
         return _DEFAULT

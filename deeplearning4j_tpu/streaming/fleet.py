@@ -1683,6 +1683,9 @@ class EngineFleetRouter:
         clone._created_t = fr._created_t
         if old_inner is not None:
             clone.generated = list(old_inner.generated)
+            # keep emissions() in step with the snapshot: sum(n) ==
+            # len(generated) holds across the migration
+            clone._emissions = list(getattr(old_inner, "_emissions", ()))
             clone.trace = old_inner.trace
             clone._created_t = getattr(old_inner, "_created_t",
                                        fr._created_t)

@@ -121,7 +121,15 @@ class SparkStyntheticPhaseTimer:
 
 def profiler_trace(log_dir: str):
     """Context manager around jax.profiler (SURVEY.md §5.1 parity — the
-    jax-native replacement for the reference's listener-based profiling)."""
+    jax-native replacement for the reference's listener-based profiling):
+    how an operator takes an xplane. The trace it writes holds the
+    program's own spans beside the device's events, on one clock: the
+    engine loop's and ``fit_batch``'s seams (``dl4j.engine.*``,
+    ``dl4j.train.*`` on ``/host:CPU``; observability.tracing.Seam), every
+    operation's layer in its ``tf_op`` (the named scopes), and the
+    attention kernels under their own names. Keep it to a second or two
+    outside a benchmark: the stop holds the interpreter for some 50 s a
+    traced second (PERF.md)."""
     import contextlib
     import jax
 

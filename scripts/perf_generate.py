@@ -607,12 +607,6 @@ def spec_ab(gate: float = None) -> int:
         adv_best = float(max(v for v, _, _ in adv))
         speedup = on_best / off_best if off_best else None
         adv_ratio = adv_best / off_best if off_best else None
-        # roofline join: attained GB/s for the fallback block vs the
-        # verify forward (same profiler across all arms of this shape)
-        roof = prof.roofline()
-        gbs = {name: row.get("attained_gbs")
-               for name, row in roof.items()
-               if f"block{k}_impl" in name or f"block{sk}_impl" in name}
         row = {
             "shape": {"slots": slots, "k": k, "spec_k": sk,
                       "page_size": ps, "requests": len(prompts)},
@@ -626,7 +620,6 @@ def spec_ab(gate: float = None) -> int:
             if on[0][1] is not None else None,
             "adversarial_acceptance": round(adv[0][1], 4)
             if adv[0][1] is not None else None,
-            "attained_gbs": gbs,
             "steady_new_compiles": steady_delta,
         }
         shape_ok = bool(speedup and speedup >= gate and

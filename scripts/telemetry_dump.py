@@ -207,16 +207,6 @@ def scrape_fleet(urls, timeout: float = 5.0) -> dict:
         except (urllib.error.URLError, OSError, ValueError,
                 TimeoutError) as e:
             per_url[base] = {"__error__": f"{type(e).__name__}: {e}"}
-            continue
-        # profiler roofline (ISSUE 13): one extra GET per live replica
-        # for the attained-GB/s column; absent/old replicas degrade to
-        # a '-' cell, never a failed scrape
-        try:
-            per_url[base]["__profile__"] = fetch(f"{base}/profile",
-                                                 timeout)
-        except (urllib.error.URLError, OSError, ValueError,
-                TimeoutError):
-            pass
     return merge_snapshots(per_url)
 
 
@@ -227,23 +217,11 @@ def _kv_bytes(snap: dict):
     return sum(vals) if vals else None
 
 
-def _profile_cols(snap: dict):
-    """(bubble_pct, attained_gbs) for one replica: bubble-% from the
-    /snapshot profiler headline, attained GB/s as the best measured
-    decode-block impl in the /profile roofline (None when the replica
-    predates the profiler)."""
-    head = ((snap.get("profiler") or {}).get("headline") or {})
-    bubble = head.get("bubble_pct")
-    gbs = None
-    roof = ((snap.get("__profile__") or {}).get("roofline") or {})
-    for impl, row in roof.items():
-        if not isinstance(row, dict):
-            continue
-        if "decode" in impl and isinstance(row.get("attained_gbs"),
-                                           (int, float)):
-            gbs = row["attained_gbs"] if gbs is None \
-                else max(gbs, row["attained_gbs"])
-    return bubble, gbs
+def _bubble_col(snap: dict):
+    """Decode pipeline bubble-% of one replica, from the /snapshot
+    profiler headline (None when the replica predates the profiler)."""
+    return ((snap.get("profiler") or {}).get("headline") or {}) \
+        .get("bubble_pct")
 
 
 def _counter_sum(snap: dict, family: str):
@@ -351,9 +329,8 @@ def merge_snapshots(per_url: dict) -> dict:
         row["journal_pending"] = _gauge_sum(snap, "journal_pending")
         deg = _gauge_sum(snap, "journal_degraded")
         row["journal_degraded"] = None if deg is None else bool(deg)
-        # hot-loop profiler (ISSUE 13): decode pipeline bubble-% and
-        # best attained decode GB/s per replica
-        row["bubble_pct"], row["attained_gbs"] = _profile_cols(snap)
+        # hot-loop profiler (ISSUE 13): decode pipeline bubble-%
+        row["bubble_pct"] = _bubble_col(snap)
         # disagg tier (ISSUE 14): phase role (P = prefill worker, D =
         # decode worker, '-' = classic both-phase) and the measured
         # KV-handoff transfer account
@@ -415,7 +392,7 @@ def pretty_scrape(doc: dict, out=sys.stdout) -> None:
       f"{'att-long':>8} {'burn-sh':>8} {'reqs':>6} {'miss':>5} "
       f"{'hd-p50':>8} {'hd-min':>8} {'kv-bytes':>10} {'pg-free':>7} "
       f"{'pg-shr':>6} {'xfer-MB':>8} {'j-pend':>6} {'j-deg':>5} "
-      f"{'bub%':>6} {'GB/s':>7} {'spec-acc':>8} {'numflt':>6} "
+      f"{'bub%':>6} {'spec-acc':>8} {'numflt':>6} "
       f"{'kv-cor':>6} {'canary':>7}\n")
     fmt = (lambda v, spec="": "-" if v is None else format(v, spec))
     for base, row in sorted(doc["replicas"].items()):
@@ -438,7 +415,6 @@ def pretty_scrape(doc: dict, out=sys.stdout) -> None:
           f"{fmt(row.get('journal_pending')):>6} "
           f"{'-' if jd is None else ('Y' if jd else 'n'):>5} "
           f"{fmt(row.get('bubble_pct')):>6} "
-          f"{fmt(row.get('attained_gbs')):>7} "
           f"{fmt(row.get('spec_acc')):>8} "
           f"{fmt(row.get('numerical_faults')):>6} "
           f"{fmt(row.get('kv_corruptions')):>6} "

@@ -1285,6 +1285,71 @@ class TestMetricNamingAndSinkRule:
         assert out == []
 
 
+class TestSeamRule:
+    """ISSUE 26: the engine loop's one stamp source
+    (``observability.tracing.Seam``, the engine's ``_seam``) is a host-only
+    record call like the sinks it feeds — GL008 under jit, GL014 (GL008
+    generalized) inside shard_map/pjit regions, and ``mark_idle`` joins
+    GL016's record methods."""
+
+    @pytest.mark.parametrize("call", ["Seam(\"dl4j.engine.retire\", 3)",
+                                      "tracing.Seam(\"dl4j.train.step\")",
+                                      "eng._seam(\"dl4j.engine.admit\")"])
+    def test_seam_inside_jit_flags(self, tmp_path, call):
+        out = _lint_src(tmp_path, f"""
+            import jax
+
+            @jax.jit
+            def step(x, eng, tracing, Seam):
+                with {call} as s:
+                    y = x + 1
+                return y
+        """, rules=["GL008"])
+        assert _rules(out) == ["GL008"]
+        assert "stamps a seam" in out[0].message
+
+    def test_seam_inside_shard_map_flags(self, tmp_path):
+        out = _lint_src(tmp_path, """
+            from jax.experimental.shard_map import shard_map
+
+            def region(x, eng):
+                with eng._seam("dl4j.engine.dispatch_block", 1, 2, 4):
+                    return x * 2
+
+            def run(mesh, x):
+                return shard_map(region, mesh=mesh, in_specs=None,
+                                 out_specs=None)(x)
+        """, rules=["GL008", "GL014"])
+        assert set(_rules(out)) == {"GL008", "GL014"}
+
+    def test_mark_idle_inside_jit_flags(self, tmp_path):
+        out = _lint_src(tmp_path, """
+            import jax
+
+            @jax.jit
+            def step(x, prof):
+                prof.mark_idle(0.0)
+                return x
+        """, rules=["GL016"])
+        assert _rules(out) == ["GL016"]
+
+    def test_seam_on_the_serve_thread_is_fine(self, tmp_path):
+        out = _lint_src(tmp_path, """
+            import jax
+
+            @jax.jit
+            def decode(x):
+                return x + 1
+
+            def loop(eng, x):
+                with eng._seam("dl4j.engine.dispatch_block", 1) as s:
+                    y = decode(x)
+                eng._prof.mark_idle(s.t1)
+                return y
+        """, rules=["GL008", "GL014", "GL016"])
+        assert out == []
+
+
 class TestProfilerStampRule:
     """GL016 (ISSUE 13): profiler/phase-stamp recording banned from
     jit-traced AND shard_map contexts — phase stamps are host
