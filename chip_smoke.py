@@ -453,8 +453,9 @@ def phase_multichip(net, sizes: Sizes, require_mosaic: bool
     check(split > 0, "no serving parameter is actually partitioned")
     cache = dec.init_cache(sizes.num_slots)
     ck = cache[dec.attn_names[0]]["k"]
-    want = (sizes.num_slots // 2, sizes.heads // 2, sizes.t_max,
-            sizes.d_model // sizes.heads)
+    g = dec.kv_heads_per_row    # heads to a 128-lane cache row (2 at 12x64)
+    want = (sizes.num_slots // 2, sizes.heads // g // 2, sizes.t_max,
+            g * (sizes.d_model // sizes.heads))
     check(_spans(ck, 4) and ck.addressable_shards[0].data.shape == want,
           f"KV cache shard {ck.addressable_shards[0].data.shape} on "
           f"{len(ck.sharding.device_set)} device(s); expected {want} on 4")

@@ -12,12 +12,13 @@ path the ROADMAP's "heavy traffic" north star needs:
   ElementWiseVertex add / TransformerFeedForward / RnnOutputLayer).
   ``prefill()`` runs ONE ordinary forward over the prompt (the attention
   helper seam — flash / short-T Pallas kernels — is reused unchanged)
-  while filling a preallocated [B, H, T_max, Dh] KV cache per attention
-  layer; ``decode_step()`` is a jitted fixed-shape single-token step
-  (vmapped ``lax.dynamic_update_slice`` writes + length-masked
-  dot-product attention over the cache, routed through the
-  kind="decode_attention" helper seam so a future decode kernel can slot
-  in). Next-token selection (greedy / temperature, per-row) happens
+  while filling a preallocated [B, H/g, T_max, g·Dh] KV cache per
+  attention layer (g heads to a 128-lane row, see
+  ``SelfAttentionLayer.init_cache``); ``decode_step()`` is a jitted
+  fixed-shape single-token step (one ``lax.dynamic_update_slice`` row
+  write per slot + length-masked dot-product attention over the cache
+  in the layout it is stored in). Next-token selection (greedy /
+  temperature, per-row) happens
   on-device; only the [B] token ids cross to the host each step, so ONE
   compile serves every request shape.
 
@@ -138,6 +139,17 @@ def _abstract_spec(x):
     return jax.ShapeDtypeStruct((), np.asarray(x).dtype)
 
 
+def compiled_peak_bytes(compiled) -> Optional[int]:
+    """A compiled program's peak by its own ``memory_analysis``:
+    arguments + outputs + temporaries − what is aliased (donated
+    arguments written in place); None where the backend gives none."""
+    ma = compiled.memory_analysis()
+    if ma is None:
+        return None
+    return int(ma.argument_size_in_bytes + ma.output_size_in_bytes
+               + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+
+
 class TransformerDecoder:
     """Cache-aware executor for a causal decoder-only ComputationGraph.
 
@@ -149,8 +161,8 @@ class TransformerDecoder:
     from ``parallel.mesh.generation_mesh`` — shards the decoder
     end-to-end: parameters by role through a
     ``parallel.spec_layout.SpecLayout`` (embeddings/projections on
-    ``tp``, optional fsdp axis), the per-layer [B, H, T_max, Dh] KV
-    cache with heads on ``tp`` and batch/slots on ``data``, and every
+    ``tp``, optional fsdp axis), the per-layer [B, H/g, T_max, g·Dh] KV
+    cache with head groups on ``tp`` and batch/slots on ``data``, and every
     jitted impl compiled with NamedSharding-constrained in/out
     shardings (pure GSPMD — the traced math is unchanged, XLA inserts
     the collectives). Divisibility (heads by tp, batch rows by data) is
@@ -215,8 +227,9 @@ class TransformerDecoder:
         self.t_max = int(t_max)
         self.vocab_size = out_v.layer.n_out
         self._jit: Dict = {}
-        # cost seam (observability/devstats.py): impl audit name →
-        # [jitted fn, first-dispatch abstract arg specs, memoized cost]
+        # cost seam: impl audit name → [jitted fn, first-dispatch
+        # abstract arg specs, memoized memory_analysis peak
+        # (program_peak_bytes)]
         self._cost_seam: Dict[str, List] = {}
         self._cast_src = None
         self._cast_params = None
@@ -310,15 +323,40 @@ class TransformerDecoder:
 
     # -------------------------------------------------------------- cache
     def init_cache(self, batch: int) -> Dict[str, Dict]:
-        """{attn_name: {"k","v" [B, H, t_max, Dh]}} for every attention
-        vertex, preallocated in the net's compute dtype. With a mesh the
-        cache is BORN sharded (slots over ``data``, heads over ``tp``) —
-        it is the dominant serving allocation and must never materialize
+        """{attn_name: {"k","v" [B, H/g, t_max, g·Dh]}} for every
+        attention vertex (g = :attr:`kv_heads_per_row`), preallocated in
+        the net's compute dtype. With a mesh the cache is BORN sharded
+        (slots over ``data``, head groups over ``tp``) — it is the
+        dominant serving allocation and must never materialize
         replicated."""
         return {name: self.net.conf.vertices[name].layer.init_cache(
                     batch, self.t_max, self.net.compute_dtype,
                     sharding=self._cache_sharding)
                 for name in self.attn_names}
+
+    @property
+    def kv_heads_per_row(self) -> int:
+        """``g`` of the slab this decoder allocates: heads sharing one
+        128-lane cache row (``SelfAttentionLayer.heads_per_row`` under
+        this decoder's tp axis; 1 is the unpacked [B, H, T_max, Dh])."""
+        tp = 1 if self.mesh is None else \
+            int(self.mesh.shape.get(self._layout.tp_axis, 1))
+        return min(self.net.conf.vertices[n].layer.heads_per_row(tp)
+                   for n in self.attn_names)
+
+    def program_peak_bytes(self, impl_name: str) -> Optional[int]:
+        """:func:`compiled_peak_bytes` of an impl that has been
+        dispatched once (``decode_block4_impl``): compiled again from the
+        signature the cost seam recorded at that dispatch, once, and
+        kept. None before the first dispatch or where the backend gives
+        no analysis."""
+        entry = self._cost_seam.get(impl_name)
+        if entry is None or entry[1] is None:
+            return None
+        if entry[2] is None:
+            entry[2] = compiled_peak_bytes(
+                entry[0].lower(*entry[1]).compile())
+        return entry[2]
 
     def _pool_shardings(self):
         """Paged-pool NamedSharding tree (heads over tp, pages and the
@@ -786,8 +824,12 @@ class TransformerDecoder:
                 # shared cache at its slot index. M and Tp are bucketed
                 # by the caller (pow2), so the signature set is finite.
                 m, tp = tokens.shape
-                c1 = {n: self.net.conf.vertices[n].layer.init_cache(
-                          m, self.t_max, self.net.compute_dtype)
+                # the fresh cache takes the SHARED cache's row layout
+                # (heads per row are decided once, by init_cache under
+                # the decoder's mesh)
+                c1 = {n: {kk: jnp.zeros((m,) + caches[n][kk].shape[1:],
+                                        caches[n][kk].dtype)
+                          for kk in ("k", "v")}
                       for n in self.attn_names}
                 logits, c1 = self._walk_prefill(params, state, c1, tokens,
                                                 lengths)
@@ -4890,7 +4932,16 @@ class SlotGenerationEngine:
         # for single-device — /snapshot sources surface it verbatim
         from ..parallel.mesh import mesh_tag
         out["mesh_shape"] = mesh_tag(self.mesh) or None
+        out["kv_heads_per_row"] = self.kv_heads_per_row
         return out
+
+    @property
+    def kv_heads_per_row(self) -> int:
+        """Heads sharing one 128-lane row of this engine's KV cache: the
+        decoder's ``g`` for the slab, 1 for a paged pool (never
+        packed)."""
+        return 1 if self._pager is not None \
+            else self.decoder.kv_heads_per_row
 
     # ---------------------------------------------------------- execution
     def run_until_drained(self):

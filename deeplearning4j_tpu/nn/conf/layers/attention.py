@@ -13,6 +13,7 @@ from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..input_type import InputType
 from ..serde import register_config
@@ -115,18 +116,50 @@ class SelfAttentionLayer(BaseRecurrentLayerConf):
         return self._project_out(params, out), state
 
     # ---- KV-cache autoregressive decoding (models/generation.py) ----
+    #: lanes of one TPU vector row: a slab row narrower than this is padded
+    #: to it on the device, read and written at half speed and twice the
+    #: bytes (PERF.md, PR 27)
+    LANES = 128
+
+    def heads_per_row(self, tp: int = 1) -> int:
+        """``g``: how many heads share one row of the slab cache. A head
+        narrower than a 128-lane row is packed ``g = 128 // Dh`` to a row
+        when that fills the row exactly, the head count divides by ``g``
+        and the ``H/g`` head groups still divide over the mesh's ``tp``
+        axis; otherwise 1, the unpacked ``[B, H, T_max, Dh]`` slab. Derived
+        from what the layer and the mesh are — there is no option."""
+        hs = self._head_size()
+        if hs >= self.LANES or self.LANES % hs:
+            return 1
+        g = self.LANES // hs
+        if self.num_heads % g or (self.num_heads // g) % max(int(tp), 1):
+            return 1
+        return g
+
     def init_cache(self, batch: int, t_max: int, dtype=jnp.float32,
                    sharding=None) -> Dict:
-        """Preallocated decode cache: {"k", "v"} each [B, H, T_max, Dh].
-        ``sharding`` (a NamedSharding, slots over data / heads over tp)
-        places the buffers distributed at birth — the cache is the
+        """Preallocated decode cache: {"k", "v"} each
+        [B, H/g, T_max, g·Dh] — ``g`` heads side by side in one row, so
+        that the minor dimension is a whole 128-lane row and the decode
+        programs update and read the slab in the layout it is stored in
+        (``g`` from :meth:`heads_per_row`; 1 keeps [B, H, T_max, Dh]).
+        Head ``h`` lives in row group ``h // g`` at lanes
+        ``[(h % g)·Dh, (h % g + 1)·Dh)``. Every reader and writer takes
+        ``g`` from the cache it is handed (``H // shape[1]``), so the one
+        decision is made here.
+        ``sharding`` (a NamedSharding, slots over data / head groups over
+        tp) places the buffers distributed at birth — the cache is the
         dominant serving allocation and must never materialize
         replicated on one device of a mesh."""
         if not self.causal:
             raise ValueError("KV-cache decoding needs causal=True "
                              "(autoregressive attention)")
-        hs = self._head_size()
-        shape = (batch, self.num_heads, t_max, hs)
+        tp = 1
+        if sharding is not None and len(sharding.spec) > 1 \
+                and sharding.spec[1] is not None:
+            tp = sharding.mesh.shape[sharding.spec[1]]
+        g = self.heads_per_row(tp)
+        shape = (batch, self.num_heads // g, t_max, g * self._head_size())
         if sharding is not None:
             # allocate UNDER the sharding: zeros-then-device_put would
             # materialize the full buffer on one device first — the
@@ -136,9 +169,83 @@ class SelfAttentionLayer(BaseRecurrentLayerConf):
         return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
     # graftlint: traced
+    def _slab_rows(self, x, cache):
+        """k or v of a window [B, C, H, Dh] → the slab's rows
+        [B, H/g, C, g·Dh] in the cache's dtype (``g`` read off the
+        cache): the one place the writers spell the layout."""
+        b, c = x.shape[:2]
+        return x.reshape(b, c, cache.shape[1], cache.shape[3]) \
+            .transpose(0, 2, 1, 3).astype(cache.dtype)
+
+    # graftlint: traced
+    def _slab_write(self, cache: Dict, k, v, pos):
+        """Write a window's k/v [B, C, H, Dh] into the slab at each
+        row's own position ``pos`` [B] (in range: the callers clamp):
+        one ``dynamic_update_slice`` of [1, H/g, C, g·Dh] per row, in
+        place on the carried cache. Unrolled over the rows rather than
+        vmapped: a vmapped update is a scatter, which the TPU runs as a
+        loop of bounds check + select + update per row — 2.4 ms of a
+        13.2 ms decode step at 16 slots against 0.25 ms for the plain
+        updates (PERF.md, PR 27)."""
+        zero = np.int32(0)                # match pos dtype under x64 mode
+        new_cache = {}
+        with jax.named_scope("cache_update"):
+            # the unroll over the B rows is the point (docstring)
+            at = [jax.lax.index_in_dim(pos, b, keepdims=False)
+                  for b in range(pos.shape[0])]  # graftlint: disable=GL002
+            for kk, u in (("k", k), ("v", v)):
+                rows = self._slab_rows(u, cache[kk])
+                c = cache[kk]
+                for b, p in enumerate(at):
+                    # static numpy indices and no negative-index fix-up:
+                    # each costs traced ops, 1152 times a decode step
+                    c = jax.lax.dynamic_update_slice(
+                        c, jax.lax.slice_in_dim(rows, b, b + 1),
+                        (np.int32(b), zero, p, zero),
+                        allow_negative_indices=False)
+                new_cache[kk] = c
+        return new_cache
+
+    # graftlint: traced
+    def _slab_attend(self, q, ck, cv, qpos):
+        """Length-masked attention of a window's queries over the slab:
+        q [B, C, H, Dh], ck/cv [B, H/g, T, g·Dh], ``qpos`` [B, C] the
+        absolute position of each query (it attends cells ``<= qpos``).
+        Both contractions run over whole rows: the logits of a head
+        group are ``K_row[T, g·Dh] · Qblk[g·Dh, g]`` with ``Qblk``
+        block-diagonal (head j's query in lanes [j·Dh, (j+1)·Dh), zeros
+        elsewhere — the zeros contribute exact 0.0), the weighted sum is
+        ``P[g, T] · V_row[T, g·Dh]`` of which head j keeps its own Dh
+        lanes. f32 logits and softmax, every position under the mask;
+        ``g = 1`` is plain ``bqhd,bhtd->bhqt``. Returns [B, C, H, Dh]."""
+        b, c, h, hs = q.shape
+        hg = ck.shape[1]
+        g = h // hg
+        scale = 1.0 / math.sqrt(hs)          # math.sqrt: GL004 (x64)
+        qg = q.reshape(b, c, hg, g, 1, hs)
+        if g > 1:
+            own = jnp.eye(g, dtype=q.dtype)[None, None, None, :, :, None]
+            qg = qg * own                    # [B, C, H/g, g, g, Dh]
+        qblk = qg.reshape(b, c, hg, g, g * hs)
+        logits = jnp.einsum("bqgjl,bgtl->bgjqt", qblk, ck,
+                            preferred_element_type=jnp.float32) * scale
+        kpos = jnp.arange(ck.shape[2], dtype=jnp.int32)
+        keep = kpos[None, None, :] <= qpos[:, :, None]       # [B, C, T]
+        logits = jnp.where(keep[:, None, None, :, :], logits, -1e30)
+        probs = jax.nn.softmax(logits, axis=-1)               # f32
+        rows = jnp.einsum("bgjqt,bgtl->bqgjl", probs.astype(cv.dtype), cv)
+        if g > 1:
+            # head j's own lanes of its row: the diagonal blocks
+            rows = jnp.diagonal(rows.reshape(b, c, hg, g, g, hs),
+                                axis1=3, axis2=4)    # [B, C, H/g, Dh, g]
+            rows = jnp.moveaxis(rows, -1, 3)
+        return rows.reshape(b, c, h, hs)
+
+    # graftlint: traced
     def prefill_forward(self, params, x, cache: Dict, mask=None):
         """Teacher-forced pass over the prompt [B, T, n_in] that also fills
-        cache[:, :, :T] with this layer's k/v — attention itself rides the
+        cache[:, :, :T] ([B, H/g, T_max, g·Dh], see :meth:`init_cache`)
+        with this layer's k/v — attention itself rides the
         SAME helper seam as forward() (flash / short-T Pallas kernels), so
         prefill costs one ordinary forward. Positions beyond a row's true
         length carry garbage k/v; decode_forward's length mask never
@@ -147,27 +254,23 @@ class SelfAttentionLayer(BaseRecurrentLayerConf):
         out = self._attend(q, k, v, mask, x.dtype)
         with jax.named_scope("cache_update"):
             new_cache = {
-                "k": jax.lax.dynamic_update_slice(
-                    cache["k"],
-                    k.transpose(0, 2, 1, 3).astype(cache["k"].dtype),
-                    (0, 0, 0, 0)),
-                "v": jax.lax.dynamic_update_slice(
-                    cache["v"],
-                    v.transpose(0, 2, 1, 3).astype(cache["v"].dtype),
-                    (0, 0, 0, 0))}
+                kk: jax.lax.dynamic_update_slice(
+                    cache[kk], self._slab_rows(u, cache[kk]), (0, 0, 0, 0))
+                for kk, u in (("k", k), ("v", v))}
         return self._project_out(params, out), new_cache
 
     # graftlint: traced
     def decode_forward(self, params, x, cache: Dict, positions):
         """One decode step: x [B, 1, n_in] is the token at ``positions``
         ([B] int32, per-row — slots in a continuous batch sit at different
-        lengths). Writes k/v into the cache at each row's position
-        (vmapped ``lax.dynamic_update_slice`` — fixed-shape, ONE compile
-        serves every step) and attends q over cache[:, :, :pos+1] via a
-        length mask. Routed through the kind="decode_attention" helper
-        seam so a future Pallas decode kernel can slot in; the built-in
-        path is length-masked dot-product attention with f32 softmax.
-        Returns (out [B, 1, n_out], new_cache).
+        lengths). Writes k/v into the [B, H/g, T_max, g·Dh] cache at each
+        row's position (:meth:`_slab_write`: one
+        ``lax.dynamic_update_slice`` of whole rows per slot, in place —
+        fixed-shape, ONE compile serves every step) and attends q over
+        cache[:, :, :pos+1] via a length mask (:meth:`_slab_attend`: f32
+        logits and softmax, both contractions over whole rows — no
+        relayout of the cache, and no helper seam: the built-in path IS
+        the decode kernel). Returns (out [B, 1, n_out], new_cache).
 
         Positions are clamped to the cache depth: a fused decode block
         (models/generation.py decode_block) lets finished lanes overshoot
@@ -177,33 +280,9 @@ class SelfAttentionLayer(BaseRecurrentLayerConf):
         q, k, v = self._project_qkv(params, x)       # [B, 1, H, Dh]
         pos = jnp.minimum(jnp.asarray(positions, jnp.int32).reshape(-1),
                           cache["k"].shape[2] - 1)
-        zero = jnp.zeros((), jnp.int32)   # match pos dtype under x64 mode
-        upd = lambda c, u, p: jax.lax.dynamic_update_slice(c, u,
-                                                           (zero, p, zero))
-        with jax.named_scope("cache_update"):
-            new_cache = {
-                "k": jax.vmap(upd)(cache["k"],
-                                   k.transpose(0, 2, 1, 3).astype(
-                                       cache["k"].dtype), pos),
-                "v": jax.vmap(upd)(cache["v"],
-                                   v.transpose(0, 2, 1, 3).astype(
-                                       cache["v"].dtype), pos)}
-        ck, cv = new_cache["k"], new_cache["v"]
-        helper = get_helper("decode_attention")
-        out = helper(self, q, ck, cv, pos) if helper is not None else None
-        if out is None:
-            hs = self._head_size()
-            # math.sqrt, not np.sqrt: an np.float64 scale would promote the
-            # f32 decode logits to f64 under x64 mode (GL004)
-            scale = 1.0 / math.sqrt(hs)
-            logits = jnp.einsum("bhd,bhtd->bht", q[:, 0], ck,
-                                preferred_element_type=jnp.float32) * scale
-            kpos = jnp.arange(ck.shape[2], dtype=jnp.int32)
-            keep = kpos[None, :] <= pos[:, None]            # [B, T_max]
-            logits = jnp.where(keep[:, None, :], logits, -1e30)
-            probs = jax.nn.softmax(logits, axis=-1)          # f32
-            out = jnp.einsum("bht,bhtd->bhd", probs.astype(cv.dtype), cv)
-            out = out[:, None]                               # [B, 1, H, Dh]
+        new_cache = self._slab_write(cache, k, v, pos)
+        out = self._slab_attend(q, new_cache["k"], new_cache["v"],
+                                pos[:, None])
         return self._project_out(params, out.astype(x.dtype)), new_cache
 
     # graftlint: traced
@@ -211,11 +290,12 @@ class SelfAttentionLayer(BaseRecurrentLayerConf):
         """Chunked-prefill step (µ-cuDNN-style micro-batching of a long
         prompt): x [B, C, n_in] is a WINDOW of C prompt tokens whose
         first token sits at absolute position ``pos0`` ([B] int32).
-        Writes the window's k/v into the cache at [pos0, pos0+C) (one
-        vmapped ``dynamic_update_slice`` — fixed shape, ONE compile per
-        chunk size) and attends each query i over cache[:, :, :pos0+i+1]
-        via a per-query length mask, so earlier chunks' context is read
-        back through the SAME cache decode_forward uses. Positions past
+        Writes the window's k/v into the [B, H/g, T_max, g·Dh] cache at
+        [pos0, pos0+C) (:meth:`_slab_write` — fixed shape, ONE compile
+        per chunk size) and attends each query i over
+        cache[:, :, :pos0+i+1] via a per-query length mask
+        (:meth:`_slab_attend`), so earlier chunks' context is read back
+        through the SAME cache decode_forward uses. Positions past
         a window's true length carry garbage k/v exactly like padded
         prefill positions — the length masks never attend them before
         the decode write-head overwrites them. ``pos0`` is clamped so
@@ -238,17 +318,7 @@ class SelfAttentionLayer(BaseRecurrentLayerConf):
         if valid is None:
             p0 = jnp.clip(jnp.asarray(pos0, jnp.int32).reshape(-1), 0,
                           max(t_max - c, 0))
-            zero = jnp.zeros((), jnp.int32)
-            upd = lambda cc, u, p: jax.lax.dynamic_update_slice(
-                cc, u, (zero, p, zero))
-            with jax.named_scope("cache_update"):
-                new_cache = {
-                    "k": jax.vmap(upd)(cache["k"],
-                                       k.transpose(0, 2, 1, 3).astype(
-                                           cache["k"].dtype), p0),
-                    "v": jax.vmap(upd)(cache["v"],
-                                       v.transpose(0, 2, 1, 3).astype(
-                                           cache["v"].dtype), p0)}
+            new_cache = self._slab_write(cache, k, v, p0)
             qpos = p0[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]
         else:
             p0 = jnp.asarray(pos0, jnp.int32).reshape(-1)   # UNclamped
@@ -261,22 +331,15 @@ class SelfAttentionLayer(BaseRecurrentLayerConf):
             wpos = jnp.where(keep_w, w, t_max)
             rows = jnp.arange(x.shape[0], dtype=jnp.int32)[:, None]
             with jax.named_scope("cache_update"):
+                # advanced indices (dims 0 and 2) around the group
+                # slice: the update lands as [B, C, H/g, g·Dh]
                 new_cache = {
-                    "k": cache["k"].at[rows, :, wpos, :].set(
-                        k.astype(cache["k"].dtype), mode="drop"),
-                    "v": cache["v"].at[rows, :, wpos, :].set(
-                        v.astype(cache["v"].dtype), mode="drop")}
+                    kk: cache[kk].at[rows, :, wpos, :].set(
+                        self._slab_rows(u, cache[kk]).transpose(0, 2, 1, 3),
+                        mode="drop")
+                    for kk, u in (("k", k), ("v", v))}
             qpos = w
-        ck, cv = new_cache["k"], new_cache["v"]
-        hs = self._head_size()
-        scale = 1.0 / math.sqrt(hs)          # math.sqrt: GL004 (x64)
-        logits = jnp.einsum("bqhd,bhtd->bhqt", q, ck,
-                            preferred_element_type=jnp.float32) * scale
-        kpos = jnp.arange(t_max, dtype=jnp.int32)
-        keep = kpos[None, None, :] <= qpos[:, :, None]     # [B, C, T]
-        logits = jnp.where(keep[:, None, :, :], logits, -1e30)
-        probs = jax.nn.softmax(logits, axis=-1)            # f32
-        out = jnp.einsum("bhqt,bhtd->bqhd", probs.astype(cv.dtype), cv)
+        out = self._slab_attend(q, new_cache["k"], new_cache["v"], qpos)
         return self._project_out(params, out.astype(x.dtype)), new_cache
 
     # ---- paged KV cache (models/paging.py + models/generation.py) ----

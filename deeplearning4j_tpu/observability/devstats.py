@@ -97,9 +97,12 @@ def kv_cache_stats(engine) -> dict:
         "addressable_bytes": addressable,
         "shards": shards,
         "layers": len(caches),
-        "slot_shape": list(first.shape),          # [S, H, T_max, Dh]
+        "slot_shape": list(first.shape),   # [S, H/g, T_max, g·Dh]
         "dtype": str(first.dtype),
         "bytes_per_slot": total // max(1, int(first.shape[0])),
+        # g: heads sharing one 128-lane row of the slab (1 = unpacked,
+        # and always for a paged pool) — the engine's own account
+        "heads_per_row": int(getattr(engine, "kv_heads_per_row", 1)),
     }
     # paged engine (ISSUE 12): slot_shape is the POOL shape
     # [P, H, page_size, Dh] and bytes_per_slot is bytes per PAGE; the
@@ -131,9 +134,10 @@ class DeviceStats:
 
     Registry integration: ``devstats_live_array_bytes`` /
     ``devstats_live_arrays`` gauges (collection-time callbacks) and a
-    ``devstats_kv_cache_bytes{engine=...}`` gauge per attached engine —
-    all weakref'd, so a retired engine reads 0 instead of being pinned
-    (with its device caches) by the registry."""
+    ``devstats_kv_cache_bytes{engine=...}`` and
+    ``devstats_kv_heads_per_row{engine=...}`` gauges per attached
+    engine — all weakref'd, so a retired engine reads 0 instead of being
+    pinned (with its device caches) by the registry."""
 
     def __init__(self, registry: Optional[MetricsRegistry] = None):
         self._registry = registry if registry is not None \
@@ -144,6 +148,10 @@ class DeviceStats:
         self._g_kv = reg.gauge("devstats_kv_cache_bytes",
                                "KV-cache bytes allocated (global)",
                                ("engine",))
+        self._g_rows = reg.gauge("devstats_kv_heads_per_row",
+                                 "heads sharing one 128-lane row of the "
+                                 "slab KV cache (1 = unpacked)",
+                                 ("engine",))
         reg.gauge("devstats_live_arrays",
                   "jax.live_arrays() count").set_function(
             _live_count)
@@ -158,6 +166,9 @@ class DeviceStats:
         self._g_kv.labels(str(name)).set_function(
             lambda: (lambda e: 0 if e is None else
                      kv_cache_stats(e).get("bytes", 0))(wref()))
+        # the engine's own label: no walk over the cache leaves
+        self._g_rows.labels(str(name)).set_function(
+            lambda: int(getattr(wref(), "kv_heads_per_row", 0)))
         return self
 
     def snapshot(self) -> dict:

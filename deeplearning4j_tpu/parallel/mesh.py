@@ -114,8 +114,9 @@ def validate_decode_mesh(mesh: Mesh, num_heads: Optional[int] = None,
                          data_axis: str = DATA_AXIS,
                          tp_axis: str = TP_AXIS) -> None:
     """Decode divisibility contract, checked BEFORE any device dispatch:
-    attention heads shard over ``tp`` (the [S, H, T, Dh] cache splits on
-    H), cache slots over ``data`` (the cache splits on S). A violation
+    attention heads shard over ``tp`` (the [S, H/g, T, g·Dh] cache
+    splits on its head-group axis), cache slots over ``data`` (the cache
+    splits on S). A violation
     raises with the exact knob to change instead of an XLA sharding
     error at the first prefill. Pass only the quantities the caller
     owns (the decoder checks heads, the engine checks slots)."""

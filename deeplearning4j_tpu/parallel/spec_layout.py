@@ -83,7 +83,9 @@ class SpecLayout:
 
     # ------------------------------------------------- activations/cache
     def kv_cache(self) -> P:
-        """[S, H, T_max, Dh]: slots over data, heads over tp."""
+        """[S, H/g, T_max, g·Dh]: slots over data, head groups over tp
+        (g heads to a cache row; ``SelfAttentionLayer.heads_per_row``
+        keeps H/g divisible by tp or falls back to g = 1)."""
         return P(self.data_axis, self.tp_axis, None, None)
 
     def kv_pages(self) -> P:

@@ -1948,8 +1948,9 @@ class EngineFleetRouter:
             except Exception:   # noqa: BLE001 — a dead replica degrades
                 continue        # the aggregate, not the endpoint
             for k, v in s.items():
+                # a layout label, not a count: replicas do not add up
                 if isinstance(v, (int, float)) and \
-                        not isinstance(v, bool):
+                        not isinstance(v, bool) and k != "kv_heads_per_row":
                     out[k] = out.get(k, 0) + v
         with self._lock:
             counts = {REPLICA_ALIVE: 0, REPLICA_SUSPECT: 0,

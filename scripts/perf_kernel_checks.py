@@ -15,6 +15,10 @@ Checks:
                       causal, unmasked + ragged in-kernel key mask)
   fused sparse CE    (fused_ce vs one-hot mcxent, LM head shape)
   analytic LayerNorm (layernorm custom VJP vs naive autodiff)
+  decode block layout (decode_block4_impl at gpt2-large shapes, 16 slots:
+                      compiled only — no relayout copy of a layer's K or
+                      V in the optimized HLO, memory_analysis peak under
+                      8 GB; the 32-slot peak is printed, not gated)
 
 Error metric: max|a−b| / (max|b| + 1e-30) over fwd outputs and each
 gradient; thresholds sized for bf16 matmul noise (attention) and f32
@@ -149,6 +153,93 @@ def check_layernorm(rows):
                                        for k, v in errs.items()), flush=True)
 
 
+SLAB_PEAK_LIMIT = 8e9      # bytes: decode_block4_impl, gpt2-large, 16 slots
+
+
+def _compile_decode_block(slots, k=4):
+    """decode_block{k}_impl at the gpt2-large cell's shapes, compiled for
+    this backend from shapes alone (no weights are made): (compiled,
+    one layer's cache shape)."""
+    from jax.sharding import SingleDeviceSharding
+    from benchmark.harness import program
+    from deeplearning4j_tpu.models import TransformerDecoder
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmark", "configs",
+                           "gpt2-large.json")) as f:
+        config = json.load(f)
+    dev = SingleDeviceSharding(jax.devices()[0])
+
+    def on(tree, dtype=None):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(
+                a.shape, dtype if dtype is not None
+                and a.dtype == jnp.float32 else a.dtype, sharding=dev),
+            tree)
+    net, _, (params, state, _) = program.make_net(config)
+    params = on(params, jnp.bfloat16)
+    net.params = params
+    dec = TransformerDecoder(net, t_max=config["run"]["engine"]["t_max"])
+    caches = on(jax.eval_shape(lambda: dec.init_cache(slots)))
+    dec._fn(("block", k))
+    jitted = dec._cost_seam[f"decode_block{k}_impl"][0]
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    vec = lambda dt: jax.ShapeDtypeStruct((slots,), dt, sharding=dev)
+    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=dev)
+    compiled = jitted.lower(
+        params, on(state), caches, vec(jnp.int32), vec(jnp.int32),
+        vec(jnp.bool_), vec(jnp.float32), vec(jnp.int32),
+        jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=dev),
+        scalar, scalar).compile()
+    return compiled, caches[dec.attn_names[0]]["k"].shape
+
+
+def slab_relayout_copies(hlo_text, cache_shape):
+    """The optimized HLO's copies of a whole layer's K or V that are not
+    the read itself: every synchronous ``copy`` of that many elements,
+    and every ``copy-start`` whose two sides differ in more than their
+    memory space (a prefetch of the slab into fast memory, same layout
+    on both sides, IS the one read the roofline counts)."""
+    import re
+    size = int(np.prod(cache_shape))
+    shape_re = re.compile(r"\w+\[([\d,]+)\](\{[^}]*\})?")
+    found = []
+    for line in hlo_text.splitlines():
+        m = re.search(r" = (.*?) copy(-start)?\(", line)
+        if not m:
+            continue
+        shapes = [(int(np.prod([int(d) for d in dims.split(",")])),
+                   re.sub(r"S\(\d+\)", "", layout or ""))
+                  for dims, layout in shape_re.findall(m.group(1))]
+        if not shapes or shapes[0][0] != size:
+            continue
+        if m.group(2) is None or shapes[0][1] != shapes[1][1]:
+            found.append(line.strip()[:200])
+    return found
+
+
+def check_decode_block_layout(rows):
+    from deeplearning4j_tpu.models.generation import compiled_peak_bytes
+    compiled, shape = _compile_decode_block(16)
+    peak = compiled_peak_bytes(compiled)
+    copies = slab_relayout_copies(compiled.as_text(), shape)
+    for line in copies[:4]:
+        print("  slab copy: " + line, flush=True)
+    print(f"  decode_block4_impl, 16 slots, slab {list(shape)}: peak "
+          f"{peak / 1e9:.2f} GB, {len(copies)} relayout copies of a "
+          "layer's K or V", flush=True)
+    rows.append(("decode-block-layout",
+                 {"slab_copies": float(len(copies)),
+                  "peak_over_limit": max(0.0, (peak - SLAB_PEAK_LIMIT)
+                                         / SLAB_PEAK_LIMIT)}, 0.0))
+    try:
+        peak32 = compiled_peak_bytes(_compile_decode_block(32)[0])
+        print(f"  decode_block4_impl, 32 slots: peak {peak32 / 1e9:.2f} "
+              "GB (printed, not gated)", flush=True)
+    except Exception as e:   # noqa: BLE001 — printed, not gated
+        print("  decode_block4_impl, 32 slots: does not compile: "
+              f"{type(e).__name__}: {str(e)[:160]}", flush=True)
+
+
 def main():
     from deeplearning4j_tpu.kernels.pallas_attention import \
         pallas_flash_attention
@@ -174,6 +265,7 @@ def main():
         "flash@4096", b=2, t=4096, h=4, d=64, key_mask_tail=2048)
     check_fused_ce(rows)
     check_layernorm(rows)
+    check_decode_block_layout(rows)
 
     ok_all = True
     print(f"{'check':22s} {'threshold':>9s}  errors")

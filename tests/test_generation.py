@@ -127,39 +127,6 @@ class TestDecodeParity:
         out = dec_small.generate([[3, 4]], 100, temperature=0.0)[0]
         assert len(out) == 6
 
-    def test_decode_helper_seam(self, rng_np):
-        """kind='decode_attention' helper seam: a registered helper takes
-        the decode attention; returning None falls back to the built-in
-        length-masked path with identical results."""
-        from deeplearning4j_tpu.nn import helpers
-        net = _tiny_lm()
-        dec = TransformerDecoder(net)
-        tokens = rng_np.integers(0, 12, (2, 8)).astype(np.int32)
-        lengths = np.full(2, 8, np.int32)
-        nxt, _, caches = dec.prefill(dec.init_cache(2), tokens, lengths)
-        calls = []
-
-        def declining(conf, q, ck, cv, pos):
-            calls.append(q.shape)
-            return None
-
-        snap = helpers.snapshot_helper("decode_attention")
-        try:
-            helpers.register_helper("decode_attention", declining, ("cpu",))
-            helpers.enable_helper("decode_attention")
-            _, logits_h, caches = dec.decode_step(
-                caches, np.asarray(nxt), lengths)
-        finally:
-            helpers.restore_helper("decode_attention", snap)
-        assert calls                          # the seam was consulted
-        # fallback result equals the helper-free path (fresh prefill —
-        # the previous decode step already wrote position 8)
-        _, _, c2 = dec.prefill(dec.init_cache(2), tokens, lengths)
-        _, logits_n, _ = dec.decode_step(c2, np.asarray(nxt), lengths)
-        np.testing.assert_allclose(np.asarray(logits_h),
-                                   np.asarray(logits_n),
-                                   rtol=1e-6, atol=1e-7)
-
     def test_recompute_baseline_matches_decode(self, rng_np):
         """The no-cache A/B baseline program computes the same logits the
         cached path does (it had better — the bench compares their
@@ -190,6 +157,186 @@ class TestDecodeParity:
         with pytest.raises(ValueError, match="decoder"):
             TransformerDecoder(net)
 
+
+
+#: (d_model, heads) -> g, the heads sharing one 128-lane row of the slab
+PACKED_CASES = [
+    pytest.param(128, 2, 2, id="dh64-even-heads-g2"),
+    pytest.param(128, 4, 4, id="dh32-g4"),
+    pytest.param(256, 2, 1, id="dh128-g1"),
+    pytest.param(192, 3, 1, id="dh64-odd-heads-g1"),
+]
+
+
+def _padded(prompts, width):
+    tokens = np.zeros((len(prompts), width), np.int32)
+    for i, p in enumerate(prompts):
+        tokens[i, :len(p)] = p
+    return tokens, np.asarray([len(p) for p in prompts], np.int32)
+
+
+class TestPackedSlab:
+    """The slab cache is [B, H/g, T_max, g*Dh]: g = 128 // Dh heads side
+    by side in one row where that fills the row and the head count
+    allows, else 1 (the old [B, H, T_max, Dh]). Whatever g, every slab
+    program stays token- and logit-identical to the no-cache
+    references."""
+
+    @pytest.mark.parametrize("d_model,heads,g", PACKED_CASES)
+    def test_layout_and_parity_against_nocache(self, d_model, heads, g):
+        net = _tiny_lm(d_model=d_model, num_heads=heads)
+        dec = TransformerDecoder(net)
+        hs = d_model // heads
+        layer = net.conf.vertices[dec.attn_names[0]].layer
+        assert layer.heads_per_row() == g
+        assert dec.kv_heads_per_row == g
+        rng = np.random.default_rng(3)
+        prompts = [rng.integers(0, 12, n) for n in (5, 9, 3)]
+        refs = [nocache_generate(net, p, 10, temperature=0)
+                for p in prompts]
+        caches = dec.init_cache(3)
+        for c in caches.values():
+            assert c["k"].shape == c["v"].shape == \
+                (3, heads // g, 32, g * hs)
+        # prefill: first token and last-position logits
+        tokens, lengths = _padded(prompts, 16)
+        nxt, logits, caches = dec.prefill(caches, tokens, lengths)
+        _, want = dec.recompute_logits(tokens, lengths)
+        np.testing.assert_allclose(np.asarray(logits), np.asarray(want),
+                                   rtol=1e-5, atol=1e-6)
+        np.testing.assert_array_equal(
+            np.asarray(nxt), [r[len(p)] for r, p in zip(refs, prompts)])
+        # one fused decode block of 4
+        toks, ids, pos, stop, caches = dec.decode_block(
+            caches, np.asarray(nxt), lengths, block_size=4)
+        for i, (r, p) in enumerate(zip(refs, prompts)):
+            np.testing.assert_array_equal(
+                np.asarray(toks)[i], r[len(p) + 1:len(p) + 5])
+        # its logits, one step on, against the full forward
+        seqs = [r[:len(p) + 5] for r, p in zip(refs, prompts)]
+        _, step_logits, _ = dec.decode_step(
+            jax_copy(caches), np.asarray(ids), np.asarray(pos))
+        _, want = dec.recompute_logits(*_padded(seqs, 16))
+        np.testing.assert_allclose(np.asarray(step_logits),
+                                   np.asarray(want), rtol=1e-5, atol=1e-6)
+        # a verify block over the true continuation accepts all of it
+        # and adds the model's own next token
+        draft = np.stack([r[len(s):len(s) + 3]
+                          for r, s in zip(refs, seqs)])
+        out, _, vpos, _, caches = dec.verify_block(
+            caches, np.asarray(ids), np.asarray(pos), draft)
+        out = np.asarray(out)
+        for i, (r, s) in enumerate(zip(refs, seqs)):
+            assert out[i, 4] == 4                       # emitted count
+            np.testing.assert_array_equal(out[i, :4],
+                                          r[len(s):len(s) + 4])
+        np.testing.assert_array_equal(np.asarray(vpos),
+                                      np.asarray(pos) + 4)
+
+    @pytest.mark.parametrize("d_model,heads,g", PACKED_CASES)
+    def test_engine_chunked_and_speculative_match_nocache(self, d_model,
+                                                          heads, g):
+        """prefill_slots, prefill_chunk, decode and verify blocks through
+        the engine, on prompts long enough to be chunked."""
+        net = _tiny_lm(d_model=d_model, num_heads=heads)
+        dec = TransformerDecoder(net)
+        rng = np.random.default_rng(4)
+        prompts = [rng.integers(0, 12, n) for n in (13, 4, 19, 6)]
+        prompts.append((3 + np.arange(13)) % 4)      # draftable
+        refs = [nocache_generate(net, p, 7, temperature=0)
+                for p in prompts]
+        for kw in ({"prefill_chunk": 8},
+                   {"speculative": True, "spec_k": 4}):
+            eng = SlotGenerationEngine(net, num_slots=2, decoder=dec,
+                                       block_size=4, **kw)
+            reqs = [eng.submit(p, 7) for p in prompts]
+            eng.run_until_drained()
+            for r, want in zip(reqs, refs):
+                np.testing.assert_array_equal(r.result(5), want,
+                                              err_msg=str(kw))
+            st = eng.stats()
+            assert st["kv_heads_per_row"] == g
+            if "prefill_chunk" in kw:
+                assert st["prefill_chunks"] > 0
+            else:
+                assert st["spec_blocks"] > 0
+
+    def test_scrub_and_corrupt_address_one_slot_one_position(self):
+        import jax.numpy as jnp
+        net = _tiny_lm(d_model=128, num_heads=2)        # g = 2
+        dec = TransformerDecoder(net)
+        rng = np.random.default_rng(5)
+        tokens = rng.integers(0, 12, (3, 8)).astype(np.int32)
+        _, _, caches = dec.prefill(dec.init_cache(3), tokens,
+                                   np.full(3, 8, np.int32))
+        before = {n: {kk: np.asarray(c[kk]) for kk in c}
+                  for n, c in caches.items()}
+        assert all(np.abs(b[kk][:, :, :8]).min() > 0
+                   for b in before.values() for kk in b)
+        caches = dec.corrupt_cache(caches, 1, 3, "nan")
+        for n, c in caches.items():
+            for kk in ("k", "v"):
+                got = np.asarray(c[kk])
+                assert got.shape == (3, 1, 32, 128)
+                bad = np.isnan(got)
+                assert bad[1, :, 3, :].all()        # both heads' lanes
+                bad[1, :, 3, :] = False
+                assert not bad.any()
+                keep = np.ones(got.shape, bool)
+                keep[1, :, 3, :] = False
+                np.testing.assert_array_equal(got[keep],
+                                              before[n][kk][keep])
+        caches = dec._fn("scrub_slot")(caches, jnp.asarray([1, 1]))
+        for n, c in caches.items():
+            for kk in ("k", "v"):
+                got = np.asarray(c[kk])
+                assert (got[1] == 0).all()
+                np.testing.assert_array_equal(got[[0, 2]],
+                                              before[n][kk][[0, 2]])
+
+    def test_program_peak_is_recorded_with_the_compile(self):
+        """The decoder keeps the compiled decode block's own peak (its
+        ``memory_analysis``) beside the signature it first compiled for:
+        nothing before a dispatch, at least arguments it holds after."""
+        net = _tiny_lm(d_model=128, num_heads=2)
+        dec = TransformerDecoder(net)
+        assert dec.program_peak_bytes("decode_block4_impl") is None
+        caches = dec.init_cache(2)
+        held = sum(int(c[kk].nbytes) for c in caches.values() for kk in c)
+        dec.decode_block(caches, np.zeros(2, np.int32),
+                         np.zeros(2, np.int32), block_size=4)
+        peak = dec.program_peak_bytes("decode_block4_impl")
+        assert peak is not None and peak >= held
+        assert dec.program_peak_bytes("decode_block4_impl") == peak
+
+    @pytest.mark.parametrize("d_model,heads,g", PACKED_CASES)
+    def test_cache_bytes_do_not_depend_on_packing(self, d_model, heads, g):
+        from deeplearning4j_tpu.observability import (DeviceStats,
+                                                      MetricsRegistry)
+        from deeplearning4j_tpu.observability.devstats import \
+            kv_cache_stats
+        net = _tiny_lm(d_model=d_model, num_heads=heads)
+        reg = MetricsRegistry()
+        eng = SlotGenerationEngine(net, num_slots=2, registry=reg)
+        st = kv_cache_stats(eng)
+        # layers x (k, v) x slots x H x T_max x Dh x float32
+        assert st["bytes"] == 2 * 2 * 2 * 32 * d_model * 4
+        assert st["heads_per_row"] == g
+        assert st["slot_shape"] == [2, heads // g, 32,
+                                    g * (d_model // heads)]
+        DeviceStats(registry=reg).attach_engine("gen", eng)
+        snap = reg.snapshot()
+        assert snap["devstats_kv_cache_bytes"]["values"]["engine=gen"] \
+            == st["bytes"]
+        assert snap["devstats_kv_heads_per_row"]["values"]["engine=gen"] \
+            == g
+
+
+def jax_copy(tree):
+    """A copy of a cache tree the callee may donate."""
+    import jax
+    import jax.numpy as jnp
+    return jax.tree_util.tree_map(jnp.copy, tree)
 
 class TestBlockDecode:
     """Fused K-step decode blocks + the pipelined double-buffered loop
@@ -376,6 +523,8 @@ class TestBlockDecode:
                 np.testing.assert_array_equal(o, want)
             assert sup.restarts == 1
             assert sup.engine.block_size == 4
+            # a layout label, not a counter: a takeover does not add it up
+            assert sup.stats()["kv_heads_per_row"] == 1
         finally:
             sup.stop()
 

@@ -298,6 +298,40 @@ class TestMeshParity:
             spec = c["k"].sharding.spec
             assert tuple(spec)[:2] == ("data", "tp")
 
+    @pytest.mark.parametrize("data,tp,g", [
+        pytest.param(1, 4, 1, id="1x4-tp-does-not-divide-groups-g1"),
+        pytest.param(1, 2, 2, id="1x2-g2"),
+        pytest.param(2, 2, 2, id="2x2-g2"),
+    ])
+    def test_packed_slab_follows_the_tp_axis(self, data, tp, g):
+        """4 heads of 64 pack 2 to a row: 2 head groups shard over tp=2
+        and not over tp=4, where the slab falls back to one head a row
+        (as 12 heads over tp=4 do: 6 groups). Either way the sharded
+        engine serves what the no-cache reference generates."""
+        net = _tiny_lm(d_model=256, num_heads=4)
+        rng = np.random.default_rng(21)
+        prompts = [rng.integers(0, 12, n) for n in (13, 4, 7, 5)]
+        refs = [nocache_generate(net, p, 7, temperature=0)
+                for p in prompts]
+        assert TransformerDecoder(net).kv_heads_per_row == 2
+        dec = TransformerDecoder(net, mesh=generation_mesh(data, tp))
+        assert dec.kv_heads_per_row == g
+        for c in dec.init_cache(4).values():
+            assert c["k"].shape == (4, 4 // g, 32, g * 64)
+            assert tuple(c["k"].sharding.spec)[:2] == ("data", "tp")
+            assert len(c["k"].sharding.device_set) == data * tp
+        eng = SlotGenerationEngine(net, num_slots=4, decoder=dec,
+                                   block_size=4, prefill_chunk=8)
+        reqs = [eng.submit(p, 7) for p in prompts]
+        eng.run_until_drained()
+        for r, want in zip(reqs, refs):
+            np.testing.assert_array_equal(r.result(5), want)
+        assert eng.stats()["kv_heads_per_row"] == g
+        # the mesh-free batched path too
+        for out, want in zip(dec.generate(prompts, 7, temperature=0.0,
+                                          block_size=4), refs):
+            np.testing.assert_array_equal(out, want)
+
 
 class TestShardedEngine:
     """Continuous batching, supervision, and the facades on a mesh."""
