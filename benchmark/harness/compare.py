@@ -69,6 +69,16 @@ def judge(numbers: Dict[str, float], limits: Dict[str, Dict]
     return ok, rows
 
 
+def verdict(limits: Dict[str, Dict], numbers: Dict[str, float],
+            over: Optional[Dict[str, float]] = None) -> bool:
+    """``correct`` as a run's last line would say it, of ``numbers`` with
+    those in ``over`` put in their place (of the limits, those whose number
+    these readings produce: a window's own, as the closing loss, are not).
+    The calibration's readings go through it."""
+    got = dict(numbers, **(over or {}))
+    return judge(got, {k: v for k, v in limits.items() if k in got})[0]
+
+
 def print_rows(rows: Dict[str, Dict], correct: bool) -> None:
     """Each number compared beside its limit, as the last lines on standard
     error."""

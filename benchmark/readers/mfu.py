@@ -1,7 +1,20 @@
-"""The whole step's share of the chip's bf16 peak, from operations counted
-by ``flops.py`` (true lengths, nothing recomputed) and ``peaks.json``."""
+"""The whole step's share of the chip's bf16 peak, from model operations as
+the cell's family counts them (``ctx.family``: true lengths, nothing
+recomputed) and ``peaks.json``."""
 
-from benchmark.harness import flops, serve, trace_reduce
+from benchmark.harness import trace_reduce
+
+
+def processed_flops(ctx) -> float:
+    """Model operations of every prompt and new token of the requests that
+    completed inside the window (true lengths, no padding)."""
+    total = 0.0
+    for r in ctx.records:
+        if r.error is None and r.done is not None and r.done <= ctx.window_s:
+            p, g = len(r.request.prompt), r.request.new_tokens
+            total += ctx.family.prompt_flops(ctx.sizes, p) \
+                + ctx.family.decode_flops(ctx.sizes, p, g)
+    return total
 
 
 def serve_window(ctx):
@@ -9,7 +22,7 @@ def serve_window(ctx):
     the window, over the window's seconds."""
     if not ctx.records:
         return None
-    total = serve.processed_flops(ctx)
+    total = processed_flops(ctx)
     if total <= 0:
         return None
     return 100.0 * total / (ctx.window_s * ctx.peak["bf16_flops_per_s"])
@@ -26,5 +39,6 @@ def train_traced(ctx, program: str = "train_step"):
         return None
     rate = (len(starts) - 1) * ctx.train["tokens_per_step"] \
         / (starts[-1] - starts[0])
-    per_token = flops.train_token_flops(ctx.sizes, ctx.train["seq_len"])
+    per_token = ctx.family.train_token_flops(ctx.sizes,
+                                             ctx.train["seq_len"])
     return 100.0 * per_token * rate / ctx.peak["bf16_flops_per_s"]

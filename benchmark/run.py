@@ -9,10 +9,18 @@ plain reference, and prints one JSON object as the last line of standard
 output. It fails, and prints no result, without a TPU that ``peaks.json``
 knows or with fewer chips than the cell asks for.
 
-Everything specific to a cell is data: BENCHMARK.json names the cell, its
-configuration (``configs/``), its traffic mix (``traffic/``), the per-layer
-metrics that list it (``metrics/`` + ``readers/``) and its limits
-(``limits/``). The traffic's ``kind`` picks one of the general runners.
+Everything specific to a cell is files found by name, and nothing here or
+under ``harness/`` knows a model: BENCHMARK.json names the cell, its
+configuration (``configs/``) and its traffic mix (``traffic/``); the
+configuration's ``family`` names the model's code (``families/<family>/``:
+the program's builder, the seed's weights, the plain reference, the counts);
+the mix's ``kind`` names its runner (``runners/<kind>.py``); the per-layer
+metrics that list the cell name their readers (``metrics/`` + ``readers/``);
+``limits/<cell>.json`` holds what ``correct`` compares. A new architecture
+brings a configuration, a family, a traffic mix, limits and its entries (a
+runner only for a new kind of traffic), and edits no file that is here
+(``harness/manifest.py`` has the rules, ``families/README.md`` and
+``runners/README.md`` the two protocols).
 """
 
 from __future__ import annotations
@@ -24,7 +32,6 @@ _T_START = time.perf_counter()
 import argparse
 import contextlib
 import glob
-import importlib
 import json
 import os
 import shutil
@@ -37,17 +44,14 @@ _ROOT = os.path.dirname(_HERE)
 if _ROOT not in sys.path:
     sys.path.insert(0, _ROOT)
 
-#: traffic kind -> runner module under harness/ (general, not per cell)
-RUNNERS = {"open_loop": "serve", "train": "train"}
-
 
 class Tracer:
     """With ``--trace 1``: a profiler trace bracketed by the ``bench.window``
     span, taken from a helper thread so that starting it never holds up the
     load. ``tick`` starts it ``trace_seconds`` before a window of ``seconds``
     closes (training); ``start`` starts it at once (serving traces a stretch
-    of its traffic offered again after the window, see
-    ``serve.trace_replay``). ``finish`` stops it and reads it — a stop holds the interpreter for some tens of
+    of its traffic offered again after the window, see ``trace_replay`` in
+    ``runners/open_loop.py``). ``finish`` stops it and reads it — a stop holds the interpreter for some tens of
     seconds per traced second, so it comes only when nothing timed is left."""
 
     def __init__(self, ctx, seconds: float):
@@ -119,6 +123,8 @@ class Context:
         self.peak = peak
         self.config = manifest.config(cell["config"])
         self.traffic = manifest.traffic(cell["traffic"])
+        self.family = manifest.family(self.config)
+        self.runner = manifest.runner(self.traffic)
         self.t_start = _T_START
         self.trace = None
         self.control_precision = ""
@@ -198,9 +204,14 @@ def main(argv=None) -> int:
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     args = ap.parse_args(argv)
 
-    from benchmark.harness import manifest as mf
+    from benchmark.harness import compare, manifest as mf
     manifest = mf.Manifest(_ROOT)
     cell = manifest.cell(args.workload)
+    # every file the cell names, found before the chip is looked for: a cell
+    # whose family, runner or limits are missing ends here, naming them
+    manifest.family(manifest.config(cell["config"]))
+    manifest.runner(manifest.traffic(cell["traffic"]))
+    compare.load_limits(manifest.bench_dir, cell["name"])
     configure_cache()
     device, peak = find_chips(int(cell["chips"]))
     result = run_cell(manifest, cell, args, device, peak)
@@ -220,8 +231,7 @@ def run_cell(manifest, cell, args, device, peak, prepare=None):
     print(f"[run] {cell['name']} seed {args.seed} on {device}",
           file=sys.stderr, flush=True)
 
-    runner = importlib.import_module(
-        "benchmark.harness." + RUNNERS[ctx.traffic["kind"]])
+    runner = ctx.runner
     out = runner.run(ctx)
     correct, rows = compare.judge(out["numbers"], limits)
 

@@ -1,6 +1,8 @@
 """Runner for serving cells (traffic kind ``open_loop``): the window drives
 ``SlotGenerationEngine.start()/submit()/result()`` and nothing else of the
-program.
+program. General over model families: the net with the seed's weights, the
+ids the traffic may draw (``sizes["vocab"]``) and the plain reference come
+from ``ctx.family`` (``families/README.md``), and nothing else does.
 
 Set-up: weights from the seed, the engine, then a warm-up that sends one
 admission batch per (count bucket x padded length) the mix can reach and a
@@ -13,7 +15,8 @@ After the window, with ``--trace 1``: the mix again from its beginning, the
 same arrivals and lengths, a stretch of it under the profiler
 (:func:`trace_replay`). Then the program's state is freed, and the plain
 reference runs once over a seeded sample of finished requests (the longest
-included).
+included). ``calibrate`` and ``sweep`` are ``benchmark/calibrate.py``'s
+readings for this kind of traffic: many seeds from one engine.
 """
 
 from __future__ import annotations
@@ -28,7 +31,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from . import flops, loadgen, reference, stats
+from benchmark.harness import compare, loadgen, stats
 
 POLL_S = 0.002                 # first-token poll; TTFT is read to this grain
 DRAIN_S = 60.0                 # wait this long past the close for answers
@@ -342,15 +345,13 @@ class Session:
         from deeplearning4j_tpu.analysis.compile_audit import CompileAudit
         from deeplearning4j_tpu.models.generation import \
             SlotGenerationEngine
-
-        from . import program
         self.ctx = ctx
         self.compiles = CompileCounter()
         self.audit = CompileAudit(ignore=())
         config, args = ctx.config, ctx.args
-        self.net, self.sizes, self.shapes = program.make_net(config)
-        program.install(self.net, config, self.sizes, self.shapes, args.seed,
-                        train=False)
+        self.net, self.sizes, self.shapes = ctx.family.make_net(config)
+        ctx.family.install(self.net, config, self.sizes, self.shapes,
+                           args.seed, train=False)
         ctx.sizes = self.sizes
         ctx.engine_options = dict(config["run"]["engine"])
         _say(f"weights on device: {time.perf_counter() - ctx.t_start:.1f}s")
@@ -362,9 +363,8 @@ class Session:
 
     def install(self, seed: int) -> None:
         """Hand the idle engine the weights of another seed."""
-        from . import program
-        program.install(self.net, self.ctx.config, self.sizes, self.shapes,
-                        seed, train=False)
+        self.ctx.family.install(self.net, self.ctx.config, self.sizes,
+                                self.shapes, seed, train=False)
 
     def settle(self) -> None:
         """The last act of set-up: one collection of what set-up left (see
@@ -424,7 +424,7 @@ def check(ctx, sizes: Dict, seed: int, records: List[Record],
         r.handle = None
     t_ref = time.perf_counter()
     if sequences:
-        gaps = reference.served_token_gaps(
+        gaps = ctx.family.served_token_gaps(
             sizes, seed, sequences, prompt_lens,
             control=ctx.control_precision)
     else:
@@ -492,13 +492,82 @@ def end_to_end(ctx, name: str) -> Optional[float]:
     return None
 
 
-def processed_flops(ctx) -> float:
-    """Model operations of every prompt and new token of the requests that
-    completed inside the window (flops.py, true lengths, no padding)."""
-    total = 0.0
-    for r in ctx.records:
-        if r.error is None and r.done is not None and r.done <= ctx.window_s:
-            p, g = len(r.request.prompt), r.request.new_tokens
-            total += flops.prompt_flops(ctx.sizes, p) \
-                + flops.decode_flops(ctx.sizes, p, g)
-    return total
+# ------------------------------------------- readings for calibrate.py
+def calibrate(ctx, seeds, seconds, n_control, n_fault, limits, control):
+    """Per seed the program's numbers from one engine; on the first
+    ``n_control`` seeds also the control (the reference in the precision
+    ``control``), on the last ``n_fault`` seeds a token altered where it is
+    produced. One line (a dict) per seed."""
+    session = Session(ctx)
+    vocab = session.sizes["vocab"]
+    for i, seed in enumerate(seeds):
+        fault = i >= len(seeds) - n_fault
+        ctx.control_precision = control if i < n_control else ""
+        ctx.control_numbers = None
+        session.install(seed)
+        undo = _alter_tokens(vocab) if fault else None
+        session.settle()
+        try:
+            records, compiles = session.window(seed, seconds)
+        finally:
+            if undo:
+                undo()
+        numbers = check(ctx, session.sizes, seed, records, compiles)
+        verdict = {"token_altered" if fault else "program":
+                   compare.verdict(limits, numbers)}
+        if ctx.control_numbers is not None:
+            verdict["control"] = compare.verdict(limits, numbers,
+                                                 ctx.control_numbers)
+        pauses = ctx.gc_pauses or []
+        yield {"seed": seed, "fault": "token_altered" if fault else None,
+               "numbers": numbers, "control": ctx.control_numbers,
+               "verdict": verdict,
+               "end_to_end": {m: end_to_end(ctx, m) for m in (
+                   "ttft_p95_ms", "tpot_p95_ms")},
+               "gc": {"collections": len(pauses),
+                      "full": sum(g == 2 for g, _ in pauses),
+                      "ms": sum(s for _, s in pauses) * 1e3},
+               "requests": len(records),
+               "failed": sum(r.error is not None for r in records)}
+    session.close()
+
+
+def sweep(ctx, rates, seed, seconds):
+    """The knee, found once: the same mix offered at each of a few fixed
+    rates from one engine; per rate the tails, the tokens completed inside
+    the window and how much was still unfinished when it closed."""
+    session = Session(ctx)
+    for rate in rates:
+        ctx.traffic["rate_per_s"] = float(rate)
+        session.settle()
+        records, _ = session.window(seed, seconds)
+        done_in = [r for r in records if r.error is None
+                   and r.done is not None and r.done <= seconds]
+        yield {"rate_per_s": rate, "requests": len(records),
+               "failed": sum(r.error is not None for r in records),
+               "finished_in_window": len(done_in),
+               "ttft_p95_ms": end_to_end(ctx, "ttft_p95_ms"),
+               "tpot_p95_ms": end_to_end(ctx, "tpot_p95_ms"),
+               "new_tokens_per_s": sum(r.request.new_tokens
+                                       for r in done_in) / seconds,
+               "last_done_s": max((r.done or 0.0) for r in records)}
+        for r in records:
+            r.handle = None
+    session.close()
+
+
+def _alter_tokens(vocab):
+    """A token altered where it is produced: the last token of every
+    request, as it completes."""
+    from deeplearning4j_tpu.models.generation import GenerationRequest
+    real = GenerationRequest._complete
+
+    def altered(self):
+        if self.generated:
+            self.generated[-1] = (self.generated[-1] + 1) % vocab
+        real(self)
+    GenerationRequest._complete = altered
+
+    def undo():
+        GenerationRequest._complete = real
+    return undo

@@ -11,7 +11,6 @@ import jax.numpy as jnp
 import pytest
 
 import tiny
-from benchmark.harness import program
 
 HBM_BYTES = 15.75 * 2 ** 30        # what a v5e chip lets a program use
 
@@ -85,7 +84,7 @@ def _fits(compiled):
 def test_gpt2_medium_train_step_compiles_and_fits(one_chip,
                                                    compiled_kernels):
     config, traffic = _config("gpt2-medium"), _traffic("train-t1024")
-    net, _, (params, state, upd) = program.make_net(config)
+    net, _, (params, state, upd) = tiny.family(config).make_net(config)
     rows, seq = traffic["batch_rows"], traffic["seq_len"]
     ids = jax.ShapeDtypeStruct((rows, seq), jnp.int32, sharding=one_chip)
     lowered = net._get_train_step(False).lower(
@@ -101,16 +100,15 @@ def test_gpt2_medium_train_step_compiles_and_fits(one_chip,
 def large_decoder(one_chip):
     from deeplearning4j_tpu.models import TransformerDecoder
     config = _config("gpt2-large")
-    net, sizes, (params, state, _) = program.make_net(config)
+    net, _, (params, state, _) = tiny.family(config).make_net(config)
     params = _on(params, one_chip, jnp.bfloat16)   # served in bfloat16
     net.params = params
     eng = config["run"]["engine"]
     dec = TransformerDecoder(net, t_max=eng["t_max"])
-    hd = sizes["d"] // sizes["heads"]
-    kv = jax.ShapeDtypeStruct(
-        (eng["num_slots"], sizes["heads"], eng["t_max"], hd), jnp.bfloat16,
-        sharding=one_chip)
-    caches = {n: {"k": kv, "v": kv} for n in dec.attn_names}
+    # the cache as the engine allocates it (since PR 27 two heads of 64 to
+    # a 128-lane row), so that what compiles here is what the cell runs
+    caches = _on(jax.eval_shape(lambda: dec.init_cache(eng["num_slots"])),
+                 one_chip)
     return dec, params, _on(state, one_chip), caches, eng
 
 
@@ -139,6 +137,7 @@ def test_gpt2_large_decode_block_compiles_and_fits(one_chip, large_decoder,
                                                    compiled_kernels):
     dec, params, state, caches, eng = large_decoder
     s, k = eng["num_slots"], eng["block_size"]
+    assert caches[dec.attn_names[0]]["k"].shape == (s, 10, 1024, 128)
     dec._fn(("block", k))
     jitted = dec._cost_seam[f"decode_block{k}_impl"][0]
     key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
@@ -153,3 +152,7 @@ def test_gpt2_large_decode_block_compiles_and_fits(one_chip, large_decoder,
     held = _config("gpt2-large")["run"]["held_on_device_bytes"]
     assert total > held["weights_bfloat16"] \
         + held["slab_cache_16_slots_x_1024"]
+    # the packed slab is read in place: no padded copies of it (12.1 GB with
+    # a [slots, heads, t_max, 64] cache; the file's figure is PR 27's)
+    assert total == pytest.approx(
+        held["decode_block4_16_slots_peak_memory_analysis"], rel=0.1)

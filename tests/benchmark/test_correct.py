@@ -107,26 +107,18 @@ def test_answer_that_loses_its_prompt_is_not_correct(root, monkeypatch):
     assert out["compared"]["wrong_echo"]["value"] > 0
 
 
-def test_compile_inside_the_window_is_not_correct(root):
-    def narrow_warm_up(ctx):
+def test_compile_inside_the_window_is_not_correct(root, monkeypatch):
+    serve = tiny.runner("open_loop", root)    # the runner this root's run finds
+    real = serve.warm_up
+
+    def partial(engine, traffic, vocab, seed):
         # warm only short prompts: the mix's longer ones then compile
         # inside the window
-        from benchmark.harness import serve
-        real = serve.warm_up
-
-        def partial(engine, traffic, vocab, seed):
-            short = dict(traffic, prompt_tokens=dict(
-                traffic["prompt_tokens"], max=8))
-            return real(engine, short, vocab, seed)
-        ctx._restore = (serve, real)
-        serve.warm_up = partial
-    from benchmark.harness import serve
-    real = serve.warm_up
-    try:
-        out = tiny.drive(root, "tiny.tiny-open", seed=17, seconds=1.0,
-                         prepare=narrow_warm_up)
-    finally:
-        serve.warm_up = real
+        short = dict(traffic, prompt_tokens=dict(
+            traffic["prompt_tokens"], max=8))
+        return real(engine, short, vocab, seed)
+    monkeypatch.setattr(serve, "warm_up", partial)
+    out = tiny.drive(root, "tiny.tiny-open", seed=17, seconds=1.0)
     assert out["correct"] is False
     assert out["compared"]["window_compiles"]["value"] > 0
 
@@ -152,7 +144,8 @@ def test_collector_pauses_inside_the_window_are_reported():
     import gc
     import types
 
-    from benchmark.harness import manifest as mf, serve
+    from benchmark.harness import manifest as mf
+    serve = tiny.runner("open_loop")
     ctx = types.SimpleNamespace(gc_pauses=None)
     reader = mf.Manifest(tiny.ROOT).reader("gc_pause_ms.chat")
     assert reader(ctx) is None            # nothing watched: nothing said
@@ -163,6 +156,49 @@ def test_collector_pauses_inside_the_window_are_reported():
     assert reader(ctx) == pytest.approx(
         sum(s for _, s in ctx.gc_pauses) * 1e3) and reader(ctx) > 0
     assert gc.isenabled() and gc.get_freeze_count() == frozen
+
+
+@pytest.mark.parametrize("cell,fault", [("tiny.tiny-open", "token_altered"),
+                                        ("tiny.tiny-train", "half_batch")])
+def test_calibration_reads_program_control_and_fault(root, cell, fault,
+                                                     monkeypatch, capsys):
+    """``calibrate.py`` through the runner's own ``calibrate``: sound seeds
+    judged correct, the control and the cell's fault not."""
+    import json
+
+    from benchmark import calibrate, run as bench_run
+    monkeypatch.setattr(calibrate, "_ROOT", root)
+    monkeypatch.setattr(bench_run, "configure_cache", lambda: None)
+    monkeypatch.setattr(bench_run, "find_chips", lambda chips: (
+        dict(tiny.FAKE_DEVICE), dict(tiny.FAKE_PEAK)))
+    assert calibrate.main(["--workload", cell, "--seeds", "31,32",
+                           "--seconds", "1", "--control-seeds", "1",
+                           "--fault-seeds", "1"]) == 0
+    lines = [json.loads(x) for x in capsys.readouterr().out.splitlines()
+             if x.startswith("{")]
+    assert [x["seed"] for x in lines] == [31, 32]
+    first, last = lines[0]["verdict"], lines[1]["verdict"]
+    assert first["program"] is True and first["control"] is False
+    assert last[fault] is False
+
+
+def test_rate_sweep_goes_through_the_runner(root, monkeypatch, capsys):
+    import json
+
+    from benchmark import calibrate, run as bench_run
+    monkeypatch.setattr(calibrate, "_ROOT", root)
+    monkeypatch.setattr(bench_run, "configure_cache", lambda: None)
+    monkeypatch.setattr(bench_run, "find_chips", lambda chips: (
+        dict(tiny.FAKE_DEVICE), dict(tiny.FAKE_PEAK)))
+    assert calibrate.main(["--workload", "tiny.tiny-open", "--seeds", "33",
+                           "--seconds", "0.5", "--rates", "20,40"]) == 0
+    lines = [json.loads(x) for x in capsys.readouterr().out.splitlines()
+             if x.startswith("{")]
+    assert [x["requests"] for x in lines] == [10, 20]
+    assert all(x["failed"] == 0 for x in lines)
+    with pytest.raises(SystemExit, match="has no sweep"):      # train brings none
+        calibrate.main(["--workload", "tiny.tiny-train", "--seeds", "33",
+                        "--rates", "20"])
 
 
 def test_judge_needs_every_listed_number():

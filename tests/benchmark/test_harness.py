@@ -1,7 +1,8 @@
 """The harness is driven by data: every cell resolves to files that exist,
 every metric is wired to metrics its cells report, names keep to the
-contract's characters, and a new cell, configuration, mix and reader are
-picked up from new files and entries alone. Runs on the CPU in seconds."""
+contract's characters, and a new cell, configuration, model family, mix,
+runner and reader are picked up from new files and entries alone. Runs on
+the CPU in seconds."""
 
 import json
 import os
@@ -11,6 +12,7 @@ import sys
 
 import pytest
 
+import extension
 import tiny
 from benchmark.harness import compare, manifest as mf
 
@@ -55,7 +57,20 @@ def test_cell_resolves_to_files_that_exist(man, cell):
     w = man.cell(cell)
     config = man.config(w["config"])
     traffic = man.traffic(w["traffic"])
-    assert traffic["kind"] in ("open_loop", "train")
+    # the family and the runner are found by name, as files of this tree
+    family, runner = man.family(config), man.runner(traffic)
+    assert family.__file__ == os.path.join(
+        man.bench_dir, "families", config["family"], "__init__.py")
+    assert runner.__file__ == os.path.join(
+        man.bench_dir, "runners", traffic["kind"] + ".py")
+    for name in ("sizes_of", "make_net", "install", "canonical_view",
+                 "leaf_norms", "change_norms", "flat_names",
+                 "served_token_gaps", "train_steps", "prompt_flops",
+                 "decode_flops", "train_token_flops", "total_params"):
+        assert callable(getattr(family, name)), name
+    assert callable(runner.run) and callable(runner.end_to_end)
+    assert family.total_params(family.sizes_of(config)) == \
+        config["run"]["held_on_device_bytes"]["parameters"]
     assert config["source"].startswith("https://")
     assert "held_on_device_bytes" in config["run"] and "assumed" in config
     limits = compare.load_limits(man.bench_dir, cell)
@@ -120,11 +135,25 @@ def test_names_and_units_use_only_the_allowed_characters(man):
                 assert re.match(r"^[A-Za-z0-9_.\-]+$", f), (base, f)
 
 
+def _files(top):
+    out = {}
+    for base, dirs, files in os.walk(top):
+        dirs[:] = [d for d in dirs if d != "__pycache__"]
+        for name in files:
+            path = os.path.join(base, name)
+            with open(path, "rb") as f:
+                out[os.path.relpath(path, top)] = f.read()
+    return out
+
+
 def test_new_cell_config_mix_and_reader_need_no_edit(tmp_path):
-    """A fourth cell, a third configuration, new mixes and a new reader,
-    added to a copy as files and entries: run.py and harness/ untouched."""
+    """New cells, configurations, mixes, a new reader, a second model family
+    whose configuration is written in other keys and a runner of a new kind
+    of traffic, added to a copy as files and entries: every file the
+    benchmark had is byte for byte what it was."""
     root = tiny.make_root(tmp_path)
     bench = os.path.join(root, "benchmark")
+    extension.add_second_family_and_new_runner(root)
     with open(os.path.join(bench, "readers", "dummy.py"), "w") as f:
         f.write("def read(ctx, scale=1.0):\n"
                 "    return scale * ctx.train['tokens_per_step']\n")
@@ -140,15 +169,14 @@ def test_new_cell_config_mix_and_reader_need_no_edit(tmp_path):
                              "workloads": ["tiny.tiny-train"]})
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(doc, f)
-    for sub in ("run.py", "harness"):
-        a, b = os.path.join(bench, sub), os.path.join(ROOT, "benchmark", sub)
-        if os.path.isdir(a):
-            for name in os.listdir(b):
-                if name.endswith((".py", ".json")):
-                    assert open(os.path.join(a, name)).read() == \
-                        open(os.path.join(b, name)).read()
-        else:
-            assert open(a).read() == open(b).read()
+    had, has = _files(os.path.join(ROOT, "benchmark")), _files(bench)
+    assert {"run.py", "calibrate.py", "harness/manifest.py",
+            "runners/open_loop.py", "runners/train.py",
+            "families/gpt2/__init__.py", "readers/mfu.py"} <= set(had)
+    for name, data in had.items():
+        assert has[name] == data, name
+    assert {"families/other/__init__.py", "runners/score.py",
+            "configs/other.json"} <= set(has) - set(had)
     out = tiny.drive(root, "tiny.tiny-train", seconds=0.5, trace=1)
     assert out["correct"] is True, out["compared"]
     assert out["metrics"]["dummy_tokens"] == {"value": 2.0 * 4 * 32,
@@ -161,6 +189,52 @@ def test_new_cell_config_mix_and_reader_need_no_edit(tmp_path):
     out = tiny.drive(root, "tiny.tiny-train", seconds=0.5, trace=0)
     assert set(out["metrics"]) == {"train_tokens_per_s", "setup_s"}
     assert out["metrics"]["train_tokens_per_s"]["value"] > 0
+    # the second family, through the open-loop runner as it stands: correct
+    # by its own reference, and its marked counts are what serve.mfu reads
+    out = tiny.drive(root, "other.tiny-open", seed=21, seconds=1.0, trace=1)
+    assert out["correct"] is True, out["compared"]
+    assert out["attempted"] == 30 and out["failed"] == 0
+    finished = out["metrics"]["serve.mfu.chat"]["value"] / 100.0 \
+        * 1.0 * tiny.FAKE_PEAK["bf16_flops_per_s"] / extension.MARK
+    assert finished == pytest.approx(round(finished), abs=1e-6)
+    assert 1 <= round(finished) <= 30
+    # a runner of a new kind, found by the name in the traffic file
+    out = tiny.drive(root, "other.tiny-score", seed=22, seconds=0.3)
+    assert out["correct"] is True, out["compared"]
+    assert set(out["metrics"]) == {"score_tokens_per_s", "setup_s"}
+    assert out["metrics"]["score_tokens_per_s"]["value"] > 0
+    out = tiny.drive(root, "other.tiny-score", seed=22, seconds=0.3, trace=1)
+    assert out["metrics"]["score_tokens"]["value"] == \
+        out["attempted"] * 2 * 16
+
+
+@pytest.mark.parametrize("cell,looked_for", [
+    ("orphan.tiny-open", ["'orphan'", '"family"',
+                          os.path.join("benchmark", "families")]),
+    ("ghost.tiny-open", ["'ghost'", os.path.join(
+        "benchmark", "families", "ghost", "__init__.py")]),
+    ("tiny.tiny-closed", ["'closed_loop'", os.path.join(
+        "benchmark", "runners", "closed_loop.py")])])
+def test_cell_that_names_no_family_or_runner_ends_with_what_was_looked_for(
+        tmp_path, cell, looked_for):
+    """No default family and no default runner: the run ends before it
+    looks for a chip, names what it looked for and prints no result."""
+    root = tiny.make_root(tmp_path)
+    extension.add_cells_that_name_nothing(root)
+    with pytest.raises(LookupError) as err:
+        tiny.drive(root, cell)
+    for text in looked_for:
+        assert text in str(err.value), str(err.value)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    p = subprocess.run(
+        [sys.executable, os.path.join(root, "benchmark", "run.py"),
+         "--workload", cell, "--seed", "1", "--seconds", "1", "--trace", "0"],
+        cwd=root, env=env, capture_output=True, text=True, timeout=300)
+    assert p.returncode != 0
+    assert p.stdout.strip() == ""
+    assert "no TPU" not in p.stderr           # it never got as far
+    for text in looked_for:
+        assert text in p.stderr, p.stderr[-2000:]
 
 
 def test_run_refuses_the_cpu_and_prints_no_result():

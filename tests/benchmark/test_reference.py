@@ -8,7 +8,9 @@ import numpy as np
 import pytest
 
 import tiny
-from benchmark.harness import program, reference, weights as wgen
+
+gpt2 = tiny.family()
+program, reference, wgen = gpt2.program, gpt2.reference, gpt2.weights
 
 SEED = 2 ** 31 + 77          # larger than 32 signed bits hold
 

@@ -1,8 +1,8 @@
 """The reduction from trace to numbers against traces whose answers are
 known: one written out by hand (every number worked out on paper) and one
 recorded on a TPU v5e, committed under benchmark/fixtures/. Also the counts
-in flops.py against hand counts for both configurations, and the load
-generator's schedule."""
+of the ``gpt2`` family's flops.py against hand counts for both
+configurations, and the load generator's schedule."""
 
 import json
 import os
@@ -11,8 +11,11 @@ import numpy as np
 import pytest
 
 import tiny
-from benchmark.harness import flops, loadgen, stats, trace_reduce as tr
-from benchmark.harness import weights as wgen
+from benchmark.harness import flops as kernel_flops, loadgen, stats
+from benchmark.harness import trace_reduce as tr
+
+gpt2 = tiny.family()
+flops, wgen = gpt2.flops, gpt2.weights
 
 MOSAIC = 'custom_call_target="tpu_custom_call"'
 
@@ -120,7 +123,7 @@ def test_readers_on_the_hand_trace(hand):
     sizes = wgen.sizes_of(man.config("gpt2-medium"))
     ctx = argparse.Namespace(
         trace=hand, peak=mf.peaks("TPU v5 lite"), sizes=sizes, records=None,
-        train={"tokens_per_step": 8192, "seq_len": 1024})
+        family=gpt2, train={"tokens_per_step": 8192, "seq_len": 1024})
     assert man.reader("idle_share.train")(ctx) == \
         pytest.approx(100 * (1 - 14 / 30))
     assert man.reader("train_step_ms")(ctx) == pytest.approx(5e-3)
@@ -196,18 +199,18 @@ def test_flops_hand_counts_gpt2_large():
 
 
 def test_attention_kernel_need_and_roofline():
-    need = flops.attention_kernel(128, 1024, 1024, 64, True, products=2,
+    need = kernel_flops.attention_kernel(128, 1024, 1024, 64, True, products=2,
                                   tensors=4)
     assert need == {"flops": 2 * 2 * 128 * 1024 * 1024 * 64 / 2,
                     "bytes": 4 * 128 * 1024 * 64 * 2}
-    dkv = flops.attention_kernel(128, 1024, 1024, 64, True, products=2,
+    dkv = kernel_flops.attention_kernel(128, 1024, 1024, 64, True, products=2,
                                  tensors=6)
     assert dkv["flops"] == need["flops"] and dkv["bytes"] == 1.5 * need["bytes"]
     peak = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
-    r = flops.roofline_seconds(need["flops"], need["bytes"], peak)
+    r = kernel_flops.roofline_seconds(need["flops"], need["bytes"], peak)
     assert r["bound"] == "compute" and \
         r["seconds"] == pytest.approx(need["flops"] / 197e12)
-    assert flops.roofline_seconds(1.0, 1e9, peak)["bound"] == "memory"
+    assert kernel_flops.roofline_seconds(1.0, 1e9, peak)["bound"] == "memory"
 
 
 # ---------------------------------------------------------------- loadgen
@@ -271,7 +274,7 @@ def test_train_batches_follow_the_seed():
 
 @pytest.mark.parametrize("seed", [1, 77, 2 ** 31 + 12])
 def test_traced_stretch_of_the_replay_holds_an_arrival(seed):
-    from benchmark.harness import serve
+    serve = tiny.runner("open_loop")
     mix = _mix("chat-open")
     sched = loadgen.open_loop_schedule(mix, 50257, seed, 40.0)
     length = mix["trace_seconds"]

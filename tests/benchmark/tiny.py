@@ -16,7 +16,7 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 TINY_CONFIG = {
-    "source": "test only", "n_embd": 64, "n_head": 4, "n_inner": None,
+    "source": "test only", "family": "gpt2", "n_embd": 64, "n_head": 4, "n_inner": None,
     "n_layer": 2, "n_positions": 128, "vocab_size": 211, "reduced": [],
     "run": {"compute_dtype": "float32", "weights_dtype": "float32",
             "engine": {"num_slots": 4, "t_max": 128, "block_size": 4},
@@ -39,7 +39,9 @@ SERVE_LIMITS = {"numbers": {"served_gap": {"limit": 2e-4},
 TRAIN_LIMITS = {"numbers": {"loss_gap.1": {"limit": 1e-4},
                             "loss_gap.2": {"limit": 1e-4},
                             "loss_gap.3": {"limit": 1e-4},
-                            "grad_norm_gap": {"limit": 1e-3},
+                            # sound 2e-7..3e-7, the reference in bfloat16
+                            # in the program's place 5e-4..7e-4 (3 seeds)
+                            "grad_norm_gap": {"limit": 1e-4},
                             "change_norm_gap": {"limit": 1e-2},
                             "final_loss_finite": {"limit": 0}}}
 #: tiny cell -> the cell of the benchmark whose metrics it joins
@@ -95,6 +97,20 @@ def make_root(tmp: str) -> str:
           os.path.join(bench, "metrics", "tiny_queue_wait_ms.json"))
     _dump(doc, os.path.join(root, "BENCHMARK.json"))
     return root
+
+
+def family(config=None, root: str = ROOT):
+    """The model family of a configuration (the tiny one's by default), as
+    a run finds it: by the name in the configuration, from ``root``."""
+    from benchmark.harness import manifest as mf
+    return mf.Manifest(root).family(TINY_CONFIG if config is None
+                                    else config)
+
+
+def runner(kind: str, root: str = ROOT):
+    """The runner of a kind of traffic, as a run finds it."""
+    from benchmark.harness import manifest as mf
+    return mf.Manifest(root).runner({"kind": kind})
 
 
 FAKE_DEVICE = {"platform": "cpu", "kind": "test", "count": 1}
