@@ -50,7 +50,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding
 
-from ..nn.conf.layers import (RnnOutputLayer, SelfAttentionLayer,
+from ..nn.conf.layers import (LatentAttentionLayer, RnnOutputLayer,
+                              RoutedExpertsLayer, SelfAttentionLayer,
                               TokenAndPositionEmbedding)
 from ..nn.graph.computation_graph import scoped
 from ..nn.graph.vertices import LayerVertex
@@ -119,7 +120,21 @@ _ENGINE_COUNTERS = {
                             "generation_spec_accepted_total{len=})",
     "spec_fallbacks": "decode blocks dispatched by the low-acceptance "
                       "adaptive fallback while speculation is enabled",
+    # routed-expert load of alive lanes (and, last, of every lane), a decode
+    # step and an expert layer at a time (MOE_COUNTERS; they ride each
+    # block's one readback)
+    "moe_step_layers": "decode steps x expert layers with an alive lane",
+    "moe_assignments": "token-expert assignments of alive lanes",
+    "moe_experts_hit": "distinct experts chosen by some alive lane, "
+                       "summed over steps and layers",
+    "moe_experts_read": "distinct experts chosen by ANY lane of the block, "
+                        "stopped ones too (what the step computed and "
+                        "read), summed over steps and layers",
 }
+#: the columns a decode block of a model with expert layers appends to its
+#: token matrix, in this order (every row carries the same four sums)
+MOE_COUNTERS = ("moe_step_layers", "moe_assignments", "moe_experts_hit",
+                "moe_experts_read")
 #: unique per-engine metric label values (e0, e1, ...)
 _ENGINE_SEQ = itertools.count()
 
@@ -193,6 +208,10 @@ class TransformerDecoder:
         self.input_name = conf.network_inputs[0]
         self.output_name = conf.network_outputs[0]
         self.attn_names: List[str] = []
+        # vertices that route tokens to experts: their per-expert token
+        # counts (the layer's second return) leave a decode block as
+        # MOE_COUNTERS columns; none, and every program is what it was
+        self.moe_names: List[str] = []
         embed = None
         for name in conf.topological_order:
             v = conf.vertices[name]
@@ -210,6 +229,8 @@ class TransformerDecoder:
                 self.attn_names.append(name)
             elif isinstance(v.layer, TokenAndPositionEmbedding):
                 embed = v.layer
+            elif isinstance(v.layer, RoutedExpertsLayer):
+                self.moe_names.append(name)
         if embed is None or not self.attn_names:
             raise ValueError("graph has no TokenAndPositionEmbedding / "
                              "causal SelfAttentionLayer — not a decoder LM")
@@ -242,6 +263,11 @@ class TransformerDecoder:
         self._row_shardings = None
         self._pool_shardings_cached = None   # paged-pool NamedShardings
         if mesh is not None:
+            if self.moe_names or self.latent_cache_bytes_per_token:
+                raise NotImplementedError(
+                    "a latent (compressed-KV) cache and routed experts "
+                    "have no layout under a mesh: SpecLayout has no latent "
+                    "or expert rule; decode them on one device")
             from ..parallel.mesh import mesh_tag, validate_decode_mesh
             from ..parallel.spec_layout import (SpecLayout,
                                                 decoder_param_specs,
@@ -279,9 +305,10 @@ class TransformerDecoder:
             from ..parallel.spec_layout import param_shardings
             psh = param_shardings(self.mesh, self._param_specs,
                                   self.net.params)
-            csh = {n: {"k": self._cache_sharding,
-                       "v": self._cache_sharding}
-                   for n in self.attn_names}
+            csh = jax.tree_util.tree_map(
+                lambda _: self._cache_sharding,
+                jax.eval_shape(lambda: self.init_cache(
+                    self.data_axis_size)))
             self._row_shardings = (psh, csh,
                                    self._ns(self._layout.batch(1)),
                                    self._ns(self._layout.batch(2)))
@@ -343,6 +370,17 @@ class TransformerDecoder:
             int(self.mesh.shape.get(self._layout.tp_axis, 1))
         return min(self.net.conf.vertices[n].layer.heads_per_row(tp)
                    for n in self.attn_names)
+
+    @property
+    def latent_cache_bytes_per_token(self) -> int:
+        """Bytes one cached token takes over all latent-attention layers
+        (each holds one ``[c_kv ; k_rope]`` row a token, nothing per
+        head); 0 for a model whose cache is per-head k/v."""
+        item = jnp.dtype(self.net.compute_dtype).itemsize
+        return sum(v.layer.row_width * item
+                   for v in (self.net.conf.vertices[n]
+                             for n in self.attn_names)
+                   if isinstance(v.layer, LatentAttentionLayer))
 
     def program_peak_bytes(self, impl_name: str) -> Optional[int]:
         """:func:`compiled_peak_bytes` of an impl that has been
@@ -420,9 +458,12 @@ class TransformerDecoder:
         return logits.astype(jnp.float32), new_caches
 
     # graftlint: traced
-    def _walk_decode(self, params, state, caches, ids, positions):
+    def _walk_decode(self, params, state, caches, ids, positions,
+                     alive=None, tally=None):
         """One single-token step: ids [B] at per-row ``positions`` [B] →
-        (logits [B, V] f32, new caches)."""
+        (logits [B, V] f32, new caches). ``alive`` [B] bool marks the lanes
+        an expert layer counts, and ``tally`` (a list) receives each expert
+        layer's per-expert token counts (its second return)."""
         conf = self.net.conf
         acts = {self.input_name: ids}
         new_caches = {}
@@ -439,6 +480,11 @@ class TransformerDecoder:
                     params[name], xs[0], caches[name], positions)
             elif name == self.output_name:
                 logits = v.layer.preoutput(params[name], xs[0])[:, 0]
+            elif tally is not None and name in self.moe_names:
+                acts[name], load = v.forward(
+                    params[name], state[name], xs, train=False, rng=None,
+                    masks=[alive[:, None]])
+                tally.append(load)
             else:
                 y, _ = v.forward(params[name], state[name], xs, train=False,
                                  rng=None, masks=[None] * len(xs))
@@ -690,6 +736,39 @@ class TransformerDecoder:
                                              axis=-1).astype(jnp.int32)
             return jnp.where(temps <= 0, greedy, sampled)
 
+    @staticmethod
+    # graftlint: traced
+    def _moe_sums(tally):
+        """One decode step's MOE_COUNTERS [4] int32 from its expert layers'
+        per-expert counts: of alive lanes' tokens, and of every lane's."""
+        load = jnp.stack([t["expert_tokens"] for t in tally])   # [L, E]
+        rows = jnp.stack([t["expert_rows"] for t in tally])
+        return jnp.stack([
+            jnp.sum(jnp.any(load > 0, axis=1)), jnp.sum(load),
+            jnp.sum(load > 0), jnp.sum(rows > 0)]).astype(jnp.int32)
+
+    # graftlint: traced
+    def _block_columns(self, toks, fault, moe):
+        """A decode block's ONE read-back matrix: its tokens [B, K], then
+        the sentinel's verdict column (sentinel decoders), then the
+        MOE_COUNTERS sums, the same four in every row (models with expert
+        layers). A model with neither reads back [B, K], as ever."""
+        cols = [toks]
+        if self.sentinel:
+            cols.append(fault.astype(jnp.int32)[:, None])
+        if self.moe_names:
+            cols.append(jnp.broadcast_to(moe[None, :],
+                                         (toks.shape[0], moe.shape[0])))
+        return toks if len(cols) == 1 else jnp.concatenate(cols, axis=1)
+
+    def split_block(self, host: np.ndarray):
+        """(matrix without the MOE_COUNTERS columns, their sums or None) of
+        a fetched decode-block matrix (see :meth:`_block_columns`)."""
+        if not self.moe_names:
+            return host, None
+        n = len(MOE_COUNTERS)
+        return host[:, :-n], host[0, -n:]
+
     # graftlint: traced
     def _fault_of(self, logits, stop=None):
         """Per-row sentinel verdict over traced logits (sentinel
@@ -827,9 +906,8 @@ class TransformerDecoder:
                 # the fresh cache takes the SHARED cache's row layout
                 # (heads per row are decided once, by init_cache under
                 # the decoder's mesh)
-                c1 = {n: {kk: jnp.zeros((m,) + caches[n][kk].shape[1:],
-                                        caches[n][kk].dtype)
-                          for kk in ("k", "v")}
+                c1 = {n: {kk: jnp.zeros((m,) + leaf.shape[1:], leaf.dtype)
+                          for kk, leaf in caches[n].items()}
                       for n in self.attn_names}
                 logits, c1 = self._walk_prefill(params, state, c1, tokens,
                                                 lengths)
@@ -842,7 +920,7 @@ class TransformerDecoder:
                                 jax.lax.dynamic_slice_in_dim(
                                     c1[n][kk], i, 1, axis=0)[:, :, :tp],
                                 (slots[i], z, z, z))
-                            for kk in ("k", "v")}
+                            for kk in caches[n]}
                         for n in self.attn_names}
                 sel = self._select(logits, temps, key)
                 if self.sentinel:
@@ -875,14 +953,14 @@ class TransformerDecoder:
                 z = jnp.zeros((), jnp.int32)
                 c1 = {n: {kk: jax.lax.dynamic_slice_in_dim(
                               caches[n][kk], slot[0], 1, axis=0)
-                          for kk in ("k", "v")}
+                          for kk in caches[n]}
                       for n in self.attn_names}
                 logits, c1 = self._walk_chunk(params, state, c1, tokens,
                                               pos0, valid)
                 merged = {n: {kk: jax.lax.dynamic_update_slice(
                                   caches[n][kk], c1[n][kk],
                                   (slot[0], z, z, z))
-                              for kk in ("k", "v")}
+                              for kk in caches[n]}
                           for n in self.attn_names}
                 sel = self._select(logits, temps, key)
                 if self.sentinel:
@@ -1024,10 +1102,14 @@ class TransformerDecoder:
                 # schedule folds the ABSOLUTE step index, so a given
                 # lane samples identically for every block size.
                 def body(carry, _):
-                    caches, ids, pos, stop, fault, step = carry
+                    caches, ids, pos, stop, fault, moe, step = carry
                     pos_c = jnp.minimum(pos, self.t_max - 1)
-                    logits, caches = self._walk_decode(params, state,
-                                                       caches, ids, pos_c)
+                    tally = [] if self.moe_names else None
+                    logits, caches = self._walk_decode(
+                        params, state, caches, ids, pos_c, alive=~stop,
+                        tally=tally)
+                    if tally:
+                        moe = moe + self._moe_sums(tally)
                     if self.sentinel:
                         fault = fault | self._fault_of(logits, stop)
                     kk = jax.random.fold_in(
@@ -1040,19 +1122,15 @@ class TransformerDecoder:
                     hit_eos = jnp.logical_and(eos_ids >= 0, nxt == eos_ids)
                     new_pos = jnp.where(stop, pos, pos + 1)
                     new_stop = stop | hit_eos | (new_pos >= self.t_max)
-                    return (caches, nxt, new_pos, new_stop, fault,
+                    return (caches, nxt, new_pos, new_stop, fault, moe,
                             step + 1), nxt
                 fault0 = jnp.zeros_like(stopped)
-                (caches, ids, positions, stopped, fault, _), toks = \
+                moe0 = jnp.zeros(len(MOE_COUNTERS), jnp.int32)
+                (caches, ids, positions, stopped, fault, moe, _), toks = \
                     jax.lax.scan(
                         body, (caches, ids, positions, stopped, fault0,
-                               step0), None, length=k_steps)
-                out = toks.T
-                if self.sentinel:
-                    # one extra int32 column on the SAME readback — the
-                    # ≤1-readback-per-block invariant holds structurally
-                    out = jnp.concatenate(
-                        [out, fault.astype(jnp.int32)[:, None]], axis=1)
+                               moe0, step0), None, length=k_steps)
+                out = self._block_columns(toks.T, fault, moe)
                 return out, ids, positions, stopped, caches
             # per-K name: the compile auditor attributes by __name__, and
             # two K values share every input shape — one shared name
@@ -1136,8 +1214,8 @@ class TransformerDecoder:
                 # NaN past its fill point would poison it through the
                 # masked probs·V contraction. Pad rows repeat a victim
                 # slot (idempotent zeroing), keeping signatures finite.
-                return {n: {kk: caches[n][kk].at[slots].set(0.0)
-                            for kk in ("k", "v")}
+                return {n: {kk: leaf.at[slots].set(0.0)
+                            for kk, leaf in caches[n].items()}
                         for n in self.attn_names}
             fn = self._jit_sharded(scrub_slot_impl,
                                    train_donate_argnums((0,)),
@@ -1154,8 +1232,8 @@ class TransformerDecoder:
                 # probs·V contraction. pids are pow2-bucketed; pad
                 # rows scrub the null/trash page (harmless by
                 # definition).
-                return {n: {kk: caches[n][kk].at[pids].set(0.0)
-                            for kk in ("k", "v")}
+                return {n: {kk: leaf.at[pids].set(0.0)
+                            for kk, leaf in caches[n].items()}
                         for n in self.attn_names}
             pool_sh = self._pool_shardings()
             fn = self._jit_sharded(scrub_pages_impl,
@@ -1197,7 +1275,7 @@ class TransformerDecoder:
                 out = {}
                 for n in self.attn_names:
                     out[n] = {}
-                    for kk in ("k", "v"):
+                    for kk in caches[n]:
                         cell = caches[n][kk][slot, :, pos, :]
                         poison = jnp.where(mode == 0,
                                            jnp.full_like(cell, jnp.nan),
@@ -1558,7 +1636,8 @@ class TransformerDecoder:
             # a tripped REAL row fails the whole batch call — this is
             # the library entry point, with no per-request recovery
             # seam; the serving engine fails only the tripped request
-            arr = device_fetch(dev, tag="generate.decode")
+            arr, _ = self.split_block(
+                device_fetch(dev, tag="generate.decode"))
             if self.sentinel:
                 bad = np.nonzero(arr[:n_real, -1])[0]
                 if len(bad):
@@ -1678,6 +1757,9 @@ class GenerationRequest:
         self._result = np.concatenate(
             [self.prompt, np.asarray(self.generated, np.int32)])
         self._running = False
+        # a finished request does not pin its engine: a caller that keeps
+        # the handle would keep the caches and the weights with it
+        self._engine = None
         self._done_t = interval_now()
         if self.trace is not None:
             self.trace.finish("ok", tokens=len(self.generated))
@@ -1688,6 +1770,7 @@ class GenerationRequest:
     def _fail(self, exc: BaseException):
         self._error = exc
         self._running = False
+        self._engine = None      # as _complete; requeue() sets it again
         self._done_t = interval_now()
         if self.trace is not None:
             self.trace.finish(f"failed:{type(exc).__name__}",
@@ -4710,7 +4793,8 @@ class SlotGenerationEngine:
         toks_dev, snapshot, k, disp, qdepth, overlapped = block
         bid, t_disp, lanes = disp.block, disp.t0, len(snapshot)
         with self._seam(tracing.BLOCK_READBACK, bid, lanes, k) as rb:
-            host = device_fetch(toks_dev, tag="engine.decode")
+            host, moe = self.decoder.split_block(
+                device_fetch(toks_dev, tag="engine.decode"))
         t_ret = rb.t1
         fault_col = None
         if self._sentinel_on:
@@ -4729,6 +4813,9 @@ class SlotGenerationEngine:
                 return   # the drain owns the requests; recovery
                          # re-prefills and regenerates these tokens
             self._m["host_readbacks"].inc()
+            if moe is not None:
+                for name, n in zip(MOE_COUNTERS, moe):
+                    self._m[name].inc(int(n))
             emitted = 0
             for s, req in snapshot:
                 if req.done() or self._slots[s] is not req:
@@ -4942,6 +5029,12 @@ class SlotGenerationEngine:
         packed)."""
         return 1 if self._pager is not None \
             else self.decoder.kv_heads_per_row
+
+    @property
+    def latent_cache_bytes_per_token(self) -> int:
+        """The decoder's: bytes a cached token takes over all latent-
+        attention layers, 0 for a per-head k/v cache."""
+        return self.decoder.latent_cache_bytes_per_token
 
     # ---------------------------------------------------------- execution
     def run_until_drained(self):

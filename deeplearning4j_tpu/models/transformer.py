@@ -17,9 +17,11 @@ from typing import Optional
 import numpy as np
 
 from ..nn.conf.config import NeuralNetConfiguration
-from ..nn.conf.layers import (LayerNormalization, RnnOutputLayer,
+from ..nn.conf.layers import (GatedFeedForward, LatentAttentionLayer,
+                              LayerNormalization, RMSNormalization,
+                              RnnOutputLayer, RoutedExpertsLayer,
                               SelfAttentionLayer, TokenAndPositionEmbedding,
-                              TransformerFeedForward)
+                              TokenEmbedding, TransformerFeedForward)
 from ..nn.graph.computation_graph import ComputationGraph
 from ..nn.graph.vertices import ElementWiseVertex
 
@@ -73,6 +75,72 @@ def transformer_lm_conf(vocab_size: int, d_model: int = 128,
     g.add_layer("out",
                 RnnOutputLayer(n_in=d_model, n_out=vocab_size,
                                loss="mcxent", activation="softmax"), "lnf")
+    g.set_outputs("out")
+    return g.build()
+
+
+def latent_moe_lm_conf(vocab_size: int, d_model: int, num_heads: int,
+                       num_layers: int, *, q_rank: int, kv_rank: int,
+                       nope_dim: int, rope_dim: int, v_dim: int,
+                       dense_hidden: int, dense_layers: int = 1,
+                       num_experts: int, top_k: int, expert_hidden: int,
+                       shared_experts: int = 1, routed_scaling: float = 1.0,
+                       first_expert: int = 0, experts_held: int = 0,
+                       rope_theta: float = 10000.0, eps: float = 1e-6,
+                       max_length: int = 4096, learning_rate: float = 3e-4,
+                       seed: int = 42):
+    """ComputationGraphConfiguration for a causal LM of pre-RMSNorm blocks
+    with latent attention and routed experts:
+
+        h <- h + LatentAttention(RMSNorm(h));  h <- h + FFN(RMSNorm(h))
+
+    the first ``dense_layers`` blocks with a dense gated FFN of width
+    ``dense_hidden``, the rest with ``num_experts`` routed experts (top
+    ``top_k``, sigmoid scores, no drops) of width ``expert_hidden`` plus a
+    shared expert; a token-only embedding (positions are rotary, inside
+    attention), a final RMSNorm and an untied head with no bias. Vertices
+    are named as :func:`transformer_lm_conf` names them. ``first_expert`` /
+    ``experts_held`` give every expert layer its share of the experts (all
+    by default). ``max_length`` is the context the model declares."""
+    g = (NeuralNetConfiguration.Builder().seed(seed)
+         .learning_rate(learning_rate).updater("adam").weight_init("xavier")
+         .graph_builder()
+         .add_inputs("tokens"))
+    g.add_layer("embed", TokenEmbedding(n_in=vocab_size, n_out=d_model,
+                                        max_length=max_length), "tokens")
+    norm = lambda: RMSNormalization(n_in=d_model, n_out=d_model, eps=eps)
+    x = "embed"
+    for i in range(num_layers):
+        g.add_layer(f"ln{i}a", norm(), x)
+        g.add_layer(f"attn{i}",
+                    LatentAttentionLayer(
+                        n_in=d_model, n_out=d_model, num_heads=num_heads,
+                        q_rank=q_rank, kv_rank=kv_rank, nope_dim=nope_dim,
+                        rope_dim=rope_dim, v_dim=v_dim,
+                        rope_theta=rope_theta, eps=eps,
+                        activation="identity"),
+                    f"ln{i}a")
+        g.add_vertex(f"res{i}a", ElementWiseVertex(op="add"), x, f"attn{i}")
+        g.add_layer(f"ln{i}b", norm(), f"res{i}a")
+        if i < dense_layers:
+            ffn = GatedFeedForward(n_in=d_model, n_out=d_model,
+                                   hidden=dense_hidden,
+                                   activation="identity")
+        else:
+            ffn = RoutedExpertsLayer(
+                n_in=d_model, n_out=d_model, num_experts=num_experts,
+                top_k=top_k, expert_hidden=expert_hidden,
+                shared_experts=shared_experts,
+                routed_scaling=routed_scaling, first_expert=first_expert,
+                experts_held=experts_held, activation="identity")
+        g.add_layer(f"ffn{i}", ffn, f"ln{i}b")
+        g.add_vertex(f"res{i}b", ElementWiseVertex(op="add"),
+                     f"res{i}a", f"ffn{i}")
+        x = f"res{i}b"
+    g.add_layer("lnf", norm(), x)
+    g.add_layer("out",
+                RnnOutputLayer(n_in=d_model, n_out=vocab_size, loss="mcxent",
+                               activation="softmax", has_bias=False), "lnf")
     g.set_outputs("out")
     return g.build()
 

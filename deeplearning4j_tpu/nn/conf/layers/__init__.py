@@ -10,7 +10,11 @@ from .convolution import (ConvolutionLayer, Convolution1DLayer,
                           ZeroPaddingLayer, GlobalPoolingLayer)
 from .recurrent import GravesLSTM, LSTM, GravesBidirectionalLSTM
 from .attention import (SelfAttentionLayer, LayerNormalization,
-                        TransformerFeedForward, TokenAndPositionEmbedding)
+                        RMSNormalization, TransformerFeedForward,
+                        GatedFeedForward, TokenAndPositionEmbedding,
+                        TokenEmbedding)
+from .latent_attention import LatentAttentionLayer
+from .experts import RoutedExpertsLayer
 from .variational import VariationalAutoencoder
 
 __all__ = [
@@ -23,4 +27,6 @@ __all__ = [
     "GravesLSTM", "LSTM", "GravesBidirectionalLSTM", "VariationalAutoencoder",
     "SelfAttentionLayer", "LayerNormalization",
     "TransformerFeedForward", "TokenAndPositionEmbedding",
+    "RMSNormalization", "GatedFeedForward", "TokenEmbedding",
+    "LatentAttentionLayer", "RoutedExpertsLayer",
 ]

@@ -535,6 +535,29 @@ class LayerNormalization(BaseRecurrentLayerConf):
 
 @register_config
 @dataclasses.dataclass
+class RMSNormalization(LayerNormalization):
+    """Last-axis RMS norm: ``x / sqrt(mean(x^2) + eps) * gamma`` — no mean
+    subtracted, no shift. Statistics in f32 regardless of compute dtype."""
+    eps: float = 1e-6
+
+    def init_params(self, key, dtype=jnp.float32) -> Dict:
+        return {"gamma": jnp.ones((self.n_out or self.n_in,), dtype)}
+
+    def forward(self, params, state, x, *, train=False, rng=None, mask=None):
+        return rms_norm(x, params["gamma"], self.eps), state
+
+
+# graftlint: traced
+def rms_norm(x, gamma, eps: float):
+    """RMS norm over the last axis at >= f32, back in ``x``'s type."""
+    xf = x.astype(jnp.promote_types(x.dtype, jnp.float32))
+    inv = jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    g = gamma.astype(xf.dtype).reshape((1,) * (x.ndim - 1) + (-1,))
+    return (xf * inv * g).astype(x.dtype)
+
+
+@register_config
+@dataclasses.dataclass
 class TransformerFeedForward(BaseRecurrentLayerConf):
     """Per-token two-layer MLP (the transformer FFN block): [N, T, C] →
     gelu(x W1 + b1) W2 + b2 → [N, T, C]. Time-distributed by construction —
@@ -565,6 +588,63 @@ class TransformerFeedForward(BaseRecurrentLayerConf):
         h = jax.nn.gelu(x @ params["W1"] + params["b1"][None, None, :])
         h = self.maybe_dropout(h, train=train, rng=rng)
         return h @ params["W2"] + params["b2"][None, None, :], state
+
+
+@register_config
+@dataclasses.dataclass
+class GatedFeedForward(BaseRecurrentLayerConf):
+    """Per-token gated MLP, no bias: [N, T, C] →
+    ``(silu(x Wg) * (x Wu)) Wd`` → [N, T, C], hidden width ``hidden``."""
+    hidden: int = 0
+
+    def set_n_in(self, it: InputType) -> None:
+        if not self.n_in:
+            self.n_in = it.size
+        if not self.n_out:
+            self.n_out = self.n_in
+
+    def init_params(self, key, dtype=jnp.float32) -> Dict:
+        h = self.hidden or 4 * self.n_in
+        kg, ku, kd = jax.random.split(key, 3)
+        return {"Wg": self._winit(kg, (self.n_in, h), self.n_in, h, dtype),
+                "Wu": self._winit(ku, (self.n_in, h), self.n_in, h, dtype),
+                "Wd": self._winit(kd, (h, self.n_out), h, self.n_out, dtype)}
+
+    def regularizable(self):
+        return ("Wg", "Wu", "Wd")
+
+    def forward(self, params, state, x, *, train=False, rng=None, mask=None):
+        return gated_ffn(x, params["Wg"], params["Wu"], params["Wd"]), state
+
+
+#: tokens a wide per-token layer takes at once: a longer input (a batched
+#: admission's prompts) is walked in blocks of this many, which bounds the
+#: hidden activations (and an expert layer's token-expert rows) held at a time
+TOKEN_BLOCK = 4096
+
+
+# graftlint: traced
+def in_token_blocks(fn, *arrays):
+    """``fn(*arrays)`` over arrays that share a leading token axis [N, ...],
+    ``TOKEN_BLOCK`` tokens at a time where N is a longer multiple of it (the
+    results, a pytree of [N, ...] arrays, put together again)."""
+    n = arrays[0].shape[0]
+    if n <= TOKEN_BLOCK or n % TOKEN_BLOCK:
+        return fn(*arrays)
+    blocks = tuple(a.reshape((n // TOKEN_BLOCK, TOKEN_BLOCK) + a.shape[1:])
+                   for a in arrays)
+    out = jax.lax.map(lambda blk: fn(*blk), blocks)
+    return jax.tree_util.tree_map(
+        lambda a: a.reshape((n,) + a.shape[2:]), out)
+
+
+# graftlint: traced
+def gated_ffn(x, wg, wu, wd):
+    """``(silu(x Wg) * (x Wu)) Wd`` over the last axis of x [..., d]."""
+    y = in_token_blocks(
+        lambda blk: (jax.nn.silu(blk @ wg) * (blk @ wu)) @ wd,
+        x.reshape(-1, x.shape[-1]))
+    return y.reshape(x.shape[:-1] + (wd.shape[-1],))
 
 
 @register_config
@@ -621,3 +701,33 @@ class TokenAndPositionEmbedding(BaseRecurrentLayerConf):
                           jnp.arange(c, dtype=jnp.int32)[None, :],
                           self.max_length - 1)               # [B, C]
         return params["W"][ids] + params["P"][pos]
+
+
+@register_config
+@dataclasses.dataclass
+class TokenEmbedding(TokenAndPositionEmbedding):
+    """Token ids [N, T] → embeddings [N, T, n_out] and nothing else: a
+    lookup with no position table, for models whose positions enter inside
+    attention (rotary). ``max_length`` is only the context the model
+    declares — what bounds a decoder's ``t_max``. The decode walks reach it
+    through :class:`TokenAndPositionEmbedding`'s seams (``embed_at``,
+    ``embed_chunk``), which here ignore the positions."""
+
+    def init_params(self, key, dtype=jnp.float32) -> Dict:
+        return {"W": jax.random.normal(key, (self.n_in, self.n_out),
+                                       dtype) * 0.02}
+
+    def forward(self, params, state, x, *, train=False, rng=None, mask=None):
+        ids = x.astype(jnp.int32)
+        if ids.ndim == 3:              # one-hot [N, T, V]
+            ids = jnp.argmax(ids, axis=-1)
+        return self.maybe_dropout(params["W"][ids], train=train,
+                                  rng=rng), state
+
+    # graftlint: traced
+    def embed_at(self, params, ids, positions):
+        return params["W"][jnp.asarray(ids, jnp.int32).reshape(-1)][:, None]
+
+    # graftlint: traced
+    def embed_chunk(self, params, ids, pos0):
+        return params["W"][jnp.asarray(ids, jnp.int32)]

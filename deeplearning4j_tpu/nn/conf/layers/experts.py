@@ -1,0 +1,149 @@
+"""Routed experts without drops: sigmoid scores, a selection bias, top-k,
+renormalised and scaled weights, and a shared expert.
+
+    s = sigmoid(x Wr)                        float32, over ALL experts
+    chosen = top_k(s + b)                    b: for choosing only
+    g_i = scaling * s_i / sum_{j chosen} s_j
+    y = sum_{i chosen} g_i E_i(x) + E_shared(x),   E = (silu(x Wg) * x Wu) Wd
+
+No capacity and no dropped token: every token is computed by every expert it
+chose, whatever the load. The layer is told which experts it HOLDS
+(``first_expert`` .. ``first_expert + experts_held``): it routes over all of
+them, and returns the part its own experts give plus the shared expert's —
+what one chip of an expert-parallel layer computes before the exchange (all
+of them by default).
+
+The second return (the layer's state) is two [E] int32 counts of this
+call's tokens by the expert they chose: ``expert_rows`` of every token (the
+rows the experts computed, the weights that were read) and
+``expert_tokens`` of the tokens a ``mask`` [N, T] marks (a decode block's
+alive lanes; every token without one). The mask counts only: a decode block
+computes every lane in every layer whether a request holds it or not, and
+this layer is no exception, so a stopped lane still routes and its experts
+are read — the difference between the two counts is what keeping stopped
+lanes out would save (a third of a step at partial occupancy, PERF.md §6,
+PR 29). Integer, not differentiated; a decode block sums them into the
+engine's counters.
+
+Two ways through the experts, one result: the built-in path is dense over
+the experts held (every expert on every token, weighted by a gate that is
+zero where it was not chosen) — the plain definition, right for a CPU at a
+test's size; the ``routed_experts`` helper (kernels/expert_ffn.py, the TPU's
+default) computes each token-expert pair once and reads only the experts
+hit."""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Dict
+
+import jax
+import jax.numpy as jnp
+
+from ...helpers import get_helper
+from ..input_type import InputType
+from ..serde import register_config
+from .attention import gated_ffn, in_token_blocks
+from .base import BaseRecurrentLayerConf
+
+@register_config
+@dataclasses.dataclass
+class RoutedExpertsLayer(BaseRecurrentLayerConf):
+    """Input [N, T, n_in] → [N, T, n_in]."""
+    num_experts: int = 8
+    top_k: int = 2
+    expert_hidden: int = 0
+    shared_experts: int = 1          # the shared expert is this many wide
+    routed_scaling: float = 1.0
+    first_expert: int = 0
+    experts_held: int = 0            # 0: all of them
+
+    def set_n_in(self, it: InputType) -> None:
+        if not self.n_in:
+            self.n_in = it.size
+        if not self.n_out:
+            self.n_out = self.n_in
+
+    def _held(self) -> int:
+        return self.experts_held or self.num_experts
+
+    def init_params(self, key, dtype=jnp.float32) -> Dict:
+        d, h, e = self.n_in, self.expert_hidden, self._held()
+        ks = jax.random.split(key, 8)
+        w = lambda k, shape: self._winit(k, shape, shape[-2], shape[-1],
+                                         dtype)
+        p = {"Wr": w(ks[0], (d, self.num_experts)),
+             "b": jnp.zeros((self.num_experts,), dtype),
+             "Wg": w(ks[1], (e, d, h)), "Wu": w(ks[2], (e, d, h)),
+             "Wd": w(ks[3], (e, h, d))}
+        if self.shared_experts:
+            hs = self.shared_experts * h
+            p.update(Sg=w(ks[4], (d, hs)), Su=w(ks[5], (d, hs)),
+                     Sd=w(ks[6], (hs, d)))
+        return p
+
+    def init_state(self) -> Dict:
+        zero = jnp.zeros((self.num_experts,), jnp.int32)
+        return {"expert_tokens": zero, "expert_rows": zero}
+
+    def regularizable(self):
+        return ("Wg", "Wu", "Wd", "Sg", "Su", "Sd")
+
+    # graftlint: traced
+    def route(self, params, x):
+        """x [N, d] → (chosen [N, k] int32, gates [N, k] f32)."""
+        s = jax.nn.sigmoid(jnp.einsum(
+            "nd,de->ne", x, params["Wr"],
+            preferred_element_type=jnp.float32).astype(jnp.float32))
+        _, chosen = jax.lax.top_k(s + params["b"].astype(jnp.float32)[None],
+                                  self.top_k)
+        picked = jnp.take_along_axis(s, chosen, axis=-1)
+        gates = self.routed_scaling * picked / jnp.sum(picked, axis=-1,
+                                                       keepdims=True)
+        return chosen.astype(jnp.int32), gates
+
+    # graftlint: traced
+    def _dense(self, params, x, chosen, gates):
+        """The experts held, every one on every token; [N, d] f32."""
+        local = chosen - self.first_expert
+        weight = jnp.sum(
+            jax.nn.one_hot(local, self._held(), dtype=jnp.float32)
+            * gates[..., None], axis=1)                       # [N, E]
+        h = jax.nn.silu(jnp.einsum("nd,edh->neh", x, params["Wg"])) \
+            * jnp.einsum("nd,edh->neh", x, params["Wu"])
+        y = jnp.einsum("neh,ehd->ned", h, params["Wd"])
+        return jnp.einsum("ned,ne->nd", y.astype(jnp.float32), weight)
+
+    # graftlint: traced
+    def _block(self, params, x):
+        """x [N, d] → (y [N, d], chosen [N, k])."""
+        with jax.named_scope("route"):
+            chosen, gates = self.route(params, x)
+        with jax.named_scope("experts"):
+            helper = get_helper("routed_experts")
+            if helper is not None:
+                y = helper(x, chosen, gates, params["Wg"], params["Wu"],
+                           params["Wd"], self.first_expert)
+            else:
+                y = self._dense(params, x, chosen, gates)
+        if self.shared_experts:
+            with jax.named_scope("shared"):
+                y = y + gated_ffn(x, params["Sg"], params["Su"],
+                                  params["Sd"]).astype(jnp.float32)
+        return y.astype(x.dtype), chosen
+
+    def forward(self, params, state, x, *, train=False, rng=None, mask=None):
+        x = self.maybe_dropout(x, train=train, rng=rng)
+        shape = x.shape
+        flat = x.reshape(-1, shape[-1])
+        # a long input in blocks: bounds the token-expert rows laid out
+        y, chosen = in_token_blocks(lambda blk: self._block(params, blk),
+                                    flat)
+        def count(marked):
+            return jnp.zeros((self.num_experts,), jnp.int32).at[
+                chosen.reshape(-1)].add(jnp.repeat(marked, self.top_k))
+        rows = count(jnp.ones((flat.shape[0],), jnp.int32))
+        tokens = rows if mask is None \
+            else count((mask.reshape(-1) > 0).astype(jnp.int32))
+        return y.reshape(shape), {"expert_tokens": tokens,
+                                  "expert_rows": rows}

@@ -43,6 +43,15 @@ class OutputLayer(DenseLayer):
     """Dense + loss head (reference OutputLayer/BaseOutputLayer). The loss is
     computed from the *pre-output* with the fused stable form (losses.py)."""
     loss: str = "mcxent"
+    #: False: a projection with no bias (the head of an RMSNorm decoder);
+    #: such a head takes the materialized loss, not the fused sparse CE
+    has_bias: bool = True
+
+    def init_params(self, key, dtype=jnp.float32) -> Dict:
+        p = super().init_params(key, dtype)
+        if not self.has_bias:
+            del p["b"]
+        return p
 
     def compute_score(self, params, labels, preoutput, mask=None,
                       average: bool = True):
@@ -50,6 +59,8 @@ class OutputLayer(DenseLayer):
                             self.activation or "identity", mask, average)
 
     def preoutput(self, params, x):
+        if not self.has_bias:
+            return x @ params["W"]
         return x @ params["W"] + chan(params["b"], x.ndim)
 
     def forward(self, params, state, x, *, train=False, rng=None, mask=None):

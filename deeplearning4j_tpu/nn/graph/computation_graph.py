@@ -290,7 +290,7 @@ class ComputationGraph:
             if str(getattr(layer, "activation", "")).lower() != "softmax":
                 continue
             from ..conf.layers import OutputLayer
-            if not isinstance(layer, OutputLayer):
+            if not isinstance(layer, OutputLayer) or not layer.has_bias:
                 continue                 # needs a W/b projection to fuse
             y = labels.get(out_name)
             if y is None or not jnp.issubdtype(jnp.asarray(y).dtype,

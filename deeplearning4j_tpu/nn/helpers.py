@@ -64,6 +64,9 @@ _DEFAULT_PROVIDERS: Dict[str, str] = {
     # where it wins. Ring attention (enable_ring_attention) replaces this
     # slot explicitly for sequence-parallel training.
     "attention": "deeplearning4j_tpu.kernels.pallas_attention",
+    # drop-free routed experts: the grouped FFN kernel reads only the
+    # experts hit (TPU only; the layer's dense jnp path elsewhere)
+    "routed_experts": "deeplearning4j_tpu.kernels.expert_ffn",
     # "lstm" is deliberately NOT a default provider: honest r2 measurements
     # (BASELINE.md) show XLA's scan lowering beats the Pallas kernel at
     # char-RNN shapes in both f32 (11.5 vs 12.5 ms/step) and bf16 (8.0 vs

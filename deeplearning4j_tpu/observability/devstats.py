@@ -103,6 +103,10 @@ def kv_cache_stats(engine) -> dict:
         # g: heads sharing one 128-lane row of the slab (1 = unpacked,
         # and always for a paged pool) — the engine's own account
         "heads_per_row": int(getattr(engine, "kv_heads_per_row", 1)),
+        # a latent (compressed-KV) slab: bytes a cached token takes over
+        # all its layers; 0 for per-head k/v
+        "latent_bytes_per_token": int(getattr(
+            engine, "latent_cache_bytes_per_token", 0)),
     }
     # paged engine (ISSUE 12): slot_shape is the POOL shape
     # [P, H, page_size, Dh] and bytes_per_slot is bytes per PAGE; the
@@ -135,7 +139,8 @@ class DeviceStats:
     Registry integration: ``devstats_live_array_bytes`` /
     ``devstats_live_arrays`` gauges (collection-time callbacks) and a
     ``devstats_kv_cache_bytes{engine=...}`` and
-    ``devstats_kv_heads_per_row{engine=...}`` gauges per attached
+    ``devstats_kv_heads_per_row{engine=...}`` /
+    ``devstats_latent_cache_bytes_per_token{engine=...}`` gauges per attached
     engine — all weakref'd, so a retired engine reads 0 instead of being
     pinned (with its device caches) by the registry."""
 
@@ -152,6 +157,10 @@ class DeviceStats:
                                  "heads sharing one 128-lane row of the "
                                  "slab KV cache (1 = unpacked)",
                                  ("engine",))
+        self._g_latent = reg.gauge(
+            "devstats_latent_cache_bytes_per_token",
+            "bytes one cached token takes over all latent-attention "
+            "layers (0 = per-head k/v cache)", ("engine",))
         reg.gauge("devstats_live_arrays",
                   "jax.live_arrays() count").set_function(
             _live_count)
@@ -169,6 +178,8 @@ class DeviceStats:
         # the engine's own label: no walk over the cache leaves
         self._g_rows.labels(str(name)).set_function(
             lambda: int(getattr(wref(), "kv_heads_per_row", 0)))
+        self._g_latent.labels(str(name)).set_function(
+            lambda: int(getattr(wref(), "latent_cache_bytes_per_token", 0)))
         return self
 
     def snapshot(self) -> dict:
