@@ -29,10 +29,14 @@ machine-checks those invariants:
   benches (``BENCH_MODE=generate --audit-compiles``); plus
   :class:`TransferAudit`, its sibling for host syncs — per-tag
   device→host readback counts through the ``ops.transfer.device_fetch``
-  seam, with a ≤1-readback-per-decode-block budget check.
+  seam, with a ≤1-readback-per-decode-block budget check; and
+  :class:`AttentionPlanAudit` — the attention calls traced in a region
+  by the plan each took (packed 128-lane tile, folded, short,
+  materialized).
 """
 
-from .compile_audit import (CompileAudit, CompileBudgetError, TransferAudit,
+from .compile_audit import (AttentionPlanAudit, CompileAudit,
+                            CompileBudgetError, TransferAudit,
                             TransferBudgetError)
 from .lint import (Finding, LintCache, LintRunner, RULES,
                    collect_package_facts, load_baseline, lint_paths,
@@ -40,7 +44,8 @@ from .lint import (Finding, LintCache, LintRunner, RULES,
 from .lock_audit import LockAudit, LockOrderError
 
 __all__ = [
-    "CompileAudit", "CompileBudgetError", "TransferAudit",
+    "AttentionPlanAudit", "CompileAudit", "CompileBudgetError",
+    "TransferAudit",
     "TransferBudgetError", "Finding", "LintCache", "LintRunner", "RULES",
     "LockAudit", "LockOrderError", "collect_package_facts",
     "lint_paths", "load_baseline", "new_findings", "write_baseline",

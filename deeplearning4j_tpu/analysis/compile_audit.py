@@ -163,6 +163,44 @@ class TransferAudit:
                 f"block(s) exceeds {max_per_block}/block")
 
 
+class AttentionPlanAudit:
+    """Counts the attention calls TRACED within a ``with`` block by the
+    plan each took (``nn.helpers.note_attention_plan``: ``packed`` with its
+    ``g`` and tile sizes, ``folded``, ``short``, ``materialized``), so a
+    test or a reader of a run can say how many of a program's attention
+    calls engaged the packed 128-lane tile. Tracing only: a program served
+    from jit's cache adds nothing.
+
+    Usage::
+
+        with AttentionPlanAudit() as plans:
+            step.lower(*args)
+        assert plans.calls("packed") == plans.calls() == 24
+    """
+
+    def __enter__(self) -> "AttentionPlanAudit":
+        from ..nn.helpers import attention_plan_counts
+        self._counts = attention_plan_counts
+        self._start = attention_plan_counts()
+        self._end: Optional[Dict[str, int]] = None
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        self._end = self._counts()
+
+    def plans(self) -> Dict[str, int]:
+        """Calls since entry by plan (``"packed,g=2,kb=1024,qb=1024"``);
+        live inside the block, frozen at exit."""
+        now = self._end if self._end is not None else self._counts()
+        return {k: n - self._start.get(k, 0) for k, n in sorted(now.items())
+                if n != self._start.get(k, 0)}
+
+    def calls(self, kind: Optional[str] = None) -> int:
+        """Calls since entry: all, or those whose plan is ``kind``."""
+        return sum(n for k, n in self.plans().items()
+                   if kind is None or k.split(",")[0] == kind)
+
+
 class _CompileLogHandler(logging.Handler):
     def __init__(self, audit: "CompileAudit"):
         super().__init__(level=logging.DEBUG)

@@ -46,7 +46,7 @@ from jax.experimental.pallas import tpu as pltpu
 from .pallas_attention import _interpret_default, on_device_blocks
 
 NEG = -1e30
-ROWW = 8          # row-scalar carrier width, matches pallas_attention.ROWW
+ROWW = 8          # row-scalar carriers travel as [BH, T, ROWW]
 
 #: largest T the whole-block kernel accepts (one [T, T] f32 logits tile
 #: per head must fit VMEM alongside its neighbors)

@@ -18,7 +18,7 @@ import numpy as np
 from ..input_type import InputType
 from ..serde import register_config
 from .base import BaseRecurrentLayerConf
-from ...helpers import get_helper
+from ...helpers import get_helper, note_attention_plan
 
 
 @register_config
@@ -88,6 +88,7 @@ class SelfAttentionLayer(BaseRecurrentLayerConf):
         if out is None:
             # no helper, or the helper declined (e.g. flash kernel below
             # its min_seq_len): built-in materialized-softmax path
+            note_attention_plan("materialized")
             scale = 1.0 / jnp.sqrt(jnp.asarray(hs, dtype))
             logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
             neg = jnp.asarray(-1e30, dtype)

@@ -49,6 +49,32 @@ def attention_spmd_context():
     the current trace, or None under a single-device jit."""
     return getattr(_SPMD, "ctx", None)
 
+_PLAN_LOCK = threading.Lock()
+_PLANS: Dict[str, int] = {}
+
+
+def note_attention_plan(kind: str, **detail) -> None:
+    """Called while a program is TRACED, once per attention call: which
+    path the call took — ``packed`` (heads side by side in 128-lane tiles,
+    with its ``g`` and tile sizes), ``folded`` ([BH, T, Dh] through the same
+    flash kernels), ``short`` (the whole-block short-T kernels) or
+    ``materialized`` (the built-in softmax). The count is process-wide and
+    only grows; readers snapshot and subtract
+    (:func:`attention_plan_counts`, ``analysis.AttentionPlanAudit``,
+    ``devstats``' ``attention_plans``)."""
+    key = kind + "".join(f",{k}={v}" for k, v in sorted(detail.items()))
+    with _PLAN_LOCK:
+        _PLANS[key] = _PLANS.get(key, 0) + 1
+
+
+def attention_plan_counts() -> Dict[str, int]:
+    """Attention calls traced so far in this process, by plan
+    (``"packed,g=2,kb=512,qb=512"``, ``"folded,..."``, ``"short"``,
+    ``"materialized"``)."""
+    with _PLAN_LOCK:
+        return dict(_PLANS)
+
+
 # Lazy default discovery — the analog of the reference's reflective
 # Class.forName("...CudnnConvolutionHelper") at ConvolutionLayer.java:69-76:
 # the kernel module providing this kind self-registers on first use. The

@@ -196,6 +196,11 @@ class DeviceStats:
             except Exception as e:   # noqa: BLE001 — degrade per engine
                 kv[name] = {"error": f"{type(e).__name__}: {e}"[:200]}
         out["kv_cache"] = kv
+        # attention calls traced in this process by the plan each took
+        # (nn.helpers.note_attention_plan): how many engaged the packed
+        # 128-lane flash tile
+        from ..nn.helpers import attention_plan_counts
+        out["attention_plans"] = attention_plan_counts()
         return out
 
 

@@ -127,7 +127,7 @@ def _ring_flash_fwd_impl(ql3, kl3, vl3, axis, n_dev, causal, qb, kb,
             kc, vc = kv
             op, lsep = _flash_fwd_impl(ql3, kc, vc, None, 1, diag, qb, kb,
                                        interpret)
-            return op.astype(jnp.float32), lsep[..., 0].astype(jnp.float32)
+            return op.astype(jnp.float32), lsep[:, 0, 0].astype(jnp.float32)
         return fn
 
     def skip_fn(kv):
@@ -163,22 +163,19 @@ def _ring_flash_fwd(ql3, kl3, vl3, axis, n_dev, causal, qb, kb, interpret):
 
 
 def _ring_flash_bwd(axis, n_dev, causal, qb, kb, interpret, res, do):
-    from ..kernels.pallas_attention import ROWW, _flash_bwd_impl
+    from ..kernels.pallas_attention import _flash_bwd_impl
     ql3, kl3, vl3, o, lse = res
     bh, t, d = ql3.shape
     my = lax.axis_index(axis) if n_dev > 1 else jnp.int32(0)
-    lse3 = jnp.broadcast_to(lse[..., None], (bh, t, ROWW))
-    # delta depends only on do/o (loop-invariant): compute ONCE, not per
-    # ring step
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-    delta3 = jnp.broadcast_to(delta[..., None], (bh, t, ROWW))
+    # the kernels' row carrier: [BH, 1, 1, T]; the row term rowsum(dO·O) is
+    # made inside the dq kernel from its dO and O tiles
+    lse3 = lse[:, None, None, :]
 
     def pair_fn(diag):
         def fn(kv):
             kc, vc = kv
             dqp, dkp, dvp = _flash_bwd_impl(ql3, kc, vc, None, 1, o, lse3,
-                                            do, diag, qb, kb, interpret,
-                                            delta3=delta3)
+                                            do, diag, qb, kb, interpret)
             return (dqp.astype(jnp.float32), dkp.astype(jnp.float32),
                     dvp.astype(jnp.float32))
         return fn

@@ -12,7 +12,10 @@ Checks:
   short-T attention  (pallas_shortseq, T=512 flagship shape, causal,
                       unmasked + ragged key mask)
   general flash pair (pallas_attention, T=4096 long-context shape,
-                      causal, unmasked + ragged in-kernel key mask)
+                      causal, unmasked + ragged in-kernel key mask; the
+                      packed tile at gpt2-medium.train-t1024's shape and at
+                      gpt2-large.chat-open's masked 1024 bucket; the folded
+                      operands at Dh 192)
   fused sparse CE    (fused_ce vs one-hot mcxent, LM head shape)
   analytic LayerNorm (layernorm custom VJP vs naive autodiff)
   decode block layout (decode_block4_impl at gpt2-large shapes, 16 slots:
@@ -257,12 +260,21 @@ def main():
     # smaller B/H than the bench shape: the f32 materialized REFERENCE
     # must also fit/compile quickly ([B,H,T,T] logits are 3.2 GB at the
     # full bench shape); the kernel path itself is shape-generic
-    check_attention(
-        rows,
-        lambda q, k, v, km: pallas_flash_attention(q, k, v, causal=True,
-                                                   interpret=False,
-                                                   key_mask=km),
-        "flash@4096", b=2, t=4096, h=4, d=64, key_mask_tail=2048)
+    flash = lambda q, k, v, km: pallas_flash_attention(
+        q, k, v, causal=True, interpret=False, key_mask=km)
+    check_attention(rows, flash, "flash@4096", b=2, t=4096, h=4, d=64,
+                    key_mask_tail=2048)
+    # Dh 192 does not pack: the folded [BH, T, Dh] operands, g = 1
+    check_attention(rows, flash, "flash@2048xDh192", b=2, t=2048, h=4,
+                    d=192, key_mask_tail=512)
+    # the packed tile (two 64-wide heads to a 128-lane row) at the shapes
+    # the cells run it: gpt2-medium.train-t1024's step, and the masked
+    # 1024 bucket of gpt2-large.chat-open's admissions (H 20; 4 of its 16
+    # rows, for the float32 reference's sake)
+    check_attention(rows, flash, "flash@train-t1024", b=8, t=1024, h=16,
+                    d=64, key_mask_tail=0)
+    check_attention(rows, flash, "flash@chat-open-1024", b=4, t=1024, h=20,
+                    d=64, key_mask_tail=256)
     check_fused_ce(rows)
     check_layernorm(rows)
     check_decode_block_layout(rows)
