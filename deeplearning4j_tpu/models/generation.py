@@ -50,9 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding
 
-from ..nn.conf.layers import (LatentAttentionLayer, RnnOutputLayer,
-                              RoutedExpertsLayer, SelfAttentionLayer,
-                              TokenAndPositionEmbedding)
+from ..nn.conf.layers import Window
 from ..nn.graph.computation_graph import scoped
 from ..nn.graph.vertices import LayerVertex
 from ..nn.helpers import attention_spmd
@@ -207,10 +205,13 @@ class TransformerDecoder:
                              "single-output graph")
         self.input_name = conf.network_inputs[0]
         self.output_name = conf.network_outputs[0]
+        # what a layer offers says what it is to the walk (``Window``):
+        # ``advance`` keeps sequence state, ``embed`` turns ids into rows
         self.attn_names: List[str] = []
-        # vertices that route tokens to experts: their per-expert token
-        # counts (the layer's second return) leave a decode block as
-        # MOE_COUNTERS columns; none, and every program is what it was
+        # vertices whose class says ``counts_tokens`` (routed experts): their
+        # per-expert token counts (the layer's second return) leave a decode
+        # block as MOE_COUNTERS columns; none, and every program is what it
+        # was
         self.moe_names: List[str] = []
         embed = None
         for name in conf.topological_order:
@@ -221,15 +222,15 @@ class TransformerDecoder:
                 raise ValueError(f"vertex '{name}' has a preprocessor; the "
                                  "decode walk supports plain transformer "
                                  "topologies only")
-            if isinstance(v.layer, SelfAttentionLayer):
+            if hasattr(v.layer, "advance"):
                 if not v.layer.causal:
                     raise ValueError(f"attention vertex '{name}' is not "
                                      "causal — cannot decode "
                                      "autoregressively")
                 self.attn_names.append(name)
-            elif isinstance(v.layer, TokenAndPositionEmbedding):
-                embed = v.layer
-            elif isinstance(v.layer, RoutedExpertsLayer):
+            elif hasattr(v.layer, "embed"):
+                embed, self._embed_name = v.layer, name
+            elif getattr(v.layer, "counts_tokens", False):
                 self.moe_names.append(name)
         if embed is None or not self.attn_names:
             raise ValueError("graph has no TokenAndPositionEmbedding / "
@@ -376,11 +377,8 @@ class TransformerDecoder:
         """Bytes one cached token takes over all latent-attention layers
         (each holds one ``[c_kv ; k_rope]`` row a token, nothing per
         head); 0 for a model whose cache is per-head k/v."""
-        item = jnp.dtype(self.net.compute_dtype).itemsize
-        return sum(v.layer.row_width * item
-                   for v in (self.net.conf.vertices[n]
-                             for n in self.attn_names)
-                   if isinstance(v.layer, LatentAttentionLayer))
+        return sum(self.net.conf.vertices[n].layer.latent_bytes_per_token(
+            self.net.compute_dtype) for n in self.attn_names)
 
     def program_peak_bytes(self, impl_name: str) -> Optional[int]:
         """:func:`compiled_peak_bytes` of an impl that has been
@@ -423,272 +421,58 @@ class TransformerDecoder:
                     self.net.compute_dtype, sharding=sharding)
                 for name in self.attn_names}
 
-    # -------------------------------------------------------------- walks
+    # --------------------------------------------------------------- walk
     # graftlint: traced
-    def _walk_prefill(self, params, state, caches, tokens, lengths):
-        """One teacher-forced pass over padded prompts [B, Tp]: fills
-        cache[:, :, :Tp] at every attention vertex (the attention itself
-        rides the standard helper seam — flash/short-T kernels) and
-        returns the logits at each row's LAST real position [B, V]."""
+    def _walk(self, params, state, caches, tokens, window, every=False):
+        """The graph, vertex by vertex, over ``tokens`` — padded prompts or
+        a window [B, C], or one id a row [B] — placed by ``window`` (a
+        :class:`Window`: fresh prompt, decode step, chunk, verify window;
+        slab or pages). The embedding and every vertex that keeps sequence
+        state read the window themselves (``embed`` / ``advance``: a prompt
+        rides the attention helper seam — flash/short-T kernels — while it
+        fills the cache, a step or a window attends earlier context through
+        it); ``caches`` None is the NO-CACHE reference, a full
+        teacher-forced forward that writes nothing. Where ``window.alive``
+        is given, a counting vertex gets it as its mask and its second
+        return is kept. The head projects each row's LAST real position
+        (the one position of a step) or, with ``every``, all of them
+        (speculative acceptance needs every position's distribution).
+        Returns (logits [B, V] or [B, C, V] f32, new caches, counts)."""
         conf = self.net.conf
-        tp = tokens.shape[1]
-        kmask = (jnp.arange(tp, dtype=jnp.int32)[None, :] <
-                 lengths[:, None]).astype(jnp.float32)
         acts = {self.input_name: tokens}
-        new_caches = {}
+        new_caches, tally = {}, []
+        counting = self.moe_names if window.alive is not None else ()
         logits = None
         for name in scoped(conf.topological_order):
             v = conf.vertices[name]
             xs = [acts[i] for i in conf.vertex_inputs[name]]
-            if isinstance(v, LayerVertex) and \
-                    isinstance(v.layer, SelfAttentionLayer):
-                acts[name], new_caches[name] = v.layer.prefill_forward(
-                    params[name], xs[0], caches[name], mask=kmask)
+            if name == self._embed_name:
+                acts[name] = v.layer.embed(params[name], xs[0], window)
+            elif name in self.attn_names:
+                acts[name], new_caches[name] = v.layer.advance(
+                    params[name], xs[0],
+                    None if caches is None else caches[name], window)
+            elif name == self.output_name and every:
+                logits = v.layer.preoutput(params[name], xs[0])
             elif name == self.output_name:
-                # gather each row's last real hidden state BEFORE the
-                # vocab projection: [B, Tp, V] logits would be GBs at a
-                # 32k vocab; [B, 1, V] is what sampling needs
-                idx = jnp.clip(lengths - 1, 0)[:, None, None]
-                h_last = jnp.take_along_axis(xs[0], idx, axis=1)
-                logits = v.layer.preoutput(params[name], h_last)[:, 0]
-            else:
-                y, _ = v.forward(params[name], state[name], xs, train=False,
-                                 rng=None, masks=[None] * len(xs))
-                acts[name] = y
-        return logits.astype(jnp.float32), new_caches
-
-    # graftlint: traced
-    def _walk_decode(self, params, state, caches, ids, positions,
-                     alive=None, tally=None):
-        """One single-token step: ids [B] at per-row ``positions`` [B] →
-        (logits [B, V] f32, new caches). ``alive`` [B] bool marks the lanes
-        an expert layer counts, and ``tally`` (a list) receives each expert
-        layer's per-expert token counts (its second return)."""
-        conf = self.net.conf
-        acts = {self.input_name: ids}
-        new_caches = {}
-        logits = None
-        for name in scoped(conf.topological_order):
-            v = conf.vertices[name]
-            xs = [acts[i] for i in conf.vertex_inputs[name]]
-            if isinstance(v, LayerVertex) and \
-                    isinstance(v.layer, TokenAndPositionEmbedding):
-                acts[name] = v.layer.embed_at(params[name], xs[0], positions)
-            elif isinstance(v, LayerVertex) and \
-                    isinstance(v.layer, SelfAttentionLayer):
-                acts[name], new_caches[name] = v.layer.decode_forward(
-                    params[name], xs[0], caches[name], positions)
-            elif name == self.output_name:
-                logits = v.layer.preoutput(params[name], xs[0])[:, 0]
-            elif tally is not None and name in self.moe_names:
+                h = xs[0]
+                if window.valid is not None:
+                    # gather each row's last real hidden state BEFORE the
+                    # vocab projection: [B, Tp, V] logits would be GBs at
+                    # a 32k vocab; [B, 1, V] is what sampling needs
+                    idx = jnp.clip(window.valid - 1, 0)[:, None, None]
+                    h = jnp.take_along_axis(h, idx, axis=1)
+                logits = v.layer.preoutput(params[name], h)[:, 0]
+            elif name in counting:
                 acts[name], load = v.forward(
                     params[name], state[name], xs, train=False, rng=None,
-                    masks=[alive[:, None]])
+                    masks=[window.alive[:, None]])
                 tally.append(load)
             else:
                 y, _ = v.forward(params[name], state[name], xs, train=False,
                                  rng=None, masks=[None] * len(xs))
                 acts[name] = y
-        return logits.astype(jnp.float32), new_caches
-
-    # graftlint: traced
-    def _walk_chunk(self, params, state, caches, tokens, pos0, valid):
-        """One chunked-prefill window: tokens [B, C] at absolute start
-        positions ``pos0`` [B] → (logits at each row's LAST real window
-        position [B, V] f32, new caches). The chunk attends earlier
-        chunks' context through the cache (chunk_forward), so a long
-        prompt prefills in bounded windows interleaved with decode
-        blocks instead of one monopolizing device program."""
-        conf = self.net.conf
-        acts = {self.input_name: tokens}
-        new_caches = {}
-        logits = None
-        for name in scoped(conf.topological_order):
-            v = conf.vertices[name]
-            xs = [acts[i] for i in conf.vertex_inputs[name]]
-            if isinstance(v, LayerVertex) and \
-                    isinstance(v.layer, TokenAndPositionEmbedding):
-                acts[name] = v.layer.embed_chunk(params[name], xs[0], pos0)
-            elif isinstance(v, LayerVertex) and \
-                    isinstance(v.layer, SelfAttentionLayer):
-                acts[name], new_caches[name] = v.layer.chunk_forward(
-                    params[name], xs[0], caches[name], pos0)
-            elif name == self.output_name:
-                idx = jnp.clip(valid - 1, 0)[:, None, None]
-                h_last = jnp.take_along_axis(xs[0], idx, axis=1)
-                logits = v.layer.preoutput(params[name], h_last)[:, 0]
-            else:
-                y, _ = v.forward(params[name], state[name], xs, train=False,
-                                 rng=None, masks=[None] * len(xs))
-                acts[name] = y
-        return logits.astype(jnp.float32), new_caches
-
-    # graftlint: traced
-    def _walk_paged_decode(self, params, state, caches, ptables, ids,
-                           positions):
-        """One single-token step over PAGED pools: like
-        :meth:`_walk_decode`, but every attention vertex writes/reads
-        through the shared per-slot page table (``ptables`` [B, NP] —
-        one table serves every layer, like a slot index does)."""
-        conf = self.net.conf
-        acts = {self.input_name: ids}
-        new_caches = {}
-        logits = None
-        for name in scoped(conf.topological_order):
-            v = conf.vertices[name]
-            xs = [acts[i] for i in conf.vertex_inputs[name]]
-            if isinstance(v, LayerVertex) and \
-                    isinstance(v.layer, TokenAndPositionEmbedding):
-                acts[name] = v.layer.embed_at(params[name], xs[0], positions)
-            elif isinstance(v, LayerVertex) and \
-                    isinstance(v.layer, SelfAttentionLayer):
-                acts[name], new_caches[name] = v.layer.paged_decode_forward(
-                    params[name], xs[0], caches[name], ptables, positions)
-            elif name == self.output_name:
-                logits = v.layer.preoutput(params[name], xs[0])[:, 0]
-            else:
-                y, _ = v.forward(params[name], state[name], xs, train=False,
-                                 rng=None, masks=[None] * len(xs))
-                acts[name] = y
-        return logits.astype(jnp.float32), new_caches
-
-    # graftlint: traced
-    def _walk_paged_chunk(self, params, state, caches, ptables, tokens,
-                          pos0, valid):
-        """One paged prefill/chunk window: tokens [B, C] at absolute
-        start positions ``pos0`` [B] (0 for fresh prompts, the shared-
-        prefix length after a prefix-cache hit) with ``valid`` [B] real
-        tokens per row. The paged analogue of :meth:`_walk_chunk` —
-        earlier context (including READ-ONLY shared prefix pages) is
-        attended through the page tables, so a prefix-cache hit
-        prefills only the tail. Returns (logits at each row's last real
-        window position [B, V] f32, new pools)."""
-        conf = self.net.conf
-        acts = {self.input_name: tokens}
-        new_caches = {}
-        logits = None
-        for name in scoped(conf.topological_order):
-            v = conf.vertices[name]
-            xs = [acts[i] for i in conf.vertex_inputs[name]]
-            if isinstance(v, LayerVertex) and \
-                    isinstance(v.layer, TokenAndPositionEmbedding):
-                acts[name] = v.layer.embed_chunk(params[name], xs[0], pos0)
-            elif isinstance(v, LayerVertex) and \
-                    isinstance(v.layer, SelfAttentionLayer):
-                # through the prefill-named seam (which delegates to
-                # paged_chunk_forward): admission tails and chunk
-                # windows are the same computation, and the fused
-                # paged-prefill kernel (ROADMAP 5) overrides here
-                acts[name], new_caches[name] = \
-                    v.layer.paged_prefill_forward(
-                        params[name], xs[0], caches[name], ptables,
-                        pos0, valid)
-            elif name == self.output_name:
-                idx = jnp.clip(valid - 1, 0)[:, None, None]
-                h_last = jnp.take_along_axis(xs[0], idx, axis=1)
-                logits = v.layer.preoutput(params[name], h_last)[:, 0]
-            else:
-                y, _ = v.forward(params[name], state[name], xs, train=False,
-                                 rng=None, masks=[None] * len(xs))
-                acts[name] = y
-        return logits.astype(jnp.float32), new_caches
-
-    # graftlint: traced
-    def _walk_verify(self, params, state, caches, tokens, pos0, valid):
-        """Speculative verify window (ISSUE 16): tokens [B, C] are each
-        lane's last emitted token + its C-1 drafted candidates, forward
-        at absolute positions pos0 + [0, C) with PER-CELL masked cache
-        writes (``valid`` [B] — a frozen lane writes nothing, a lane at
-        the context edge writes only what fits). Unlike the chunk walk,
-        the output layer projects ALL window positions — acceptance
-        needs every position's next-token distribution. Rejected cells
-        are overwritten by the next dispatch before anything attends
-        them (write-before-attend), which is what makes the slab rewind
-        a pure position-clamp. Returns (logits [B, C, V] f32, caches)."""
-        conf = self.net.conf
-        acts = {self.input_name: tokens}
-        new_caches = {}
-        logits = None
-        for name in scoped(conf.topological_order):
-            v = conf.vertices[name]
-            xs = [acts[i] for i in conf.vertex_inputs[name]]
-            if isinstance(v, LayerVertex) and \
-                    isinstance(v.layer, TokenAndPositionEmbedding):
-                acts[name] = v.layer.embed_chunk(params[name], xs[0], pos0)
-            elif isinstance(v, LayerVertex) and \
-                    isinstance(v.layer, SelfAttentionLayer):
-                acts[name], new_caches[name] = v.layer.chunk_forward(
-                    params[name], xs[0], caches[name], pos0, valid)
-            elif name == self.output_name:
-                # ALL positions' logits: [B, C, V] — C = K+1 stays
-                # single-digit, so the full projection is small
-                logits = v.layer.preoutput(params[name], xs[0])
-            else:
-                y, _ = v.forward(params[name], state[name], xs, train=False,
-                                 rng=None, masks=[None] * len(xs))
-                acts[name] = y
-        return logits.astype(jnp.float32), new_caches
-
-    # graftlint: traced
-    def _walk_paged_verify(self, params, state, caches, ptables, tokens,
-                           pos0, valid):
-        """Paged twin of :meth:`_walk_verify`: the window's writes ride
-        :meth:`paged_chunk_forward`'s existing ``valid`` null-page
-        redirect (invalid cells land in trash, shared prefix pages stay
-        read-only), and all C window positions project to logits."""
-        conf = self.net.conf
-        acts = {self.input_name: tokens}
-        new_caches = {}
-        logits = None
-        for name in scoped(conf.topological_order):
-            v = conf.vertices[name]
-            xs = [acts[i] for i in conf.vertex_inputs[name]]
-            if isinstance(v, LayerVertex) and \
-                    isinstance(v.layer, TokenAndPositionEmbedding):
-                acts[name] = v.layer.embed_chunk(params[name], xs[0], pos0)
-            elif isinstance(v, LayerVertex) and \
-                    isinstance(v.layer, SelfAttentionLayer):
-                acts[name], new_caches[name] = v.layer.paged_chunk_forward(
-                    params[name], xs[0], caches[name], ptables, pos0,
-                    valid)
-            elif name == self.output_name:
-                logits = v.layer.preoutput(params[name], xs[0])
-            else:
-                y, _ = v.forward(params[name], state[name], xs, train=False,
-                                 rng=None, masks=[None] * len(xs))
-                acts[name] = y
-        return logits.astype(jnp.float32), new_caches
-
-    # graftlint: traced
-    def _walk_recompute(self, params, state, tokens, lengths):
-        """Full teacher-forced forward over the padded context + gather of
-        the last real position's logits — the per-token program of the
-        NO-CACHE baseline (models.generate's fixed-bucket recompute),
-        without any cache writes so the decode-vs-recompute A/B charges
-        the baseline only for what it actually does."""
-        conf = self.net.conf
-        tp = tokens.shape[1]
-        kmask = (jnp.arange(tp, dtype=jnp.int32)[None, :] <
-                 lengths[:, None]).astype(jnp.float32)
-        acts = {self.input_name: tokens}
-        logits = None
-        for name in scoped(conf.topological_order):
-            v = conf.vertices[name]
-            xs = [acts[i] for i in conf.vertex_inputs[name]]
-            if name == self.output_name:
-                idx = jnp.clip(lengths - 1, 0)[:, None, None]
-                h_last = jnp.take_along_axis(xs[0], idx, axis=1)
-                logits = v.layer.preoutput(params[name], h_last)[:, 0]
-            elif isinstance(v, LayerVertex) and \
-                    isinstance(v.layer, SelfAttentionLayer):
-                y, _ = v.layer.forward(params[name], state[name], xs[0],
-                                       train=False, mask=kmask)
-                acts[name] = y
-            else:
-                y, _ = v.forward(params[name], state[name], xs, train=False,
-                                 rng=None, masks=[None] * len(xs))
-                acts[name] = y
-        return logits.astype(jnp.float32)
+        return logits.astype(jnp.float32), new_caches, tally
 
     def recompute_logits(self, tokens, lengths, temps=None, seed: int = 0):
         """No-cache baseline step: one full forward over [B, Tp] plus the
@@ -700,7 +484,9 @@ class TransformerDecoder:
         fn = self._jit.get("recompute")
         if fn is None:
             def recompute_impl(params, state, tokens, lengths, temps, key):
-                logits = self._walk_recompute(params, state, tokens, lengths)
+                logits, _, _ = self._walk(
+                    params, state, None, tokens,
+                    Window.fresh(tokens.shape[1], lengths))
                 return self._select(logits, temps, key), logits
             # no donation on purpose: the baseline recomputes from the SAME
             # tokens every step and mutates no carried state
@@ -864,6 +650,26 @@ class TransformerDecoder:
         return jax.jit(sharded_impl, donate_argnums=donate,
                        in_shardings=in_specs, out_shardings=out_specs)
 
+    def _jit_twin(self, impl, paged, donate, in_specs, out_specs):
+        """One body, two programs: ``impl(params, state, caches, ptables,
+        *rest)`` jitted as it stands, under ``paged_`` + its name, or as
+        the slab program, which has no ``ptables`` argument. The specs are
+        the slab's; the paged twin has the pools' in the caches' place and
+        the tables' after them."""
+        if paged:
+            pool_sh = self._pool_shardings()
+            mat = None if self.mesh is None else self._sharding_sets()[3]
+            impl.__name__ = "paged_" + impl.__name__
+            return self._jit_sharded(
+                impl, donate,
+                in_specs=in_specs[:2] + (pool_sh, mat) + in_specs[3:],
+                out_specs=out_specs[:-1] + (pool_sh,))
+
+        def slab(params, state, caches, *rest):
+            return impl(params, state, caches, None, *rest)
+        slab.__name__ = impl.__name__
+        return self._jit_sharded(slab, donate, in_specs, out_specs)
+
     def _fn(self, name):
         fn = self._jit.get(name)
         if fn is not None:
@@ -878,8 +684,9 @@ class TransformerDecoder:
         if name == "prefill":
             def prefill_impl(params, state, caches, tokens, lengths, temps,
                              key):
-                logits, caches = self._walk_prefill(params, state, caches,
-                                                    tokens, lengths)
+                logits, caches, _ = self._walk(
+                    params, state, caches, tokens,
+                    Window.fresh(tokens.shape[1], lengths))
                 return self._select(logits, temps, key), logits, caches
             fn = self._jit_sharded(
                 prefill_impl, donate,
@@ -888,8 +695,9 @@ class TransformerDecoder:
         elif name == "step":
             def decode_step_impl(params, state, caches, ids, positions,
                                  temps, key):
-                logits, caches = self._walk_decode(params, state, caches,
-                                                   ids, positions)
+                logits, caches, _ = self._walk(
+                    params, state, caches, ids,
+                    Window(start=positions))
                 return self._select(logits, temps, key), logits, caches
             fn = self._jit_sharded(
                 decode_step_impl, donate,
@@ -909,8 +717,8 @@ class TransformerDecoder:
                 c1 = {n: {kk: jnp.zeros((m,) + leaf.shape[1:], leaf.dtype)
                           for kk, leaf in caches[n].items()}
                       for n in self.attn_names}
-                logits, c1 = self._walk_prefill(params, state, c1, tokens,
-                                                lengths)
+                logits, c1, _ = self._walk(params, state, c1, tokens,
+                                           Window.fresh(tp, lengths))
                 z = jnp.zeros((), jnp.int32)  # match slot dtype under x64
                 merged = caches
                 for i in range(m):    # static unroll: M <= num_slots
@@ -955,8 +763,8 @@ class TransformerDecoder:
                               caches[n][kk], slot[0], 1, axis=0)
                           for kk in caches[n]}
                       for n in self.attn_names}
-                logits, c1 = self._walk_chunk(params, state, c1, tokens,
-                                              pos0, valid)
+                logits, c1, _ = self._walk(params, state, c1, tokens,
+                                           Window(start=pos0, valid=valid))
                 merged = {n: {kk: jax.lax.dynamic_update_slice(
                                   caches[n][kk], c1[n][kk],
                                   (slot[0], z, z, z))
@@ -996,8 +804,9 @@ class TransformerDecoder:
                 # [M] is the sentinel's accumulated verdict for chunked
                 # windows (zeros on direct admission; unused — and
                 # DCE'd — on a non-sentinel decoder).
-                logits, caches = self._walk_paged_chunk(
-                    params, state, caches, ptables, tokens, pos0, valid)
+                logits, caches, _ = self._walk(
+                    params, state, caches, tokens,
+                    Window(start=pos0, valid=valid, pages=ptables))
                 sel = self._select(logits, temps, key)
                 if self.sentinel:
                     fault = fault_in | \
@@ -1013,53 +822,6 @@ class TransformerDecoder:
                 in_specs=(psh, None, pool_sh, None, None, None, None,
                           None, None, None),
                 out_specs=(None, pool_sh))
-        elif isinstance(name, tuple) and name[0] == "paged_block":
-            k_steps = int(name[1])
-
-            def paged_decode_block_impl(params, state, caches, ptables,
-                                        ids, positions, stopped, temps,
-                                        eos_ids, key, step0, key_salt):
-                # K decode steps over PAGED pools in ONE device program:
-                # same carry/freeze/key schedule as decode_block_impl
-                # (token-for-token parity paged-vs-slab is the bar), the
-                # page tables ride as a per-dispatch input — the host
-                # grows them between blocks (lazy page allocation), the
-                # scan itself never re-maps
-                def body(carry, _):
-                    caches, ids, pos, stop, fault, step = carry
-                    pos_c = jnp.minimum(pos, self.t_max - 1)
-                    logits, caches = self._walk_paged_decode(
-                        params, state, caches, ptables, ids, pos_c)
-                    if self.sentinel:
-                        fault = fault | self._fault_of(logits, stop)
-                    kk = jax.random.fold_in(
-                        key, jnp.bitwise_or(key_salt, step + 1))
-                    nxt = self._select(logits, temps, kk)
-                    nxt = jnp.where(stop, ids, nxt)
-                    hit_eos = jnp.logical_and(eos_ids >= 0, nxt == eos_ids)
-                    new_pos = jnp.where(stop, pos, pos + 1)
-                    new_stop = stop | hit_eos | (new_pos >= self.t_max)
-                    return (caches, nxt, new_pos, new_stop, fault,
-                            step + 1), nxt
-                fault0 = jnp.zeros_like(stopped)
-                (caches, ids, positions, stopped, fault, _), toks = \
-                    jax.lax.scan(
-                        body, (caches, ids, positions, stopped, fault0,
-                               step0), None, length=k_steps)
-                out = toks.T
-                if self.sentinel:
-                    # the verdict column rides the block's ONE readback
-                    out = jnp.concatenate(
-                        [out, fault.astype(jnp.int32)[:, None]], axis=1)
-                return out, ids, positions, stopped, caches
-            paged_decode_block_impl.__name__ = \
-                f"paged_decode_block{k_steps}_impl"
-            pool_sh = self._pool_shardings()
-            fn = self._jit_sharded(
-                paged_decode_block_impl, donate,
-                in_specs=(psh, None, pool_sh, mat, row, row, row, row,
-                          row, None, None, None),
-                out_specs=(mat, row, row, row, pool_sh))
         elif name == "kv_export":
             def kv_export_impl(caches, pids):
                 # gather ``pids``'s page contents out of every layer's
@@ -1088,26 +850,31 @@ class TransformerDecoder:
                                    train_donate_argnums((0,)),
                                    in_specs=(pool_sh, None, None),
                                    out_specs=pool_sh)
-        elif isinstance(name, tuple) and name[0] == "block":
+        elif isinstance(name, tuple) and name[0] in ("block", "paged_block"):
             k_steps = int(name[1])
 
-            def decode_block_impl(params, state, caches, ids, positions,
-                                  stopped, temps, eos_ids, key, step0,
-                                  key_salt):
+            def decode_block_impl(params, state, caches, ptables, ids,
+                                  positions, stopped, temps, eos_ids, key,
+                                  step0, key_salt):
                 # K decode steps fused into ONE device program
                 # (lax.scan): cache state, per-row stop flags, the
-                # sentinel's fault accumulator, and the absolute step
-                # counter ride the carry; only the [B, K(+1)] token
-                # matrix ever needs to cross to the host. The key
-                # schedule folds the ABSOLUTE step index, so a given
-                # lane samples identically for every block size.
+                # sentinel's fault accumulator, the expert counters and
+                # the absolute step counter ride the carry; only the
+                # [B, K(+1)(+4)] matrix ever needs to cross to the host.
+                # The key schedule folds the ABSOLUTE step index, so a
+                # given lane samples identically for every block size.
+                # Over PAGED pools the page tables are one more input of
+                # the dispatch (None on the slab): the host grows them
+                # between blocks (lazy page allocation), the scan never
+                # re-maps — carry, freeze and key schedule are these
+                # same lines, which is what token-for-token parity
+                # paged-vs-slab rests on
                 def body(carry, _):
                     caches, ids, pos, stop, fault, moe, step = carry
                     pos_c = jnp.minimum(pos, self.t_max - 1)
-                    tally = [] if self.moe_names else None
-                    logits, caches = self._walk_decode(
-                        params, state, caches, ids, pos_c, alive=~stop,
-                        tally=tally)
+                    logits, caches, tally = self._walk(
+                        params, state, caches, ids,
+                        Window(start=pos_c, pages=ptables, alive=~stop))
                     if tally:
                         moe = moe + self._moe_sums(tally)
                     if self.sentinel:
@@ -1137,17 +904,18 @@ class TransformerDecoder:
             # would read as a blown-cache duplicate-signature compile
             # (_jit_sharded appends the per-mesh suffix the same way)
             decode_block_impl.__name__ = f"decode_block{k_steps}_impl"
-            fn = self._jit_sharded(
-                decode_block_impl, donate,
+            fn = self._jit_twin(
+                decode_block_impl, name[0] == "paged_block", donate,
                 in_specs=(psh, None, csh, row, row, row, row, row, None,
                           None, None),
                 out_specs=(mat, row, row, row, csh))
-        elif isinstance(name, tuple) and name[0] == "verify":
+        elif isinstance(name, tuple) and name[0] in ("verify",
+                                                     "paged_verify"):
             k_draft = int(name[1])
 
-            def verify_block_impl(params, state, caches, ids, positions,
-                                  draft, stopped, temps, eos_ids, key,
-                                  step0, key_salt):
+            def verify_block_impl(params, state, caches, ptables, ids,
+                                  positions, draft, stopped, temps, eos_ids,
+                                  key, step0, key_salt):
                 # speculative verify (ISSUE 16): ONE cache-aware forward
                 # over the window [last id | K drafted candidates] scores
                 # all K+1 next-token positions — roughly the memory
@@ -1157,12 +925,19 @@ class TransformerDecoder:
                 # and zeroes for frozen lanes; rejected cells are
                 # rewritten before ever attended, so rewind is the
                 # returned position itself (host clamps nothing extra).
+                # Over PAGED pools (``ptables``; None on the slab) the
+                # window's writes ride the paged chunk path's null-page
+                # redirect, and the HOST rewinds the page tables
+                # afterwards (truncate + refcount release) — the device
+                # program never re-maps
                 window = jnp.concatenate([ids[:, None], draft], axis=1)
                 wvalid = jnp.where(stopped, 0,
                                    jnp.clip(self.t_max - positions, 0,
                                             k_draft + 1))
-                logits, caches = self._walk_verify(
-                    params, state, caches, window, positions, wvalid)
+                logits, caches, _ = self._walk(
+                    params, state, caches, window,
+                    Window(start=positions, valid=wvalid, masked=True,
+                           pages=ptables), every=True)
                 out, ids, positions, stopped = self._verify_accept(
                     logits, ids, positions, draft, stopped, temps,
                     eos_ids, key, step0, key_salt)
@@ -1170,41 +945,11 @@ class TransformerDecoder:
             # per-K name, like the decode blocks: the compile auditor
             # attributes by __name__ and two K values share input ranks
             verify_block_impl.__name__ = f"verify_block{k_draft}_impl"
-            fn = self._jit_sharded(
-                verify_block_impl, donate,
+            fn = self._jit_twin(
+                verify_block_impl, name[0] == "paged_verify", donate,
                 in_specs=(psh, None, csh, row, row, mat, row, row, row,
                           None, None, None),
                 out_specs=(mat, row, row, row, csh))
-        elif isinstance(name, tuple) and name[0] == "paged_verify":
-            k_draft = int(name[1])
-
-            def paged_verify_block_impl(params, state, caches, ptables,
-                                        ids, positions, draft, stopped,
-                                        temps, eos_ids, key, step0,
-                                        key_salt):
-                # paged twin of verify_block_impl: window writes ride
-                # the paged chunk path's null-page redirect, and the
-                # HOST rewinds the page tables afterwards (truncate +
-                # refcount release) — the device program never re-maps
-                window = jnp.concatenate([ids[:, None], draft], axis=1)
-                wvalid = jnp.where(stopped, 0,
-                                   jnp.clip(self.t_max - positions, 0,
-                                            k_draft + 1))
-                logits, caches = self._walk_paged_verify(
-                    params, state, caches, ptables, window, positions,
-                    wvalid)
-                out, ids, positions, stopped = self._verify_accept(
-                    logits, ids, positions, draft, stopped, temps,
-                    eos_ids, key, step0, key_salt)
-                return out, ids, positions, stopped, caches
-            paged_verify_block_impl.__name__ = \
-                f"paged_verify_block{k_draft}_impl"
-            pool_sh = self._pool_shardings()
-            fn = self._jit_sharded(
-                paged_verify_block_impl, donate,
-                in_specs=(psh, None, pool_sh, mat, row, row, mat, row,
-                          row, row, None, None, None),
-                out_specs=(mat, row, row, row, pool_sh))
         elif name == "scrub_slot":
             def scrub_slot_impl(caches, slots):
                 # slab twin of scrub_pages_impl: zero the given slots'
@@ -1289,50 +1034,31 @@ class TransformerDecoder:
                                    out_specs=csh)
         else:                                 # pragma: no cover
             raise KeyError(name)
-        fn = self._with_cost_seam(name, fn)
+        fn = self._with_cost_seam(fn)
         self._jit[name] = fn
         return fn
 
     def _impl_audit_name(self, name) -> str:
-        """The wrapped impl's __name__ as the compile auditor sees it
-        (per-K, per-mesh) — devstats keys its cost table the same way,
-        so the two views line up row for row."""
-        base = {"prefill": "prefill_impl", "step": "decode_step_impl",
-                "prefill_slots": "prefill_slots_impl",
-                "paged_prefill": "paged_prefill_impl",
-                "kv_export": "kv_export_impl",
-                "kv_import": "kv_import_impl",
-                "scrub_pages": "scrub_pages_impl",
-                "scrub_slot": "scrub_slot_impl",
-                "corrupt_page": "corrupt_page_impl",
-                "corrupt_cache": "corrupt_cache_impl"}.get(name)
-        if base is None and isinstance(name, tuple) and name[0] == "block":
-            base = f"decode_block{int(name[1])}_impl"
-        if base is None and isinstance(name, tuple) and name[0] == "chunk":
-            base = f"prefill_chunk{int(name[1])}_impl"
-        if base is None and isinstance(name, tuple) and \
-                name[0] == "paged_block":
-            base = f"paged_decode_block{int(name[1])}_impl"
-        if base is None and isinstance(name, tuple) and name[0] == "verify":
-            base = f"verify_block{int(name[1])}_impl"
-        if base is None and isinstance(name, tuple) and \
-                name[0] == "paged_verify":
-            base = f"paged_verify_block{int(name[1])}_impl"
-        return (base or str(name)) + self._impl_suffix
+        """The impl's __name__ as the compile auditor sees it (per-K,
+        per-mesh), read off the function :meth:`_fn` built for the key —
+        devstats keys its cost table the same way, so the two views line
+        up row for row."""
+        return self._fn(name).__name__
 
-    def _with_cost_seam(self, name, jitted):
+    def _with_cost_seam(self, jitted):
         """Wrap a jitted impl so its FIRST dispatch captures the
         abstract arg signature (ShapeDtypeStructs — host-side, no device
         work) into ``_cost_seam``; devstats lowers from those specs on
         demand for the per-impl cost_analysis table. Steady-state cost:
         one dict-entry check per dispatch."""
         entry = [jitted, None, None]
-        self._cost_seam[self._impl_audit_name(name)] = entry
+        self._cost_seam[jitted.__name__] = entry
 
         def dispatch(*args):
             if entry[1] is None:
                 entry[1] = jax.tree_util.tree_map(_abstract_spec, args)
             return jitted(*args)
+        dispatch.__name__ = jitted.__name__
         return dispatch
 
     def prefill(self, caches, tokens, lengths, temps=None, seed: int = 0):

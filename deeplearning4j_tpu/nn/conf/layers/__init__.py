@@ -12,7 +12,7 @@ from .recurrent import GravesLSTM, LSTM, GravesBidirectionalLSTM
 from .attention import (SelfAttentionLayer, LayerNormalization,
                         RMSNormalization, TransformerFeedForward,
                         GatedFeedForward, TokenAndPositionEmbedding,
-                        TokenEmbedding)
+                        TokenEmbedding, Window)
 from .latent_attention import LatentAttentionLayer
 from .experts import RoutedExpertsLayer
 from .variational import VariationalAutoencoder
@@ -28,5 +28,5 @@ __all__ = [
     "SelfAttentionLayer", "LayerNormalization",
     "TransformerFeedForward", "TokenAndPositionEmbedding",
     "RMSNormalization", "GatedFeedForward", "TokenEmbedding",
-    "LatentAttentionLayer", "RoutedExpertsLayer",
+    "LatentAttentionLayer", "RoutedExpertsLayer", "Window",
 ]

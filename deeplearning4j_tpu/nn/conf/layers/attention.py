@@ -21,6 +21,41 @@ from .base import BaseRecurrentLayerConf
 from ...helpers import get_helper, note_attention_plan
 
 
+@dataclasses.dataclass(frozen=True)
+class Window:
+    """What one pass of the decode walk (models/generation.py) advances, the
+    same for every layer it meets: a window of tokens [B, C], or one token
+    a row (a decode step: ids [B], ``valid`` None), and where it sits in
+    each row's sequence. Made at trace time from a program's arguments. An
+    embedding reads it in ``embed(params, ids, window)``; a layer that keeps
+    sequence state in ``advance(params, x, cache, window) -> (y,
+    new_cache)``, next to its ``init_cache`` and ``init_page_pool``; a layer
+    whose class says ``counts_tokens`` gets ``alive`` as its mask and its
+    second return is kept."""
+    #: [B] int32, the absolute position of each row's first cell; None: a
+    #: fresh prompt from position 0, which rides the ``attention`` helper seam
+    start: Optional[jax.Array] = None
+    #: [B] int32, how many of a row's cells are real (a prompt's length, a
+    #: chunk's or a verify window's count); None: a decode step
+    valid: Optional[jax.Array] = None
+    #: writes are masked cell by cell to ``valid`` (speculative verify);
+    #: unmasked, a window slides left to fit the cache (chunked prefill)
+    masked: bool = False
+    #: [B, NP] int32 page tables; None on the slab
+    pages: Optional[jax.Array] = None
+    #: [B] bool, the lanes whose tokens count; None where nothing counts
+    alive: Optional[jax.Array] = None
+    #: [B, C] float32, 1.0 on a fresh prompt's real cells (:meth:`fresh`)
+    mask: Optional[jax.Array] = None
+
+    @classmethod
+    def fresh(cls, width: int, lengths) -> "Window":
+        """Prompts of ``lengths`` [B] padded to ``width``, from position 0."""
+        mask = (jnp.arange(width, dtype=jnp.int32)[None, :] <
+                lengths[:, None]).astype(jnp.float32)
+        return cls(valid=lengths, mask=mask)
+
+
 @register_config
 @dataclasses.dataclass
 class SelfAttentionLayer(BaseRecurrentLayerConf):
@@ -29,12 +64,6 @@ class SelfAttentionLayer(BaseRecurrentLayerConf):
     head_size: int = 0            # inferred as n_out // num_heads
     causal: bool = False
     project_out: bool = True
-    #: compute q/k/v as ONE [n_in, 3·inner] matmul (params stay separate
-    #: Wq/Wk/Wv tensors; the concat rides inside the jitted step).
-    #: MEASURED SLOWER on the flagship LM (135.5k vs 139.9k tok/s — the
-    #: per-step concat of 3.5 MB of weights costs more than the wider
-    #: matmul saves), so it stays opt-in (BASELINE.md r5)
-    fused_qkv: bool = False
 
     def _head_size(self) -> int:
         return self.head_size or max(self.n_out // self.num_heads, 1)
@@ -62,18 +91,9 @@ class SelfAttentionLayer(BaseRecurrentLayerConf):
         """x [N, T, n_in] → (q, k, v) each [N, T, H, Dh]."""
         n, t, _ = x.shape
         hcount, hs = self.num_heads, self._head_size()
-        inner = hcount * hs
-        if getattr(self, "fused_qkv", False):
-            w = jnp.concatenate([params["Wq"], params["Wk"],
-                                 params["Wv"]], axis=1)
-            qkv = x @ w
-            q = qkv[..., :inner].reshape(n, t, hcount, hs)
-            k = qkv[..., inner:2 * inner].reshape(n, t, hcount, hs)
-            v = qkv[..., 2 * inner:].reshape(n, t, hcount, hs)
-        else:
-            q = (x @ params["Wq"]).reshape(n, t, hcount, hs)
-            k = (x @ params["Wk"]).reshape(n, t, hcount, hs)
-            v = (x @ params["Wv"]).reshape(n, t, hcount, hs)
+        q = (x @ params["Wq"]).reshape(n, t, hcount, hs)
+        k = (x @ params["Wk"]).reshape(n, t, hcount, hs)
+        v = (x @ params["Wv"]).reshape(n, t, hcount, hs)
         return q, k, v
 
     # graftlint: traced
@@ -117,6 +137,34 @@ class SelfAttentionLayer(BaseRecurrentLayerConf):
         return self._project_out(params, out), state
 
     # ---- KV-cache autoregressive decoding (models/generation.py) ----
+    # graftlint: traced
+    def advance(self, params, x, cache, window: Window):
+        """x [B, C, n_in] of ``window`` → (out [B, C, n_out], new cache):
+        the one door the decode walk comes through. Which of the bodies
+        below runs is read off the window: no cache at all is the no-cache
+        reference's plain ``forward``; page tables are the paged pool's two;
+        on the slab a fresh prompt, one token a row, or a window from
+        ``start``, its writes masked to ``valid`` or sliding."""
+        if cache is None:
+            return self.forward(params, None, x, mask=window.mask)[0], None
+        if window.pages is not None:
+            if window.valid is None:
+                return self.paged_decode_forward(params, x, cache,
+                                                 window.pages, window.start)
+            return self.paged_chunk_forward(params, x, cache, window.pages,
+                                            window.start, window.valid)
+        if window.start is None:
+            return self.prefill_forward(params, x, cache, mask=window.mask)
+        if window.valid is None:
+            return self.decode_forward(params, x, cache, window.start)
+        return self.chunk_forward(params, x, cache, window.start,
+                                  window.valid if window.masked else None)
+
+    def latent_bytes_per_token(self, dtype) -> int:
+        """Bytes a cached token takes where the cache holds one compressed
+        row a token and nothing per head; 0 for this layer's k/v slabs."""
+        return 0
+
     #: lanes of one TPU vector row: a slab row narrower than this is padded
     #: to it on the device, read and written at half speed and twice the
     #: bytes (PERF.md, PR 27)
@@ -392,10 +440,8 @@ class SelfAttentionLayer(BaseRecurrentLayerConf):
         shape, ONE compile serves every step) and attends over the
         gathered logical view with the SAME length-masked math as
         :meth:`decode_forward`, so paged and slab logits are bitwise
-        identical at every unmasked cell. Routed through a
-        kind="paged_decode_attention" helper seam so the fused paged
-        kernel (ROADMAP item 5) can slot in. Returns (out [B, 1,
-        n_out], new_pool)."""
+        identical at every unmasked cell. Returns (out [B, 1, n_out],
+        new_pool)."""
         q, k, v = self._project_qkv(params, x)      # [B, 1, H, Dh]
         ps = pool["k"].shape[2]
         t_cap = ptable.shape[1] * ps
@@ -416,19 +462,16 @@ class SelfAttentionLayer(BaseRecurrentLayerConf):
                     v[:, 0].astype(pool["v"].dtype))}
         ck = self._paged_gather(new_pool["k"], ptable)
         cv = self._paged_gather(new_pool["v"], ptable)
-        helper = get_helper("paged_decode_attention")
-        out = helper(self, q, ck, cv, pos) if helper is not None else None
-        if out is None:
-            hs = self._head_size()
-            scale = 1.0 / math.sqrt(hs)     # math.sqrt: GL004 (x64)
-            logits = jnp.einsum("bhd,bhtd->bht", q[:, 0], ck,
-                                preferred_element_type=jnp.float32) * scale
-            kpos = jnp.arange(ck.shape[2], dtype=jnp.int32)
-            keep = kpos[None, :] <= pos[:, None]
-            logits = jnp.where(keep[:, None, :], logits, -1e30)
-            probs = jax.nn.softmax(logits, axis=-1)          # f32
-            out = jnp.einsum("bht,bhtd->bhd", probs.astype(cv.dtype), cv)
-            out = out[:, None]                               # [B,1,H,Dh]
+        hs = self._head_size()
+        scale = 1.0 / math.sqrt(hs)     # math.sqrt: GL004 (x64)
+        logits = jnp.einsum("bhd,bhtd->bht", q[:, 0], ck,
+                            preferred_element_type=jnp.float32) * scale
+        kpos = jnp.arange(ck.shape[2], dtype=jnp.int32)
+        keep = kpos[None, :] <= pos[:, None]
+        logits = jnp.where(keep[:, None, :], logits, -1e30)
+        probs = jax.nn.softmax(logits, axis=-1)          # f32
+        out = jnp.einsum("bht,bhtd->bhd", probs.astype(cv.dtype), cv)
+        out = out[:, None]                               # [B,1,H,Dh]
         return self._project_out(params, out.astype(x.dtype)), new_pool
 
     # graftlint: traced
@@ -483,20 +526,6 @@ class SelfAttentionLayer(BaseRecurrentLayerConf):
         probs = jax.nn.softmax(logits, axis=-1)            # f32
         out = jnp.einsum("bhqt,bhtd->bqhd", probs.astype(cv.dtype), cv)
         return self._project_out(params, out.astype(x.dtype)), new_pool
-
-    # graftlint: traced
-    def paged_prefill_forward(self, params, x, pool: Dict, ptable,
-                              pos0=None, valid=None):
-        """Prompt prefill into pages — the paged analogue of
-        :meth:`prefill_forward`. A prefill IS one chunk window starting
-        at each row's absolute start (0 for a fresh prompt, the shared-
-        prefix length after a prefix-cache hit), so this delegates to
-        :meth:`paged_chunk_forward`; kept as its own seam so callers
-        and a future fused kernel can distinguish the phases."""
-        if pos0 is None:
-            pos0 = jnp.zeros(x.shape[0], jnp.int32)
-        return self.paged_chunk_forward(params, x, pool, ptable, pos0,
-                                        valid)
 
 
 @register_config
@@ -679,6 +708,17 @@ class TokenAndPositionEmbedding(BaseRecurrentLayerConf):
         return self.maybe_dropout(out, train=train, rng=rng), state
 
     # graftlint: traced
+    def embed(self, params, ids, window: Window):
+        """The decode walk's door (see :class:`Window`): ids [B, C] of a
+        fresh prompt are ``forward``'s, one token a row ``embed_at``'s, a
+        window from ``start`` ``embed_chunk``'s."""
+        if window.start is None:
+            return self.forward(params, None, ids)[0]
+        if window.valid is None:
+            return self.embed_at(params, ids, window.start)
+        return self.embed_chunk(params, ids, window.start)
+
+    # graftlint: traced
     def embed_at(self, params, ids, positions):
         """Single-position decode embedding: ids [B] + per-row positions
         [B] → [B, 1, n_out]. Positions clamp to max_length - 1 (a fused
@@ -706,13 +746,15 @@ class TokenAndPositionEmbedding(BaseRecurrentLayerConf):
 
 @register_config
 @dataclasses.dataclass
-class TokenEmbedding(TokenAndPositionEmbedding):
+class TokenEmbedding(BaseRecurrentLayerConf):
     """Token ids [N, T] → embeddings [N, T, n_out] and nothing else: a
     lookup with no position table, for models whose positions enter inside
     attention (rotary). ``max_length`` is only the context the model
-    declares — what bounds a decoder's ``t_max``. The decode walks reach it
-    through :class:`TokenAndPositionEmbedding`'s seams (``embed_at``,
-    ``embed_chunk``), which here ignore the positions."""
+    declares — what bounds a decoder's ``t_max``."""
+    max_length: int = 512
+
+    def get_output_type(self, it: InputType) -> InputType:
+        return InputType.recurrent(self.n_out, it.timesteps)
 
     def init_params(self, key, dtype=jnp.float32) -> Dict:
         return {"W": jax.random.normal(key, (self.n_in, self.n_out),
@@ -726,9 +768,10 @@ class TokenEmbedding(TokenAndPositionEmbedding):
                                   rng=rng), state
 
     # graftlint: traced
-    def embed_at(self, params, ids, positions):
-        return params["W"][jnp.asarray(ids, jnp.int32).reshape(-1)][:, None]
-
-    # graftlint: traced
-    def embed_chunk(self, params, ids, pos0):
+    def embed(self, params, ids, window: Window):
+        """The decode walk's door: the lookup, wherever the window sits."""
+        if window.start is None:
+            return self.forward(params, None, ids)[0]
+        if window.valid is None:
+            return params["W"][jnp.asarray(ids, jnp.int32).reshape(-1)][:, None]
         return params["W"][jnp.asarray(ids, jnp.int32)]

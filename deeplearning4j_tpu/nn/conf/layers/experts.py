@@ -57,6 +57,9 @@ class RoutedExpertsLayer(BaseRecurrentLayerConf):
     routed_scaling: float = 1.0
     first_expert: int = 0
     experts_held: int = 0            # 0: all of them
+    #: what the decode walk reads off the class: where a pass counts, it
+    #: hands ``forward`` the alive lanes as ``mask`` and keeps the counts
+    counts_tokens = True
 
     def set_n_in(self, it: InputType) -> None:
         if not self.n_in:

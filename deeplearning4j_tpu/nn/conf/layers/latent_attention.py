@@ -46,6 +46,12 @@ from ..serde import register_config
 from .attention import TOKEN_BLOCK, SelfAttentionLayer, rms_norm
 
 
+_NO_PAGES = (
+    "latent attention: the compressed-KV cache has no paged pool (a page of "
+    "[c_kv ; k_rope] rows, its prefix cache and its gather are not built); "
+    "serve it from the slab (paged=False)")
+
+
 # graftlint: traced
 def rope(x, pos, theta: float):
     """Rotate adjacent pairs of the last axis of x [B, T, ..., d] by the
@@ -86,6 +92,9 @@ class LatentAttentionLayer(SelfAttentionLayer):
 
     def heads_per_row(self, tp: int = 1) -> int:
         return 1
+
+    def latent_bytes_per_token(self, dtype) -> int:
+        return self.row_width * jnp.dtype(dtype).itemsize
 
     def init_params(self, key, dtype=jnp.float32) -> Dict:
         h, d = self.num_heads, self.n_in
@@ -261,13 +270,13 @@ class LatentAttentionLayer(SelfAttentionLayer):
                               x.dtype), new_cache
 
     # ---- no paged pool yet ----
-    def _no_pages(self, *args, **kwargs):
-        raise NotImplementedError(
-            "latent attention: the compressed-KV cache has no paged pool "
-            "(a page of [c_kv ; k_rope] rows, its prefix cache and its "
-            "gather are not built); serve it from the slab (paged=False)")
+    def init_page_pool(self, *args, **kwargs):
+        raise NotImplementedError(_NO_PAGES)
 
-    init_page_pool = _no_pages
-    paged_decode_forward = _no_pages
-    paged_chunk_forward = _no_pages
-    paged_prefill_forward = _no_pages
+    # graftlint: traced
+    def advance(self, params, x, cache, window):
+        """``SelfAttentionLayer.advance`` over this layer's three bodies;
+        a window that carries page tables has no pool to go to."""
+        if window.pages is not None:
+            raise NotImplementedError(_NO_PAGES)
+        return super().advance(params, x, cache, window)

@@ -21,7 +21,7 @@ from deeplearning4j_tpu.models import (SlotGenerationEngine,
                                        transformer_lm_conf)
 from deeplearning4j_tpu.models.generation import MOE_COUNTERS
 from deeplearning4j_tpu.nn.conf.layers import (LatentAttentionLayer,
-                                               RoutedExpertsLayer)
+                                               RoutedExpertsLayer, Window)
 from deeplearning4j_tpu.nn.graph import ComputationGraph
 
 VOCAB, T_MAX = 97, 64
@@ -196,11 +196,10 @@ def test_paged_paths_raise_and_name_the_mechanism(net, dec):
     layer = net.conf.vertices["attn0"].layer
     assert isinstance(layer, LatentAttentionLayer)
     for call in (lambda: layer.init_page_pool(8, 8),
-                 lambda: layer.paged_decode_forward(None, None, None, None,
-                                                    None),
-                 lambda: layer.paged_chunk_forward(None, None, None, None,
-                                                   None),
-                 lambda: layer.paged_prefill_forward(None, None, None, None),
+                 lambda: layer.advance(None, None, None,
+                                       Window(pages=np.zeros((1, 1)))),
+                 lambda: layer.advance(None, None, None, Window(
+                     valid=np.ones(1), pages=np.zeros((1, 1)))),
                  lambda: SlotGenerationEngine(net, decoder=dec, num_slots=2,
                                               paged=True, page_size=8)):
         with pytest.raises(NotImplementedError, match="latent attention"):

@@ -65,6 +65,10 @@ class TestTransformerLM:
         np.testing.assert_allclose(np.asarray(net.output(x)[0]),
                                    np.asarray(loaded.output(x)[0]),
                                    rtol=1e-6)
+        # a configuration saved before PR 31 carries the removed option
+        from deeplearning4j_tpu.nn.conf import from_jsonable, to_jsonable
+        attn = net.conf.vertices["attn0"].layer
+        assert from_jsonable(dict(to_jsonable(attn), fused_qkv=False)) == attn
 
     def test_max_length_guard(self):
         net = _tiny_lm(max_length=8)
