@@ -266,7 +266,11 @@ class SelfAttentionLayer(BaseRecurrentLayerConf):
         elsewhere — the zeros contribute exact 0.0), the weighted sum is
         ``P[g, T] · V_row[T, g·Dh]`` of which head j keeps its own Dh
         lanes. f32 logits and softmax, every position under the mask;
-        ``g = 1`` is plain ``bqhd,bhtd->bhqt``. Returns [B, C, H, Dh]."""
+        ``g = 1`` is plain ``bqhd,bhtd->bhqt``. The ``slab_attention``
+        helper (kernels/slab_attention.py on the TPU: K and V streamed in
+        position tiles, an online softmax across them) takes the call
+        where it serves the shapes; the einsum body below is the
+        always-available path. Returns [B, C, H, Dh]."""
         b, c, h, hs = q.shape
         hg = ck.shape[1]
         g = h // hg
@@ -276,13 +280,21 @@ class SelfAttentionLayer(BaseRecurrentLayerConf):
             own = jnp.eye(g, dtype=q.dtype)[None, None, None, :, :, None]
             qg = qg * own                    # [B, C, H/g, g, g, Dh]
         qblk = qg.reshape(b, c, hg, g, g * hs)
-        logits = jnp.einsum("bqgjl,bgtl->bgjqt", qblk, ck,
-                            preferred_element_type=jnp.float32) * scale
-        kpos = jnp.arange(ck.shape[2], dtype=jnp.int32)
-        keep = kpos[None, None, :] <= qpos[:, :, None]       # [B, C, T]
-        logits = jnp.where(keep[:, None, None, :, :], logits, -1e30)
-        probs = jax.nn.softmax(logits, axis=-1)               # f32
-        rows = jnp.einsum("bgjqt,bgtl->bqgjl", probs.astype(cv.dtype), cv)
+        helper = get_helper("slab_attention")
+        rows = helper(self, qblk, ck, cv, qpos, scale) \
+            if helper is not None else None
+        if rows is None:
+            # no helper, or it declined (a row that is not whole lanes, a T
+            # no tile divides, a long window): the built-in body
+            note_attention_plan("slab_einsum")
+            logits = jnp.einsum("bqgjl,bgtl->bgjqt", qblk, ck,
+                                preferred_element_type=jnp.float32) * scale
+            kpos = jnp.arange(ck.shape[2], dtype=jnp.int32)
+            keep = kpos[None, None, :] <= qpos[:, :, None]   # [B, C, T]
+            logits = jnp.where(keep[:, None, None, :, :], logits, -1e30)
+            probs = jax.nn.softmax(logits, axis=-1)           # f32
+            rows = jnp.einsum("bgjqt,bgtl->bqgjl", probs.astype(cv.dtype),
+                              cv)
         if g > 1:
             # head j's own lanes of its row: the diagonal blocks
             rows = jnp.diagonal(rows.reshape(b, c, hg, g, g, hs),
@@ -318,8 +330,7 @@ class SelfAttentionLayer(BaseRecurrentLayerConf):
         fixed-shape, ONE compile serves every step) and attends q over
         cache[:, :, :pos+1] via a length mask (:meth:`_slab_attend`: f32
         logits and softmax, both contractions over whole rows — no
-        relayout of the cache, and no helper seam: the built-in path IS
-        the decode kernel). Returns (out [B, 1, n_out], new_cache).
+        relayout of the cache). Returns (out [B, 1, n_out], new_cache).
 
         Positions are clamped to the cache depth: a fused decode block
         (models/generation.py decode_block) lets finished lanes overshoot

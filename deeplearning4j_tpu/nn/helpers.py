@@ -58,7 +58,9 @@ def note_attention_plan(kind: str, **detail) -> None:
     path the call took — ``packed`` (heads side by side in 128-lane tiles,
     with its ``g`` and tile sizes), ``folded`` ([BH, T, Dh] through the same
     flash kernels), ``short`` (the whole-block short-T kernels) or
-    ``materialized`` (the built-in softmax). The count is process-wide and
+    ``materialized`` (the built-in softmax); a read of the slab cache
+    ``slab_stream`` (the streaming decode kernel, with its ``g`` and block)
+    or ``slab_einsum`` (the built-in body). The count is process-wide and
     only grows; readers snapshot and subtract
     (:func:`attention_plan_counts`, ``analysis.AttentionPlanAudit``,
     ``devstats``' ``attention_plans``)."""
@@ -93,6 +95,10 @@ _DEFAULT_PROVIDERS: Dict[str, str] = {
     # drop-free routed experts: the grouped FFN kernel reads only the
     # experts hit (TPU only; the layer's dense jnp path elsewhere)
     "routed_experts": "deeplearning4j_tpu.kernels.expert_ffn",
+    # decode attention over the lane-dense slab: K and V streamed in
+    # position tiles (TPU only; the layer's einsum body elsewhere and for
+    # the shapes the kernel declines)
+    "slab_attention": "deeplearning4j_tpu.kernels.slab_attention",
     # "lstm" is deliberately NOT a default provider: honest r2 measurements
     # (BASELINE.md) show XLA's scan lowering beats the Pallas kernel at
     # char-RNN shapes in both f32 (11.5 vs 12.5 ms/step) and bf16 (8.0 vs

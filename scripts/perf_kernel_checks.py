@@ -22,6 +22,14 @@ Checks:
                       compiled only — no relayout copy of a layer's K or
                       V in the optimized HLO, memory_analysis peak under
                       8 GB; the 32-slot peak is printed, not gated)
+  slab-stream         (kernels/slab_attention.py against the einsum body of
+                      ``_slab_attend`` at gpt2-large.chat-open's shape,
+                      [16, 10, 1024, 128] bf16, 1 / 4 / 8 queries a slot:
+                      largest absolute difference of the outputs; printed,
+                      not gated: the isolated time of each over 100 calls,
+                      the plan counts of one traced
+                      decode_block4_impl, and the block timed at empty and
+                      at full slabs with the kernel and without it)
 
 Error metric: max|a−b| / (max|b| + 1e-30) over fwd outputs and each
 gradient; thresholds sized for bf16 matmul noise (attention) and f32
@@ -159,10 +167,10 @@ def check_layernorm(rows):
 SLAB_PEAK_LIMIT = 8e9      # bytes: decode_block4_impl, gpt2-large, 16 slots
 
 
-def _compile_decode_block(slots, k=4):
-    """decode_block{k}_impl at the gpt2-large cell's shapes, compiled for
-    this backend from shapes alone (no weights are made): (compiled,
-    one layer's cache shape)."""
+def _decode_block_program(slots, k=4):
+    """decode_block{k}_impl at the gpt2-large cell's shapes, from shapes
+    alone (no weights are made): (the jitted program, its arguments'
+    shapes, one layer's cache shape)."""
     from jax.sharding import SingleDeviceSharding
     from benchmark.harness import program
     from deeplearning4j_tpu.models import TransformerDecoder
@@ -188,12 +196,18 @@ def _compile_decode_block(slots, k=4):
     key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
     vec = lambda dt: jax.ShapeDtypeStruct((slots,), dt, sharding=dev)
     scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=dev)
-    compiled = jitted.lower(
-        params, on(state), caches, vec(jnp.int32), vec(jnp.int32),
-        vec(jnp.bool_), vec(jnp.float32), vec(jnp.int32),
-        jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=dev),
-        scalar, scalar).compile()
-    return compiled, caches[dec.attn_names[0]]["k"].shape
+    args = (params, on(state), caches, vec(jnp.int32), vec(jnp.int32),
+            vec(jnp.bool_), vec(jnp.float32), vec(jnp.int32),
+            jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=dev),
+            scalar, scalar)
+    return jitted, args, caches[dec.attn_names[0]]["k"].shape
+
+
+def _compile_decode_block(slots, k=4):
+    """The same, compiled for this backend: (compiled, one layer's cache
+    shape)."""
+    jitted, args, shape = _decode_block_program(slots, k)
+    return jitted.lower(*args).compile(), shape
 
 
 def slab_relayout_copies(hlo_text, cache_shape):
@@ -243,6 +257,134 @@ def check_decode_block_layout(rows):
               f"{type(e).__name__}: {str(e)[:160]}", flush=True)
 
 
+SLAB_SHAPE = (16, 10, 1024, 128)     # gpt2-large.chat-open: one layer's K
+
+
+def _timed(fn, *args, calls=10):
+    """Seconds a call of ``fn(*args)``, after one warm call."""
+    import time
+    jax.block_until_ready(fn(*args))
+    t0 = time.perf_counter()
+    for _ in range(calls):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) / calls
+
+
+def slab_attend_times(window=1, loops=100, layers=5):
+    """One attention layer's ``_slab_attend`` at the cell's shape, ``window``
+    queries a slot: (largest absolute difference between the kernel's output
+    and the einsum body's, {"einsum" / "kernel": microseconds a call}). The
+    ``loops`` calls run inside one program, each on the queries the call
+    before left, over ``layers`` slabs in turn, each carried and written a
+    row a call as a decode step does — one loop-invariant slab the compiler
+    reads once and keeps in fast memory."""
+    from deeplearning4j_tpu.nn import helpers
+    from deeplearning4j_tpu.nn.conf.layers import SelfAttentionLayer
+    b, hg, t, lanes = SLAB_SHAPE
+    dh = 64
+    h = hg * lanes // dh
+    layer = SelfAttentionLayer(n_in=h * dh, n_out=h * dh, num_heads=h,
+                               causal=True)
+    rng = np.random.default_rng(0)
+    mk = lambda shape: jnp.asarray(rng.normal(size=shape) * 0.5,
+                                   jnp.bfloat16)
+    q = mk((b, window, h, dh))
+    slabs = [(mk(SLAB_SHAPE), mk(SLAB_SHAPE)) for _ in range(layers)]
+    qpos = jnp.asarray(rng.integers(0, t - window, (b, 1)), jnp.int32) \
+        + jnp.arange(window, dtype=jnp.int32)[None, :]
+    qpos = qpos.at[0].set(jnp.arange(window)).at[1].set(
+        t - window + jnp.arange(window))
+
+    def chained_fn():     # a new function a path: the path is picked in trace
+        def chained(q, slabs, qpos):
+            def body(i, carry):
+                q, slabs = carry
+                at = (0, 0, i % t, 0)
+                out = []
+                for ck, cv in slabs:
+                    row = q[:1, :1, :lanes // dh].reshape(1, 1, 1, lanes)
+                    ck = jax.lax.dynamic_update_slice(ck, row, at)
+                    cv = jax.lax.dynamic_update_slice(cv, row, at)
+                    q = q + (layer._slab_attend(q, ck, cv, qpos)
+                             * 1e-3).astype(q.dtype)
+                    out.append((ck, cv))
+                return q, out
+            return jax.lax.fori_loop(0, loops // layers, body,
+                                     (q, slabs))[0]
+        return jax.jit(chained)
+
+    outs, times = {}, {}
+    try:
+        for name, switch in (("einsum", helpers.disable_helper),
+                             ("kernel", helpers.enable_helper)):
+            switch("slab_attention")
+            outs[name] = np.asarray(
+                jax.jit(lambda *a: layer._slab_attend(*a))(
+                    q, *slabs[0], qpos), np.float32)
+            times[name] = _timed(chained_fn(), q, slabs, qpos, calls=3) \
+                / (loops // layers * layers) * 1e6
+    finally:
+        helpers.enable_helper("slab_attention")
+    return float(np.max(np.abs(outs["kernel"] - outs["einsum"]))), times
+
+
+def decode_block_times(slots=16, at=(0, 1000), calls=10):
+    """decode_block4_impl at gpt2-large's shapes with zero weights (its
+    time does not depend on them), every lane at position ``at[i]``:
+    (plans of the traced block, [milliseconds a block at each])."""
+    import time
+    from deeplearning4j_tpu.analysis import AttentionPlanAudit
+    with AttentionPlanAudit() as audit:
+        jitted, args, _ = _decode_block_program(slots)
+        compiled = jitted.lower(*args).compile()
+    params, state, caches = jax.tree_util.tree_map(
+        lambda a: jnp.zeros(a.shape, a.dtype), args[:3])
+
+    def spin(caches, pos):
+        rest = [jnp.zeros(slots, jnp.int32), jnp.full(slots, pos, jnp.int32),
+                jnp.zeros(slots, bool), jnp.zeros(slots, jnp.float32),
+                jnp.full(slots, -1, jnp.int32), jax.random.PRNGKey(0),
+                jnp.int32(0), jnp.int32(0)]
+        t0 = time.perf_counter()
+        for _ in range(calls):
+            caches = compiled(params, state, caches, *rest)[-1]
+        jax.block_until_ready(caches)
+        return caches, (time.perf_counter() - t0) / calls * 1e3
+    times = []
+    for pos in at:
+        # a block's first calls after another position's (or the compile)
+        # run a per cent or two slower: warm each position, then time it
+        caches, _ = spin(caches, pos)
+        caches, ms = spin(caches, pos)
+        times.append(ms)
+    return audit.plans(), times
+
+
+def check_slab_stream(rows):
+    from deeplearning4j_tpu.nn import helpers
+    errs = {}
+    # a decode step, and the verify windows no cell runs
+    for window in (1, 4, 8):
+        errs[f"c{window}"], times = slab_attend_times(window)
+        print(f"  slab-stream: {window} queries a slot over "
+              f"{list(SLAB_SHAPE)} bf16: max|kernel-einsum|="
+              f"{errs[f'c{window}']:.2e}; a call: " + ", ".join(
+                  f"{k} {v:.1f} us" for k, v in times.items()), flush=True)
+    # bf16 outputs of O(1): a rounding step or two of reassociation
+    rows.append(("slab-stream", errs, 3e-2))
+    for label, switch in (("kernel", helpers.enable_helper),
+                          ("einsum", helpers.disable_helper)):
+        switch("slab_attention")
+        try:
+            plans, (empty, full) = decode_block_times()
+        finally:
+            helpers.enable_helper("slab_attention")
+        print(f"  decode_block4_impl, {label}: plans {plans}; a block "
+              f"{empty:.3f} ms at empty slabs, {full:.3f} ms at full "
+              f"({abs(full - empty) / empty:.2%} apart)", flush=True)
+
+
 def main():
     from deeplearning4j_tpu.kernels.pallas_attention import \
         pallas_flash_attention
@@ -278,6 +420,7 @@ def main():
     check_fused_ce(rows)
     check_layernorm(rows)
     check_decode_block_layout(rows)
+    check_slab_stream(rows)
 
     ok_all = True
     print(f"{'check':22s} {'threshold':>9s}  errors")

@@ -4,8 +4,10 @@ kernels for a described TPU v5e — no chip attached, nothing run (what
 tests/benchmark/test_chip_compile.py does for the cells' whole programs).
 The three Mosaic calls lower, and the compiled program moves no q-sized
 array through a transpose or a layout copy on its way into or out of a
-``flash_*`` call. One file, one module-scoped fixture: only the worker that
-is given this file loads the TPU's compiler."""
+``flash_*`` call. Beside it one layer's ``decode_forward`` over the slab at
+the serving cell's shape, the streaming decode kernel forced. One file,
+module-scoped fixtures: only the worker that is given this file loads the
+TPU's compiler."""
 
 import os
 import re
@@ -158,6 +160,89 @@ def test_no_relayout_of_a_q_sized_array_around_the_kernels(compiled_layer):
     assert not found, "\n".join(l[:200] for l in found)
 
 
+# ---- decode over the slab: one layer's decode_forward at gpt2-large.chat-open's
+# shape, the streaming kernel forced (kernels/slab_attention.py)
+
+SLOTS, GROUPS, T_MAX, LANES = 16, 10, 1024, 128
+
+
+@pytest.fixture(scope="module")
+def compiled_decode_layer(one_chip):
+    """(compiled text, plans taken) of ``SelfAttentionLayer.decode_forward``
+    over a donated ``[16, 10, 1024, 128]`` bfloat16 slab."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from deeplearning4j_tpu.analysis import AttentionPlanAudit
+    from deeplearning4j_tpu.kernels.slab_attention import \
+        register_slab_attention
+    from deeplearning4j_tpu.nn import helpers
+    from deeplearning4j_tpu.nn.conf.layers import SelfAttentionLayer
+    snap = helpers.snapshot_helper("slab_attention")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    register_slab_attention(platforms=("tpu", "cpu"), interpret=False)
+    try:
+        with jax.enable_x64(False):
+            d = GROUPS * LANES
+            layer = SelfAttentionLayer(n_in=d, n_out=d, num_heads=d // 64,
+                                       causal=True)
+            on = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                                sharding=one_chip)
+            params = jax.tree_util.tree_map(on, jax.eval_shape(
+                lambda: layer.init_params(jax.random.PRNGKey(0),
+                                          jnp.bfloat16)))
+            cache = jax.tree_util.tree_map(on, jax.eval_shape(
+                lambda: layer.init_cache(SLOTS, T_MAX, jnp.bfloat16)))
+            assert cache["k"].shape == (SLOTS, GROUPS, T_MAX, LANES)
+            x = jax.ShapeDtypeStruct((SLOTS, 1, d), jnp.bfloat16,
+                                     sharding=one_chip)
+            pos = jax.ShapeDtypeStruct((SLOTS,), jnp.int32,
+                                       sharding=one_chip)
+
+            def step(params, x, cache, pos):
+                with jax.named_scope("attn0"):
+                    return layer.decode_forward(params, x, cache, pos)
+            with AttentionPlanAudit() as audit:
+                lowered = jax.jit(step, donate_argnums=(2,)).lower(
+                    params, x, cache, pos)
+            yield lowered.compile().as_text(), audit.plans()
+    finally:
+        helpers.restore_helper("slab_attention", snap)
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+def test_the_decode_layer_streams_the_slab(compiled_decode_layer):
+    compiled, plans = compiled_decode_layer
+    assert plans == {"slab_stream,g=2,hb=10,tb=1024": 1}
+    call = [l for l in compiled.splitlines()
+            if re.match(r"\s*%slab_decode_attn\.?\d* = ", l)]
+    assert len(call) == 1 and "tpu_custom_call" in call[0]
+    # K and V as stored are the call's operands
+    assert call[0].count(f"bf16[{SLOTS},{GROUPS},{T_MAX},{LANES}]") >= 2
+
+
+def test_no_whole_layer_read_outside_the_kernel(compiled_decode_layer):
+    """No ``copy`` / ``copy-start`` / ``slice-start`` that moves a layer's K
+    or V (or a quarter of one, as the einsum body's prefetches did): the
+    kernel's own pipeline is the one read of the slab."""
+    import sys
+    scripts = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "scripts")
+    if scripts not in sys.path:
+        sys.path.insert(0, scripts)
+    from perf_kernel_checks import slab_relayout_copies
+    compiled, _ = compiled_decode_layer
+    shape = (SLOTS, GROUPS, T_MAX, LANES)
+    assert slab_relayout_copies(compiled, shape) == []
+    quarter = int(np.prod(shape)) // 4
+    reads = [line for name, (op, size, _, _, line)
+             in _instructions(compiled).items()
+             if op in ("copy", "copy-start", "slice-start")
+             and size >= quarter]
+    assert not reads, "\n".join(l[:200] for l in reads)
+
+
 # ---- the cells' own programs, traced only (no topology, nothing lowered):
 # how many of a program's attention calls engage the packed tile
 
@@ -242,3 +327,56 @@ def test_no_attention_call_of_chat_2k_packs(traced_with_kernels):
     assert audit.calls("packed") == 0
     assert audit.plans() == {"folded,g=1,kb=1024,qb=1024":
                              config["num_hidden_layers"]}
+
+
+# ---- the serving cells' decode blocks, traced only: which of them stream
+# the slab through kernels/slab_attention.py
+
+@pytest.fixture()
+def traced_with_slab_kernel():
+    """The ``slab_attention`` helper as the chip registers it, for a trace
+    on the CPU (nothing is lowered)."""
+    from deeplearning4j_tpu.kernels.slab_attention import \
+        register_slab_attention
+    from deeplearning4j_tpu.nn import helpers
+    snap = helpers.snapshot_helper("slab_attention")
+    register_slab_attention(platforms=("tpu", "cpu"), interpret=False)
+    with jax.enable_x64(False):
+        yield
+    helpers.restore_helper("slab_attention", snap)
+
+
+def _decode_block_plans(config_name):
+    """Plans of one traced ``decode_block4_impl`` at a cell's shapes."""
+    from deeplearning4j_tpu.analysis import AttentionPlanAudit
+    from deeplearning4j_tpu.models import TransformerDecoder
+    family, config, _ = _cell(config_name)
+    net, _, (params, state, _) = family.make_net(config)
+    net.params = params = _shapes(params, jnp.bfloat16)
+    eng = config["run"]["engine"]
+    slots = eng["num_slots"]
+    dec = TransformerDecoder(net, t_max=eng["t_max"])
+    caches = jax.eval_shape(lambda: dec.init_cache(slots))
+    dec._fn(("block", 4))
+    vec = lambda dt: jax.ShapeDtypeStruct((slots,), dt)
+    scalar = jax.ShapeDtypeStruct((), jnp.int32)
+    with AttentionPlanAudit() as audit:
+        dec._cost_seam["decode_block4_impl"][0].trace(
+            params, _shapes(state), caches, vec(jnp.int32), vec(jnp.int32),
+            vec(jnp.bool_), vec(jnp.float32), vec(jnp.int32),
+            jax.eval_shape(lambda: jax.random.PRNGKey(0)), scalar, scalar)
+    return audit.plans(), config
+
+
+def test_every_slab_call_of_chat_opens_decode_block_streams(
+        traced_with_slab_kernel):
+    """36 of 36: the block scans its four steps over one traced body."""
+    plans, config = _decode_block_plans("gpt2-large")
+    assert plans == {"slab_stream,g=2,hb=10,tb=1024": config["n_layer"]}
+
+
+def test_no_slab_call_of_chat_2k_streams(traced_with_slab_kernel):
+    """The latent layer's absorbed decode never comes through
+    ``_slab_attend``."""
+    plans, _ = _decode_block_plans("joyai-llm-flash")
+    assert not any(k.startswith("slab_") for k in plans), plans
