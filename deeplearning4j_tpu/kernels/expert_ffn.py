@@ -7,18 +7,21 @@ each expert's rows padded to a whole number of ``tm``-row tiles, so a tile
 belongs to ONE expert and the kernel is a plain tiled FFN whose weight
 blocks are picked per tile from a scalar-prefetched table
 (``tile_expert``). An expert nobody chose has no tile, and its weights are
-never read: a decode step of 32 lanes x 8 choices touches the ~150 experts
-hit, not all 256. Tiles past the last used one (the static grid is sized
-for the worst case, every expert one row over a tile) are skipped: they
-compute nothing and their block indices repeat the last used tile's, so
-nothing is fetched or written back for them.
+never read: a decode step touches the experts its lanes hit, not all that
+are held. Tiles past the last used one (the static grid is sized for the
+worst case, every assignment landing here and every expert one row over a
+tile) are skipped: they compute nothing and their block indices repeat the
+last used tile's, so nothing is fetched or written back for them.
 
 Grid ``(tiles, h / th)``: the hidden width is walked in ``th``-wide chunks
 (the inner, "arbitrary" axis) with an f32 accumulator in VMEM, so the
-weight blocks are ``[d, th]`` / ``[th, d]`` (1 MB at d 2048, th 256, bf16)
-and the double-buffered working set stays under the 16 MiB a v5e kernel
-gets by default. The ``pallas_call`` is named ``moe_expert_ffn``: the
-compiler makes that the instruction's name, which a device trace shows.
+weight blocks are ``[d, th]`` / ``[th, d]``. The tiles follow the shapes
+(:func:`hidden_chunk`, :func:`max_tile_rows`): the three weight blocks,
+double-buffered, take at most ``WEIGHT_BYTES`` of the ``VMEM_BYTES`` a v5e
+kernel gets by default, and the row tiles (input and output double-buffered,
+the f32 accumulator) what is left beside ``SPARE_BYTES`` for the body's own
+temporaries. The ``pallas_call`` is named ``moe_expert_ffn``: the compiler
+makes that the instruction's name, which a device trace shows.
 
 :func:`routed_experts` is the whole path around the kernel (sort, layout,
 gather, kernel, weighted gather back) and what the "routed_experts" helper
@@ -42,17 +45,50 @@ MAX_TM = 256
 KERNEL_NAME = "moe_expert_ffn"
 
 
-def tile_rows(assignments: int, experts: int, itemsize: int = 2) -> int:
-    """``tm`` for ``assignments`` rows over ``experts`` experts: twice the
-    mean rows an expert gets, as a power of two in [16, 256] (half that for
-    4-byte operands: the working set is what a kernel's 16 MiB holds). A
-    tile costs one read of its expert's weights whatever its rows, so few
-    rows per expert (decode) want the smallest tile and many (prefill) the
-    largest: at 256 rows the products take as long as the weights take to
-    arrive."""
+#: the scoped VMEM a v5e kernel gets by default; of it, what the three
+#: weight blocks may take double-buffered, and what is left to the body
+VMEM_BYTES = 16 * 2 ** 20
+WEIGHT_BYTES = 10 * 2 ** 20
+SPARE_BYTES = 2 * 2 ** 20
+
+
+def _weight_blocks(d: int, th: int, itemsize: int) -> int:
+    """Bytes of ``[d, th]``, ``[d, th]`` and ``[th, d]``, double-buffered."""
+    return 6 * d * th * itemsize
+
+
+def hidden_chunk(d: int, h: int, itemsize: int = 2) -> int:
+    """``th``: the widest of 512 / 256 / 128 that divides ``h`` and whose
+    weight blocks fit ``WEIGHT_BYTES`` (256 at d 2048 and 128 at d 6144 for
+    2-byte operands, 128 at d 2048 for 4-byte ones); ``h`` whole where none
+    divides it (a test's size)."""
+    for th in (512, 256, 128):
+        if h % th == 0 and _weight_blocks(d, th, itemsize) <= WEIGHT_BYTES:
+            return th
+    return h
+
+
+def max_tile_rows(d: int, th: int, itemsize: int = 2) -> int:
+    """The largest ``tm`` (a power of two in [16, 256]) whose row tiles —
+    input and output double-buffered, the f32 accumulator — fit beside the
+    weight blocks: 256 at d 2048 for 2-byte operands (128 for 4-byte ones),
+    64 at d 6144."""
+    left = VMEM_BYTES - SPARE_BYTES - _weight_blocks(d, th, itemsize)
+    tm = MAX_TM
+    while tm > MIN_TM and tm * d * (4 * itemsize + 4) > left:
+        tm //= 2
+    return tm
+
+
+def tile_rows(assignments: int, experts: int, cap: int = MAX_TM) -> int:
+    """``tm`` for the ``assignments`` rows expected over ``experts``
+    experts: twice the mean rows an expert gets, as a power of two in
+    [16, ``cap``]. A tile costs one read of its expert's weights whatever
+    its rows, so few rows per expert (decode) want the smallest tile and
+    many (prefill) the largest the working set allows."""
     want = 2 * max(assignments // max(experts, 1), 1)
     tm = MIN_TM
-    while tm < want and tm < MAX_TM * 2 // max(itemsize, 2):
+    while tm < want and tm < cap:
         tm *= 2
     return tm
 
@@ -151,21 +187,25 @@ def layout(local, experts: int, tm: int):
 
 
 def routed_experts(x, idx, gates, wg, wu, wd, first_expert: int = 0,
-                   interpret=None):
+                   routed_over: int = 0, interpret=None):
     """Σ_k gates[n, k] · E_{idx[n, k]}(x[n]) over the experts held
-    (``first_expert`` .. ``first_expert + E``; a choice held elsewhere
-    contributes nothing): x [N, d], idx [N, k] int32 over all experts,
-    gates [N, k] f32. Returns [N, d] f32."""
+    (``first_expert`` .. ``first_expert + E``; a choice held elsewhere, or
+    of a zero-compute expert, contributes nothing): x [N, d], idx [N, k]
+    int32 over the router's width ``routed_over`` (0: the experts held are
+    all there is), gates [N, k] f32. Returns [N, d] f32. The layout holds a
+    row for every assignment, whatever lands here (no capacity, no dropped
+    token); ``tm`` is sized for the share EXPECTED here, ``E /
+    routed_over`` of them."""
     if interpret is None:
         interpret = _interpret_default()
     n, k = idx.shape
     experts, d, h = wg.shape
     local = idx.reshape(-1).astype(jnp.int32) - first_expert
     local = jnp.where((local >= 0) & (local < experts), local, experts)
-    itemsize = jnp.dtype(x.dtype).itemsize
-    tm = tile_rows(n * k, experts, itemsize)
-    th = 512 // max(itemsize, 2)        # 1 MB weight blocks at d 2048
-    th = th if h % th == 0 else h
+    itemsize = max(jnp.dtype(x.dtype).itemsize, 2)
+    th = hidden_chunk(d, h, itemsize)
+    tm = tile_rows(n * k * experts // (routed_over or experts), experts,
+                   max_tile_rows(d, th, itemsize))
     src, row, tile_expert, used = layout(local, experts, tm)
     rows = expert_ffn_tiles(x[src // k], tile_expert, used, wg, wu, wd,
                             tm=tm, th=th, interpret=bool(interpret))

@@ -123,16 +123,20 @@ _ENGINE_COUNTERS = {
     # block's one readback)
     "moe_step_layers": "decode steps x expert layers with an alive lane",
     "moe_assignments": "token-expert assignments of alive lanes",
-    "moe_experts_hit": "distinct experts chosen by some alive lane, "
-                       "summed over steps and layers",
-    "moe_experts_read": "distinct experts chosen by ANY lane of the block, "
-                        "stopped ones too (what the step computed and "
-                        "read), summed over steps and layers",
+    "moe_experts_hit": "distinct experts HELD HERE chosen by some alive "
+                       "lane, summed over steps and layers",
+    "moe_experts_read": "distinct experts held here chosen by ANY lane of "
+                        "the block, stopped ones too (what the step "
+                        "computed and read), summed over steps and layers",
+    "moe_zero_assignments": "alive lanes' choices of zero-compute experts",
+    "moe_held_assignments": "alive lanes' choices of experts held here "
+                            "(all of moe_assignments where every expert is)",
 }
 #: the columns a decode block of a model with expert layers appends to its
-#: token matrix, in this order (every row carries the same four sums)
+#: token matrix, in this order (every row carries the same sums)
 MOE_COUNTERS = ("moe_step_layers", "moe_assignments", "moe_experts_hit",
-                "moe_experts_read")
+                "moe_experts_read", "moe_zero_assignments",
+                "moe_held_assignments")
 #: unique per-engine metric label values (e0, e1, ...)
 _ENGINE_SEQ = itertools.count()
 
@@ -522,16 +526,29 @@ class TransformerDecoder:
                                              axis=-1).astype(jnp.int32)
             return jnp.where(temps <= 0, greedy, sampled)
 
-    @staticmethod
     # graftlint: traced
-    def _moe_sums(tally):
-        """One decode step's MOE_COUNTERS [4] int32 from its expert layers'
-        per-expert counts: of alive lanes' tokens, and of every lane's."""
+    def _moe_sums(self, tally):
+        """One decode step's MOE_COUNTERS int32 from its expert layers'
+        counts: of alive lanes' tokens, and of every lane's. A choice is of
+        a zero-compute expert (counted apart, by the layer), of an expert
+        held here, or of one held elsewhere; the distinct experts are
+        counted among those held here."""
+        layers = [self.net.conf.vertices[n].layer for n in self.moe_names]
+
+        def held(key):
+            return jnp.stack([
+                t[key][v.first_expert:v.first_expert + v.experts_held]
+                if v.experts_held else t[key]
+                for t, v in zip(tally, layers)])
         load = jnp.stack([t["expert_tokens"] for t in tally])   # [L, E]
-        rows = jnp.stack([t["expert_rows"] for t in tally])
+        zero = jnp.stack([t.get("zero_tokens", jnp.int32(0))
+                          for t in tally])                       # [L]
+        chosen = jnp.sum(load, axis=1) + zero
+        here = held("expert_tokens")
         return jnp.stack([
-            jnp.sum(jnp.any(load > 0, axis=1)), jnp.sum(load),
-            jnp.sum(load > 0), jnp.sum(rows > 0)]).astype(jnp.int32)
+            jnp.sum(chosen > 0), jnp.sum(chosen), jnp.sum(here > 0),
+            jnp.sum(held("expert_rows") > 0), jnp.sum(zero),
+            jnp.sum(here)]).astype(jnp.int32)
 
     # graftlint: traced
     def _block_columns(self, toks, fault, moe):
@@ -860,7 +877,7 @@ class TransformerDecoder:
                 # (lax.scan): cache state, per-row stop flags, the
                 # sentinel's fault accumulator, the expert counters and
                 # the absolute step counter ride the carry; only the
-                # [B, K(+1)(+4)] matrix ever needs to cross to the host.
+                # [B, K(+1)(+6)] matrix ever needs to cross to the host.
                 # The key schedule folds the ABSOLUTE step index, so a
                 # given lane samples identically for every block size.
                 # Over PAGED pools the page tables are one more input of

@@ -145,6 +145,88 @@ def latent_moe_lm_conf(vocab_size: int, d_model: int, num_heads: int,
     return g.build()
 
 
+def shortcut_moe_lm_conf(vocab_size: int, d_model: int, num_heads: int,
+                         num_layers: int, *, q_rank: int, kv_rank: int,
+                         nope_dim: int, rope_dim: int, v_dim: int,
+                         dense_hidden: int, num_experts: int,
+                         zero_experts: int = 0, top_k: int,
+                         expert_hidden: int, routed_scaling: float = 1.0,
+                         first_expert: int = 0, experts_held: int = 0,
+                         q_scale: float = 1.0, kv_scale: float = 1.0,
+                         rope_theta: float = 10000.0, eps: float = 1e-5,
+                         max_length: int = 4096, learning_rate: float = 3e-4,
+                         seed: int = 42):
+    """ComputationGraphConfiguration for a causal LM of shortcut-connected
+    DOUBLE blocks: two latent attentions and two dense gated FFNs a layer,
+    and one expert branch that leaves after the first attention and joins one
+    attention and one FFN later (every norm an RMSNorm):
+
+        a0 = x  + Attn_a(RMSNorm(x));   n0 = RMSNorm(a0)
+        s  = MoE(n0)                    # vertex ``moe{i}``: the shortcut
+        b0 = a0 + FFN_a(n0)
+        a1 = b0 + Attn_b(RMSNorm(b0))
+        x' = a1 + FFN_b(RMSNorm(a1)) + s
+
+    Vertices of layer ``i``: ``ln{i}a..d``, ``attn{i}a`` / ``attn{i}b``,
+    ``ffn{i}a`` / ``ffn{i}b`` (width ``dense_hidden``), ``moe{i}``,
+    ``res{i}a..d`` (the last a three-input add). The expert branch routes by
+    softmax over ``num_experts + zero_experts`` outputs, top ``top_k``,
+    weights ``routed_scaling`` times the scores and not renormalised, the
+    zero-compute experts the identity, no shared expert, no drops;
+    ``first_expert`` / ``experts_held`` give it its share of the experts
+    that have weights (all by default). ``q_scale`` / ``kv_scale`` scale the
+    attentions' two normalised latents. A token-only embedding (positions
+    are rotary, inside attention), a final RMSNorm and an untied head with
+    no bias, as :func:`latent_moe_lm_conf` has them."""
+    g = (NeuralNetConfiguration.Builder().seed(seed)
+         .learning_rate(learning_rate).updater("adam").weight_init("xavier")
+         .graph_builder()
+         .add_inputs("tokens"))
+    g.add_layer("embed", TokenEmbedding(n_in=vocab_size, n_out=d_model,
+                                        max_length=max_length), "tokens")
+    norm = lambda: RMSNormalization(n_in=d_model, n_out=d_model, eps=eps)
+    attention = lambda: LatentAttentionLayer(
+        n_in=d_model, n_out=d_model, num_heads=num_heads, q_rank=q_rank,
+        kv_rank=kv_rank, nope_dim=nope_dim, rope_dim=rope_dim, v_dim=v_dim,
+        rope_theta=rope_theta, eps=eps, q_scale=q_scale, kv_scale=kv_scale,
+        activation="identity")
+    dense = lambda: GatedFeedForward(n_in=d_model, n_out=d_model,
+                                     hidden=dense_hidden,
+                                     activation="identity")
+    add = lambda: ElementWiseVertex(op="add")
+    x = "embed"
+    for i in range(num_layers):
+        g.add_layer(f"ln{i}a", norm(), x)
+        g.add_layer(f"attn{i}a", attention(), f"ln{i}a")
+        g.add_vertex(f"res{i}a", add(), x, f"attn{i}a")
+        g.add_layer(f"ln{i}b", norm(), f"res{i}a")
+        g.add_layer(f"moe{i}",
+                    RoutedExpertsLayer(
+                        n_in=d_model, n_out=d_model, num_experts=num_experts,
+                        zero_experts=zero_experts, top_k=top_k,
+                        expert_hidden=expert_hidden, shared_experts=0,
+                        score_function="softmax", renormalize=False,
+                        routed_scaling=routed_scaling,
+                        first_expert=first_expert,
+                        experts_held=experts_held, activation="identity"),
+                    f"ln{i}b")
+        g.add_layer(f"ffn{i}a", dense(), f"ln{i}b")
+        g.add_vertex(f"res{i}b", add(), f"res{i}a", f"ffn{i}a")
+        g.add_layer(f"ln{i}c", norm(), f"res{i}b")
+        g.add_layer(f"attn{i}b", attention(), f"ln{i}c")
+        g.add_vertex(f"res{i}c", add(), f"res{i}b", f"attn{i}b")
+        g.add_layer(f"ln{i}d", norm(), f"res{i}c")
+        g.add_layer(f"ffn{i}b", dense(), f"ln{i}d")
+        g.add_vertex(f"res{i}d", add(), f"res{i}c", f"ffn{i}b", f"moe{i}")
+        x = f"res{i}d"
+    g.add_layer("lnf", norm(), x)
+    g.add_layer("out",
+                RnnOutputLayer(n_in=d_model, n_out=vocab_size, loss="mcxent",
+                               activation="softmax", has_bias=False), "lnf")
+    g.set_outputs("out")
+    return g.build()
+
+
 def lm_batch_sparse(tokens: np.ndarray):
     """(features, integer labels) for next-token training from token ids
     [N, T+1] — the fused-CE path (kernels/fused_ce.py): labels stay [N, T]

@@ -665,14 +665,26 @@ TOKEN_BLOCK = 4096
 
 
 # graftlint: traced
-def in_token_blocks(fn, *arrays):
+def token_block(rows_per_token: int = 1) -> int:
+    """``TOKEN_BLOCK``, halved until a block lays out at most the rows that
+    eight a token make of it (an expert layer's ``top_k`` choices a token:
+    4096 tokens up to top-8, 2048 at top-12)."""
+    block = TOKEN_BLOCK
+    while block * rows_per_token > TOKEN_BLOCK * 8:
+        block //= 2
+    return block
+
+
+def in_token_blocks(fn, *arrays, block: int = 0):
     """``fn(*arrays)`` over arrays that share a leading token axis [N, ...],
-    ``TOKEN_BLOCK`` tokens at a time where N is a longer multiple of it (the
-    results, a pytree of [N, ...] arrays, put together again)."""
+    ``block`` (``TOKEN_BLOCK`` unless given) tokens at a time where N is a
+    longer multiple of it (the results, a pytree of [N, ...] arrays, put
+    together again)."""
+    block = block or TOKEN_BLOCK
     n = arrays[0].shape[0]
-    if n <= TOKEN_BLOCK or n % TOKEN_BLOCK:
+    if n <= block or n % block:
         return fn(*arrays)
-    blocks = tuple(a.reshape((n // TOKEN_BLOCK, TOKEN_BLOCK) + a.shape[1:])
+    blocks = tuple(a.reshape((n // block, block) + a.shape[1:])
                    for a in arrays)
     out = jax.lax.map(lambda blk: fn(*blk), blocks)
     return jax.tree_util.tree_map(

@@ -1,10 +1,20 @@
-"""Routed experts without drops: sigmoid scores, a selection bias, top-k,
-renormalised and scaled weights, and a shared expert.
+"""Routed experts without drops: scores, a selection bias, top-k, scaled
+weights, and what every token gets besides (a shared expert; zero-compute
+experts).
 
-    s = sigmoid(x Wr)                        float32, over ALL experts
+    s = sigmoid(x Wr)  or  softmax(x Wr)     float32, over the router's width
     chosen = top_k(s + b)                    b: for choosing only
-    g_i = scaling * s_i / sum_{j chosen} s_j
-    y = sum_{i chosen} g_i E_i(x) + E_shared(x),   E = (silu(x Wg) * x Wu) Wd
+    g_i = scaling * s_i / sum_{j chosen} s_j     (``renormalize``; else
+    g_i = scaling * s_i)
+    y = sum_{i chosen, i < E} g_i E_i(x) + (sum_{i chosen, i >= E} g_i) x
+        + E_shared(x),                       E = (silu(x Wg) * x Wu) Wd
+
+The router is ``num_experts + zero_experts`` wide: a choice past the experts
+that have weights is a ZERO-COMPUTE expert, the identity, and adds ``g x``
+(float32, scope ``zero``). It has no weights and no home: every chip
+computes it whole for its own tokens. ``shared_experts`` 0 leaves the shared
+expert out. The defaults (sigmoid, renormalised, no zero-compute expert) are
+the DeepSeek-V3 layer.
 
 No capacity and no dropped token: every token is computed by every expert it
 chose, whatever the load. The layer is told which experts it HOLDS
@@ -17,10 +27,12 @@ The second return (the layer's state) is two [E] int32 counts of this
 call's tokens by the expert they chose: ``expert_rows`` of every token (the
 rows the experts computed, the weights that were read) and
 ``expert_tokens`` of the tokens a ``mask`` [N, T] marks (a decode block's
-alive lanes; every token without one). The mask counts only: a decode block
-computes every lane in every layer whether a request holds it or not, and
-this layer is no exception, so a stopped lane still routes and its experts
-are read — the difference between the two counts is what keeping stopped
+alive lanes; every token without one); with zero-compute experts also
+``zero_tokens``, one count of the marked tokens' choices among them (they
+are no experts, and stay out of the [E] counts). The mask counts only: a
+decode block computes every lane in every layer whether a request holds it
+or not, and this layer is no exception, so a stopped lane still routes and
+its experts are read — the difference between the two counts is what keeping stopped
 lanes out would save (a third of a step at partial occupancy, PERF.md §6,
 PR 29). Integer, not differentiated; a decode block sums them into the
 engine's counters.
@@ -43,7 +55,7 @@ import jax.numpy as jnp
 from ...helpers import get_helper
 from ..input_type import InputType
 from ..serde import register_config
-from .attention import gated_ffn, in_token_blocks
+from .attention import gated_ffn, in_token_blocks, token_block
 from .base import BaseRecurrentLayerConf
 
 @register_config
@@ -57,6 +69,9 @@ class RoutedExpertsLayer(BaseRecurrentLayerConf):
     routed_scaling: float = 1.0
     first_expert: int = 0
     experts_held: int = 0            # 0: all of them
+    score_function: str = "sigmoid"  # or "softmax", over the router's width
+    renormalize: bool = True         # gates over the chosen sum to scaling
+    zero_experts: int = 0            # identity experts past the weighted ones
     #: what the decode walk reads off the class: where a pass counts, it
     #: hands ``forward`` the alive lanes as ``mask`` and keeps the counts
     counts_tokens = True
@@ -70,13 +85,17 @@ class RoutedExpertsLayer(BaseRecurrentLayerConf):
     def _held(self) -> int:
         return self.experts_held or self.num_experts
 
+    def _routed_over(self) -> int:
+        """The router's width: every expert a token may choose."""
+        return self.num_experts + self.zero_experts
+
     def init_params(self, key, dtype=jnp.float32) -> Dict:
         d, h, e = self.n_in, self.expert_hidden, self._held()
         ks = jax.random.split(key, 8)
         w = lambda k, shape: self._winit(k, shape, shape[-2], shape[-1],
                                          dtype)
-        p = {"Wr": w(ks[0], (d, self.num_experts)),
-             "b": jnp.zeros((self.num_experts,), dtype),
+        p = {"Wr": w(ks[0], (d, self._routed_over())),
+             "b": jnp.zeros((self._routed_over(),), dtype),
              "Wg": w(ks[1], (e, d, h)), "Wu": w(ks[2], (e, d, h)),
              "Wd": w(ks[3], (e, h, d))}
         if self.shared_experts:
@@ -87,7 +106,10 @@ class RoutedExpertsLayer(BaseRecurrentLayerConf):
 
     def init_state(self) -> Dict:
         zero = jnp.zeros((self.num_experts,), jnp.int32)
-        return {"expert_tokens": zero, "expert_rows": zero}
+        state = {"expert_tokens": zero, "expert_rows": zero}
+        if self.zero_experts:
+            state["zero_tokens"] = jnp.zeros((), jnp.int32)
+        return state
 
     def regularizable(self):
         return ("Wg", "Wu", "Wd", "Sg", "Su", "Sd")
@@ -95,14 +117,20 @@ class RoutedExpertsLayer(BaseRecurrentLayerConf):
     # graftlint: traced
     def route(self, params, x):
         """x [N, d] → (chosen [N, k] int32, gates [N, k] f32)."""
-        s = jax.nn.sigmoid(jnp.einsum(
+        score = {"sigmoid": jax.nn.sigmoid,
+                 "softmax": lambda z: jax.nn.softmax(z, axis=-1)}[
+                     self.score_function]
+        s = score(jnp.einsum(
             "nd,de->ne", x, params["Wr"],
             preferred_element_type=jnp.float32).astype(jnp.float32))
         _, chosen = jax.lax.top_k(s + params["b"].astype(jnp.float32)[None],
                                   self.top_k)
         picked = jnp.take_along_axis(s, chosen, axis=-1)
-        gates = self.routed_scaling * picked / jnp.sum(picked, axis=-1,
-                                                       keepdims=True)
+        if self.renormalize:
+            gates = self.routed_scaling * picked / jnp.sum(picked, axis=-1,
+                                                           keepdims=True)
+        else:
+            gates = self.routed_scaling * picked
         return chosen.astype(jnp.int32), gates
 
     # graftlint: traced
@@ -126,9 +154,15 @@ class RoutedExpertsLayer(BaseRecurrentLayerConf):
             helper = get_helper("routed_experts")
             if helper is not None:
                 y = helper(x, chosen, gates, params["Wg"], params["Wu"],
-                           params["Wd"], self.first_expert)
+                           params["Wd"], self.first_expert,
+                           self._routed_over())
             else:
                 y = self._dense(params, x, chosen, gates)
+        if self.zero_experts:
+            with jax.named_scope("zero"):
+                identity = jnp.sum(jnp.where(chosen >= self.num_experts,
+                                             gates, 0.0), axis=-1)
+                y = y + identity[:, None] * x.astype(jnp.float32)
         if self.shared_experts:
             with jax.named_scope("shared"):
                 y = y + gated_ffn(x, params["Sg"], params["Su"],
@@ -141,12 +175,19 @@ class RoutedExpertsLayer(BaseRecurrentLayerConf):
         flat = x.reshape(-1, shape[-1])
         # a long input in blocks: bounds the token-expert rows laid out
         y, chosen = in_token_blocks(lambda blk: self._block(params, blk),
-                                    flat)
+                                    flat, block=token_block(self.top_k))
         def count(marked):
+            # a zero-compute choice lies past the [E] counts and is dropped
             return jnp.zeros((self.num_experts,), jnp.int32).at[
                 chosen.reshape(-1)].add(jnp.repeat(marked, self.top_k))
         rows = count(jnp.ones((flat.shape[0],), jnp.int32))
-        tokens = rows if mask is None \
-            else count((mask.reshape(-1) > 0).astype(jnp.int32))
-        return y.reshape(shape), {"expert_tokens": tokens,
-                                  "expert_rows": rows}
+        marked = None if mask is None \
+            else (mask.reshape(-1) > 0).astype(jnp.int32)
+        counts = {"expert_tokens": rows if mask is None else count(marked),
+                  "expert_rows": rows}
+        if self.zero_experts:
+            zero = chosen >= self.num_experts
+            counts["zero_tokens"] = jnp.sum(
+                zero if mask is None else zero * marked[:, None],
+                dtype=jnp.int32)
+        return y.reshape(shape), counts

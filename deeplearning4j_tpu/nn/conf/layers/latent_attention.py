@@ -1,15 +1,19 @@
 """Latent attention: keys and values of every head are decompressed from ONE
 low-rank row a token, and that row is all the cache holds.
 
-    c_q = RMSNorm(x Wqa)                        [q_rank]
+    c_q = RMSNorm(x Wqa) * q_scale              [q_rank]
     [q_nope_h ; q_rope_h] = c_q Wqb             per head: nope_dim + rope_dim
     [c_kv ; k_r] = x Wkva                       [kv_rank + rope_dim]
-    c_kv <- RMSNorm(c_kv);  k_rope = RoPE(k_r)  one k_rope for all heads
+    c_kv <- RMSNorm(c_kv) * kv_scale
+    k_rope = RoPE(k_r)                          one k_rope for all heads
     [k_nope_h ; v_h] = c_kv Wkvb                per head: nope_dim + v_dim
     s_h = (q_nope_h . k_nope_h + RoPE(q_rope_h) . k_rope) / sqrt(nope + rope)
     out = concat_h(softmax(s_h) v_h) Wo
 
-No bias anywhere. The slab cache is ``{"kv": [B, 1, T_max, kv_rank +
+No bias anywhere. ``q_scale`` / ``kv_scale`` (1.0: none) are one scalar each
+on the two normalised latents — a family that scales them by
+``sqrt(n_in / rank)`` — in every path alike, so the cache's row holds the
+SCALED ``c_kv``; ``k_r`` is not scaled. The slab cache is ``{"kv": [B, 1, T_max, kv_rank +
 rope_dim]}``: a token's row is ``[c_kv after its norm ; k_rope after
 rotation]``, nothing per head (the singleton axis stands where the k/v slabs
 have their head groups, so the decoder's slot slicing is the same code).
@@ -80,6 +84,8 @@ class LatentAttentionLayer(SelfAttentionLayer):
     v_dim: int = 0
     rope_theta: float = 10000.0
     eps: float = 1e-6
+    q_scale: float = 1.0
+    kv_scale: float = 1.0
 
     def _head_size(self) -> int:
         """The width a score contracts over (``_attend`` scales by it)."""
@@ -119,11 +125,15 @@ class LatentAttentionLayer(SelfAttentionLayer):
         [B, T, kv_rank + rope])."""
         b, t, _ = x.shape
         cq = rms_norm(x @ params["Wqa"], params["gq"], self.eps)
+        if self.q_scale != 1.0:
+            cq = cq * self.q_scale
         q = (cq @ params["Wqb"]).reshape(b, t, self.num_heads,
                                          self._head_size())
         q_rope = rope(q[..., self.nope_dim:], pos, self.rope_theta)
         kva = x @ params["Wkva"]
         ckv = rms_norm(kva[..., :self.kv_rank], params["gkv"], self.eps)
+        if self.kv_scale != 1.0:
+            ckv = ckv * self.kv_scale
         k_rope = rope(kva[..., self.kv_rank:], pos, self.rope_theta)
         return (q[..., :self.nope_dim], q_rope,
                 jnp.concatenate([ckv, k_rope], axis=-1))

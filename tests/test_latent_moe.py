@@ -142,7 +142,8 @@ def test_engine_is_token_identical_with_steady_compiles_and_counters(net, dec):
     transfers.check_per_block("engine.decode", stats["decode_blocks"])
     # a block whose lanes all finished meanwhile is dropped unread
     assert transfers.fetches("engine.decode") <= stats["decode_blocks"]
-    sl, asg, hit, read = (stats[k] for k in MOE_COUNTERS)
+    sl, asg, hit, read, zero, held = (stats[k] for k in MOE_COUNTERS)
+    assert zero == 0 and held == asg         # no zero-compute expert, no share
     assert sl > 0 and sl % 2 == 0            # two expert layers a step
     assert sl <= 2 * stats["decode_steps"]
     lanes = asg // 2                         # top_k 2: alive lanes, summed
@@ -173,12 +174,14 @@ def test_expert_counters_are_exact_on_hand_made_routing():
     assert host.shape == (3, 4)
     assert dict(zip(MOE_COUNTERS, moe.tolist())) == {
         "moe_step_layers": 4, "moe_assignments": 4 * 2 * 2,
-        "moe_experts_hit": 4 * 2, "moe_experts_read": 4 * 2}
+        "moe_experts_hit": 4 * 2, "moe_experts_read": 4 * 2,
+        "moe_zero_assignments": 0, "moe_held_assignments": 4 * 2 * 2}
     # every lane stopped: nothing is counted for a request, and the block
     # still computes (and reads) what its lanes route to
     out, *_ = dec.decode_block(caches, nxt, lens, block_size=4,
                                stopped=np.ones(3, bool))
-    assert dec.split_block(np.asarray(out))[1].tolist() == [0, 0, 0, 4 * 2]
+    assert dec.split_block(np.asarray(out))[1].tolist() == [0, 0, 0, 4 * 2,
+                                                            0, 0]
 
 
 def test_model_without_experts_reads_back_what_it_did():
