@@ -118,16 +118,19 @@ _ENGINE_COUNTERS = {
                             "generation_spec_accepted_total{len=})",
     "spec_fallbacks": "decode blocks dispatched by the low-acceptance "
                       "adaptive fallback while speculation is enabled",
-    # routed-expert load of alive lanes (and, last, of every lane), a decode
-    # step and an expert layer at a time (MOE_COUNTERS; they ride each
-    # block's one readback)
+    # routed-expert load of alive lanes, a decode step and an expert layer at
+    # a time (MOE_COUNTERS; they ride each block's one readback). A stopped
+    # lane's choices are cast out before the experts, so what a step reads
+    # is what its alive lanes hit: moe_experts_read equal to moe_experts_hit
+    # says the cast engaged on every step
     "moe_step_layers": "decode steps x expert layers with an alive lane",
     "moe_assignments": "token-expert assignments of alive lanes",
     "moe_experts_hit": "distinct experts HELD HERE chosen by some alive "
                        "lane, summed over steps and layers",
-    "moe_experts_read": "distinct experts held here chosen by ANY lane of "
-                        "the block, stopped ones too (what the step "
-                        "computed and read), summed over steps and layers",
+    "moe_experts_read": "distinct experts held here that some lane's "
+                        "choice REACHED (what the step computed and read; "
+                        "a stopped lane's reach none, so this equals "
+                        "moe_experts_hit), summed over steps and layers",
     "moe_zero_assignments": "alive lanes' choices of zero-compute experts",
     "moe_held_assignments": "alive lanes' choices of experts held here "
                             "(all of moe_assignments where every expert is)",
@@ -529,10 +532,10 @@ class TransformerDecoder:
     # graftlint: traced
     def _moe_sums(self, tally):
         """One decode step's MOE_COUNTERS int32 from its expert layers'
-        counts: of alive lanes' tokens, and of every lane's. A choice is of
-        a zero-compute expert (counted apart, by the layer), of an expert
-        held here, or of one held elsewhere; the distinct experts are
-        counted among those held here."""
+        counts: of alive lanes' tokens, and of the choices that reached the
+        experts. A choice is of a zero-compute expert (counted apart, by
+        the layer), of an expert held here, or of one held elsewhere; the
+        distinct experts are counted among those held here."""
         layers = [self.net.conf.vertices[n].layer for n in self.moe_names]
 
         def held(key):

@@ -23,19 +23,34 @@ them, and returns the part its own experts give plus the shared expert's —
 what one chip of an expert-parallel layer computes before the exchange (all
 of them by default).
 
-The second return (the layer's state) is two [E] int32 counts of this
-call's tokens by the expert they chose: ``expert_rows`` of every token (the
-rows the experts computed, the weights that were read) and
-``expert_tokens`` of the tokens a ``mask`` [N, T] marks (a decode block's
-alive lanes; every token without one); with zero-compute experts also
+The second return (the layer's state) is an [E] int32 count of this call's
+tokens by the expert they chose, under two names: ``expert_tokens``, of the
+tokens a ``mask`` [N, T] marks (a decode block's alive lanes; every token
+without one), and ``expert_rows``, of the choices that REACHED the experts
+(the rows they computed, the weights that were read) — one count, since a
+mask keeps the other tokens out; with zero-compute experts also
 ``zero_tokens``, one count of the marked tokens' choices among them (they
-are no experts, and stay out of the [E] counts). The mask counts only: a
-decode block computes every lane in every layer whether a request holds it
-or not, and this layer is no exception, so a stopped lane still routes and
-its experts are read — the difference between the two counts is what keeping stopped
-lanes out would save (a third of a step at partial occupancy, PERF.md §6,
-PR 29). Integer, not differentiated; a decode block sums them into the
-engine's counters.
+are no experts, and stay out of the [E] counts). Integer, not
+differentiated; a decode block sums them into the engine's counters.
+
+A mask keeps unmarked tokens OUT of the routed experts: after ``route`` and
+before either way through the experts, an unmarked token's choices are cast
+to an index past the router's width, which no chip holds — the kernel's
+layout gives it no row and no tile, so an expert only unmarked tokens chose
+is not read, and the dense path weighs it by zero. A decode step therefore
+reads the experts its alive lanes chose, not those of all its lanes (the
+engine's ``moe_experts_read`` equals its ``moe_experts_hit``). A marked
+token's output is bit for bit what it is without
+the mask: rows of a tile are independent, so who shares a tile changes
+nothing. What stays dense over every token: the router product and the
+shared expert (their weights are read once a call whatever the tokens) and
+the zero-compute term (no weights), so an unmarked token's output is its
+shared expert plus its identity share and nothing else. Nothing reads it —
+a stopped lane re-emits its last id and the sentinel's verdict exempts it —
+and nothing after ``route`` works across tokens, so whatever a stopped
+lane's stale rows hold (a NaN too) stays in that lane. The same holds for a
+training batch's padding mask: a padded token's output reaches no loss and
+no other token, so a step's numbers do not move.
 
 Two ways through the experts, one result: the built-in path is dense over
 the experts held (every expert on every token, weighted by a gate that is
@@ -73,7 +88,8 @@ class RoutedExpertsLayer(BaseRecurrentLayerConf):
     renormalize: bool = True         # gates over the chosen sum to scaling
     zero_experts: int = 0            # identity experts past the weighted ones
     #: what the decode walk reads off the class: where a pass counts, it
-    #: hands ``forward`` the alive lanes as ``mask`` and keeps the counts
+    #: hands ``forward`` the alive lanes as ``mask`` (the others stay out of
+    #: the experts) and keeps the counts
     counts_tokens = True
 
     def set_n_in(self, it: InputType) -> None:
@@ -146,18 +162,27 @@ class RoutedExpertsLayer(BaseRecurrentLayerConf):
         return jnp.einsum("ned,ne->nd", y.astype(jnp.float32), weight)
 
     # graftlint: traced
-    def _block(self, params, x):
-        """x [N, d] → (y [N, d], chosen [N, k])."""
+    def _reached(self, chosen, marked):
+        """``chosen`` [N, k] as it reaches the experts: an unmarked token's
+        choices (``marked`` [N] bool; None marks all) cast past the router's
+        width — an expert held nowhere, so they get no row and no weight."""
+        return chosen if marked is None else jnp.where(
+            marked[:, None], chosen, self._routed_over())
+
+    # graftlint: traced
+    def _block(self, params, x, marked=None):
+        """x [N, d], marked [N] bool or None → (y [N, d], chosen [N, k])."""
         with jax.named_scope("route"):
             chosen, gates = self.route(params, x)
         with jax.named_scope("experts"):
+            reached = self._reached(chosen, marked)
             helper = get_helper("routed_experts")
             if helper is not None:
-                y = helper(x, chosen, gates, params["Wg"], params["Wu"],
+                y = helper(x, reached, gates, params["Wg"], params["Wu"],
                            params["Wd"], self.first_expert,
                            self._routed_over())
             else:
-                y = self._dense(params, x, chosen, gates)
+                y = self._dense(params, x, reached, gates)
         if self.zero_experts:
             with jax.named_scope("zero"):
                 identity = jnp.sum(jnp.where(chosen >= self.num_experts,
@@ -173,21 +198,21 @@ class RoutedExpertsLayer(BaseRecurrentLayerConf):
         x = self.maybe_dropout(x, train=train, rng=rng)
         shape = x.shape
         flat = x.reshape(-1, shape[-1])
+        marked = None if mask is None else mask.reshape(-1) > 0
+        arrays = (flat,) if marked is None else (flat, marked)
         # a long input in blocks: bounds the token-expert rows laid out
-        y, chosen = in_token_blocks(lambda blk: self._block(params, blk),
-                                    flat, block=token_block(self.top_k))
-        def count(marked):
-            # a zero-compute choice lies past the [E] counts and is dropped
-            return jnp.zeros((self.num_experts,), jnp.int32).at[
-                chosen.reshape(-1)].add(jnp.repeat(marked, self.top_k))
-        rows = count(jnp.ones((flat.shape[0],), jnp.int32))
-        marked = None if mask is None \
-            else (mask.reshape(-1) > 0).astype(jnp.int32)
-        counts = {"expert_tokens": rows if mask is None else count(marked),
-                  "expert_rows": rows}
+        y, chosen = in_token_blocks(lambda *blk: self._block(params, *blk),
+                                    *arrays, block=token_block(self.top_k))
+        # by expert, the choices that reached one: a cast choice, like a
+        # zero-compute one, lies past the [E] counts and is dropped
+        load = jnp.zeros((self.num_experts,), jnp.int32).at[
+            self._reached(chosen, marked).reshape(-1)].add(
+                jnp.repeat(jnp.ones((flat.shape[0],), jnp.int32),
+                           self.top_k))
+        counts = {"expert_tokens": load, "expert_rows": load}
         if self.zero_experts:
             zero = chosen >= self.num_experts
             counts["zero_tokens"] = jnp.sum(
-                zero if mask is None else zero * marked[:, None],
+                zero if marked is None else zero & marked[:, None],
                 dtype=jnp.int32)
         return y.reshape(shape), counts

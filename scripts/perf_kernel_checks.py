@@ -30,6 +30,14 @@ Checks:
                       the plan counts of one traced
                       decode_block4_impl, and the block timed at empty and
                       at full slabs with the kernel and without it)
+  expert-mask         (RoutedExpertsLayer.forward's decode call, 16 lanes
+                      with 16 / 6 / 1 marked, at joyai-llm-flash's shape —
+                      top-8 of 256 at d 2048 / h 768 — and at
+                      longcat-flash-chat's — top-12 of 768 with 16 held at
+                      d 6144 / h 2048: kernel against the dense path;
+                      printed, not gated: microseconds a call, the experts
+                      it read, and what their bytes take at 819 GB/s — the
+                      baseline of ROADMAP S12 (c))
 
 Error metric: max|a−b| / (max|b| + 1e-30) over fwd outputs and each
 gradient; thresholds sized for bf16 matmul noise (attention) and f32
@@ -385,6 +393,77 @@ def check_slab_stream(rows):
               f"({abs(full - empty) / empty:.2%} apart)", flush=True)
 
 
+#: the two drawn configurations' expert layers as their cells serve them
+EXPERT_LAYERS = {
+    "joyai-llm-flash": dict(
+        n_in=2048, expert_hidden=768, num_experts=256, top_k=8,
+        routed_scaling=2.5, shared_experts=1),
+    "longcat-flash-chat": dict(
+        n_in=6144, expert_hidden=2048, num_experts=512, zero_experts=256,
+        experts_held=16, top_k=12, score_function="softmax",
+        renormalize=False, routed_scaling=6.0, shared_experts=0),
+}
+
+
+def expert_mask_call(name, marked, lanes=16, loops=50):
+    """The expert layer of ``name`` on ``lanes`` rows, the first ``marked``
+    of them marked, bf16: (largest error of the kernel's output against the
+    dense path's relative to its largest value, microseconds a call, experts
+    read a call, microseconds their weights' bytes take). The ``loops``
+    calls run inside one program, each on rows of its own (so every call
+    routes afresh) plus a thousandth of what the call before gave (so they
+    run in turn)."""
+    from benchmark.harness.manifest import peaks
+    from deeplearning4j_tpu.nn import helpers
+    from deeplearning4j_tpu.nn.conf.layers import RoutedExpertsLayer
+    hbm = peaks(jax.devices()[0].device_kind)["hbm_bytes_per_s"]
+    kw = EXPERT_LAYERS[name]
+    layer = RoutedExpertsLayer(n_out=kw["n_in"], **kw)
+    params = layer.init_params(jax.random.PRNGKey(0), jnp.bfloat16)
+    xs = jax.random.normal(jax.random.PRNGKey(1),
+                           (loops, lanes, 1, layer.n_in), jnp.bfloat16)
+    x = xs[0]
+    mask = (jnp.arange(lanes) < marked)[:, None]
+    held = slice(layer.first_expert, layer.first_expert + layer._held())
+
+    def once():           # a new function a path: the path is picked in trace
+        return jax.jit(lambda p, x: layer.forward(p, None, x, mask=mask)[0])
+    got = once()(params, x)
+    helpers.disable_helper("routed_experts")
+    try:
+        want = once()(params, x)
+    finally:
+        helpers.enable_helper("routed_experts")
+
+    @jax.jit
+    def chained(p, xs):
+        def body(carry, x):
+            before, read = carry
+            y, counts = layer.forward(p, None, x + 1e-3 * before, mask=mask)
+            return (y, read + jnp.sum(counts["expert_rows"][held] > 0)), None
+        return jax.lax.scan(body, (jnp.zeros_like(xs[0]), jnp.int32(0)),
+                            xs)[0]
+    seconds = _timed(chained, params, xs, calls=3) / loops
+    read = float(chained(params, xs)[1]) / loops
+    expert_bytes = 3 * layer.n_in * layer.expert_hidden * 2
+    return (rel(got, want), seconds * 1e6, read,
+            read * expert_bytes / hbm * 1e6)
+
+
+def check_expert_mask(rows):
+    for name in EXPERT_LAYERS:
+        errs = {}
+        for marked in (16, 6, 1):
+            errs[f"m{marked}"], us, read, bytes_us = expert_mask_call(
+                name, marked)
+            print(f"  expert-mask, {name}: {marked} of 16 lanes marked: "
+                  f"{us:.1f} us a call, {read:.2f} experts read a call, "
+                  f"their bytes {bytes_us:.1f} us at the memory's rate; "
+                  f"max|kernel-dense|={errs[f'm{marked}']:.2e}", flush=True)
+        # bf16 rows of O(1) summed over top-k experts in two orders
+        rows.append((f"expert-mask@{name}", errs, 3e-2))
+
+
 def main():
     from deeplearning4j_tpu.kernels.pallas_attention import \
         pallas_flash_attention
@@ -421,6 +500,7 @@ def main():
     check_layernorm(rows)
     check_decode_block_layout(rows)
     check_slab_stream(rows)
+    check_expert_mask(rows)
 
     ok_all = True
     print(f"{'check':22s} {'threshold':>9s}  errors")

@@ -52,11 +52,11 @@ def expert_net():
 @pytest.mark.parametrize("block,sentinel", [(1, True), (4, False)])
 def test_paged_and_slab_blocks_of_an_expert_model_read_back_the_same(
         expert_net, block, sentinel):
-    """Same requests, same greedy tokens, so the same assignments: the
-    ``moe_*`` counters of the two engines are equal — all four with every
-    lane held to the end, the three of alive lanes with a lane idle at times
-    (an idle lane still routes, over what its cache holds: stale rows on
-    the slab, the null page in a pool, and ``moe_experts_read`` sees it).
+    """Same requests, same greedy tokens, so the same assignments: ALL the
+    ``moe_*`` counters of the two engines are equal, with every lane held to
+    the end and with a lane idle at times — an idle lane still routes, over
+    what its cache holds (stale rows on the slab, the null page in a pool),
+    but its choices reach no expert, so what is read is what was hit.
     A plain slab engine at K = 1 takes ``decode_step_impl``, which counts
     nothing (ROADMAP Design 4); with the sentinel on, both engines take a
     block of one, and the verdict column sits between tokens and counters."""
@@ -64,7 +64,7 @@ def test_paged_and_slab_blocks_of_an_expert_model_read_back_the_same(
     assert dec.moe_names == ["ffn1"]
     want = [generate(expert_net, p, 9, temperature=0, bucket=T_MAX)
             for p in PROMPTS]
-    for slots, equal in ((3, MOE_COUNTERS), (2, MOE_COUNTERS[:3])):
+    for slots in (3, 2):
         stats = {}
         for paged in (False, True):
             eng = SlotGenerationEngine(
@@ -76,9 +76,9 @@ def test_paged_and_slab_blocks_of_an_expert_model_read_back_the_same(
                 np.testing.assert_array_equal(r.result(0), w)
             stats[paged] = eng.stats()
         assert stats[True]["moe_assignments"] > 0
-        for k in equal:
+        for k in MOE_COUNTERS:
             assert stats[True][k] == stats[False][k], (slots, k)
-        assert stats[True]["moe_experts_read"] >= \
+        assert stats[True]["moe_experts_read"] == \
             stats[True]["moe_experts_hit"]
 
 

@@ -22,6 +22,7 @@ from deeplearning4j_tpu.models import (SlotGenerationEngine,
 from deeplearning4j_tpu.models.generation import MOE_COUNTERS
 from deeplearning4j_tpu.nn.conf.layers import (LatentAttentionLayer,
                                                RoutedExpertsLayer, Window)
+from deeplearning4j_tpu.nn.conf.layers.attention import gated_ffn
 from deeplearning4j_tpu.nn.graph import ComputationGraph
 
 VOCAB, T_MAX = 97, 64
@@ -149,16 +150,16 @@ def test_engine_is_token_identical_with_steady_compiles_and_counters(net, dec):
     lanes = asg // 2                         # top_k 2: alive lanes, summed
     assert asg % 2 == 0 and sl <= lanes <= 2 * sl      # 1..2 alive lanes
     assert 2 * sl <= hit <= asg              # >= top_k distinct a layer
-    # what a step computed: every lane of the block, stopped ones too
-    assert hit <= read <= 2 * 2 * 2 * stats["decode_steps"]  # L x B x k
+    # what a step computed: a stopped lane's choices reach no expert
+    assert read == hit
     assert eng.latent_cache_bytes_per_token == 3 * 20 * 4
 
 
 def test_expert_counters_are_exact_on_hand_made_routing():
     """Router weights of zero and a bias that orders the experts make every
     token choose experts 5 and 2: with B alive lanes a step-layer adds B x 2
-    assignments and 2 experts hit; the stopped lane chooses the same two, so
-    2 experts are read."""
+    assignments and 2 experts hit, and those 2 are what is read: the stopped
+    lane's choices are cast out before the experts."""
     net = _net(num_layers=2)
     p = net.params["ffn1"]
     p["Wr"] = jnp.zeros_like(p["Wr"])
@@ -176,12 +177,11 @@ def test_expert_counters_are_exact_on_hand_made_routing():
         "moe_step_layers": 4, "moe_assignments": 4 * 2 * 2,
         "moe_experts_hit": 4 * 2, "moe_experts_read": 4 * 2,
         "moe_zero_assignments": 0, "moe_held_assignments": 4 * 2 * 2}
-    # every lane stopped: nothing is counted for a request, and the block
-    # still computes (and reads) what its lanes route to
+    # every lane stopped: nothing is counted for a request, and no expert
+    # is read
     out, *_ = dec.decode_block(caches, nxt, lens, block_size=4,
                                stopped=np.ones(3, bool))
-    assert dec.split_block(np.asarray(out))[1].tolist() == [0, 0, 0, 4 * 2,
-                                                            0, 0]
+    assert dec.split_block(np.asarray(out))[1].tolist() == [0] * 6
 
 
 def test_model_without_experts_reads_back_what_it_did():
@@ -242,7 +242,10 @@ def test_routed_expert_kernel_equals_the_dense_path(n, k, experts, first,
     np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5)
 
 
-def test_mask_counts_tokens_and_changes_no_output():
+def test_mask_keeps_unmarked_tokens_out_of_the_experts():
+    """Marked rows are bit for bit the unmasked forward's; an unmarked row
+    is its shared expert and nothing else; the rows computed are the marked
+    tokens' (both counts), and every token's without a mask."""
     layer = RoutedExpertsLayer(n_in=16, n_out=16, num_experts=8, top_k=2,
                                expert_hidden=8, shared_experts=1)
     p = layer.init_params(jax.random.PRNGKey(0))
@@ -250,12 +253,16 @@ def test_mask_counts_tokens_and_changes_no_output():
     mask = jnp.asarray([[1.0], [0.0], [1.0]])
     y, st = layer.forward(p, layer.init_state(), x, mask=mask)
     full, st_all = layer.forward(p, layer.init_state(), x)
+    np.testing.assert_array_equal(y[jnp.asarray([0, 2])],
+                                  full[jnp.asarray([0, 2])])
+    np.testing.assert_allclose(
+        y[1], gated_ffn(x[1], p["Sg"], p["Su"], p["Sd"]), atol=1e-6)
+    assert np.abs(np.asarray(full[1] - y[1])).max() > 1e-3
     assert int(st["expert_tokens"].sum()) == 2 * 2
-    assert int(st["expert_rows"].sum()) == 3 * 2      # computed all the same
+    np.testing.assert_array_equal(st["expert_rows"], st["expert_tokens"])
     assert int(st_all["expert_tokens"].sum()) == 3 * 2
     np.testing.assert_array_equal(st_all["expert_rows"],
                                   st_all["expert_tokens"])
-    np.testing.assert_array_equal(y, full)
 
 
 def test_long_inputs_walked_in_blocks_give_what_one_pass_gives(net,

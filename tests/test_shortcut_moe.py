@@ -116,7 +116,7 @@ def test_engine_is_token_identical_and_counts_the_three_kinds_of_choice(
     sl, asg, hit, read, zero, held = (stats[k] for k in MOE_COUNTERS)
     assert sl > 0 and asg % 3 == 0 and sl <= asg // 3 <= 2 * sl
     assert held == asg - zero                # every routed expert is held
-    assert 0 < zero < asg and hit <= held and hit <= read
+    assert 0 < zero < asg and hit <= held and hit == read
 
 
 def test_expert_counters_are_exact_on_hand_made_routing():
@@ -146,8 +146,8 @@ def test_expert_counters_are_exact_on_hand_made_routing():
         "moe_zero_assignments": steps * 2, "moe_held_assignments": steps * 2}
     out, *_ = dec.decode_block(caches, nxt, lens, block_size=4,
                                stopped=np.ones(3, bool))
-    assert dec.split_block(np.asarray(out))[1].tolist() == [
-        0, 0, 0, steps, 0, 0]
+    # every lane stopped: no choice reaches an expert, none is read
+    assert dec.split_block(np.asarray(out))[1].tolist() == [0] * 6
 
 
 # ------------------------------------- the expert layer's new fields, each
@@ -210,16 +210,30 @@ def test_each_routing_field_follows_its_equation(over):
 
 
 def test_a_mask_counts_zero_choices_of_marked_tokens_only():
+    """... and keeps the others out of the routed experts: a marked row is
+    bit for bit the unmasked forward's, an unmarked row is its zero-compute
+    share ``(sum of its gates there) x`` alone (no shared expert here), and
+    the rows computed are the marked tokens'."""
     layer, p = _layer(zero_experts=3, score_function="softmax",
                       renormalize=False)
+    p["b"] = p["b"].at[7].add(1.0)      # every token chooses this identity
     x = jax.random.normal(jax.random.PRNGKey(2), (4, 1, 16))
     mask = jnp.asarray([[1.0], [0.0], [1.0], [0.0]])
     y, st = layer.forward(p, layer.init_state(), x, mask=mask)
     full, st_all = layer.forward(p, layer.init_state(), x)
-    np.testing.assert_array_equal(y, full)
+    on, off = jnp.asarray([0, 2]), jnp.asarray([1, 3])
+    np.testing.assert_array_equal(y[on], full[on])
+    chosen, gates = layer.route(p, x[:, 0])
+    share = jnp.sum(jnp.where(chosen >= layer.num_experts, gates, 0.0), -1)
+    assert float(share.min()) > 0
+    np.testing.assert_allclose(y[off, 0], share[off, None] * x[off, 0],
+                               atol=1e-6)
+    assert np.abs(np.asarray(full[off] - y[off])).max() > 1e-3
     assert int(st["expert_tokens"].sum() + st["zero_tokens"]) == 2 * 3
+    np.testing.assert_array_equal(st["expert_rows"], st["expert_tokens"])
     assert int(st_all["expert_tokens"].sum() + st_all["zero_tokens"]) == 12
-    np.testing.assert_array_equal(st["expert_rows"], st_all["expert_rows"])
+    np.testing.assert_array_equal(st_all["expert_rows"],
+                                  st_all["expert_tokens"])
 
 
 # ------------------------------------------------ the two latent scales
