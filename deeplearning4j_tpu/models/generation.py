@@ -51,6 +51,7 @@ import numpy as np
 from jax.sharding import NamedSharding
 
 from ..nn.conf.layers import Window
+from ..nn.conf.layers.feedforward import head_params
 from ..nn.graph.computation_graph import scoped
 from ..nn.graph.vertices import LayerVertex
 from ..nn.helpers import attention_spmd
@@ -134,12 +135,22 @@ _ENGINE_COUNTERS = {
     "moe_zero_assignments": "alive lanes' choices of zero-compute experts",
     "moe_held_assignments": "alive lanes' choices of experts held here "
                             "(all of moe_assignments where every expert is)",
+    # state-space layers (SSM_COUNTERS ride each block's one readback)
+    "ssm_step_layers": "decode steps x state-space layers x ALIVE lanes: "
+                       "the state updates requests needed",
+    "ssm_lane_layers": "decode steps x state-space layers x every lane: "
+                       "the state updates a block computed",
+    "ssm_state_resets": "slot states overwritten by admission (admitted "
+                        "requests x state-space layers)",
 }
 #: the columns a decode block of a model with expert layers appends to its
 #: token matrix, in this order (every row carries the same sums)
 MOE_COUNTERS = ("moe_step_layers", "moe_assignments", "moe_experts_hit",
                 "moe_experts_read", "moe_zero_assignments",
                 "moe_held_assignments")
+#: the columns a decode block of a model with state-space layers appends
+#: after those, in this order
+SSM_COUNTERS = ("ssm_step_layers", "ssm_lane_layers")
 #: unique per-engine metric label values (e0, e1, ...)
 _ENGINE_SEQ = itertools.count()
 
@@ -220,6 +231,11 @@ class TransformerDecoder:
         # block as MOE_COUNTERS columns; none, and every program is what it
         # was
         self.moe_names: List[str] = []
+        # vertices that keep sequence state of a FIXED size a slot (a
+        # state-space mixer): among attn_names; admission overwrites their
+        # slot whole, and their update counts leave a block as
+        # SSM_COUNTERS columns
+        self.state_names: List[str] = []
         embed = None
         for name in conf.topological_order:
             v = conf.vertices[name]
@@ -235,6 +251,8 @@ class TransformerDecoder:
                                      "causal — cannot decode "
                                      "autoregressively")
                 self.attn_names.append(name)
+                if getattr(v.layer, "fixed_state", False):
+                    self.state_names.append(name)
             elif hasattr(v.layer, "embed"):
                 embed, self._embed_name = v.layer, name
             elif getattr(v.layer, "counts_tokens", False):
@@ -271,18 +289,20 @@ class TransformerDecoder:
         self._row_shardings = None
         self._pool_shardings_cached = None   # paged-pool NamedShardings
         if mesh is not None:
-            if self.moe_names or self.latent_cache_bytes_per_token:
+            if self.moe_names or self.latent_cache_bytes_per_token or \
+                    self.state_names:
                 raise NotImplementedError(
-                    "a latent (compressed-KV) cache and routed experts "
-                    "have no layout under a mesh: SpecLayout has no latent "
-                    "or expert rule; decode them on one device")
+                    "a latent (compressed-KV) cache, routed experts and a "
+                    "state-space layer's state have no layout under a "
+                    "mesh: SpecLayout has no latent, expert or state rule; "
+                    "decode them on one device")
             from ..parallel.mesh import mesh_tag, validate_decode_mesh
             from ..parallel.spec_layout import (SpecLayout,
                                                 decoder_param_specs,
                                                 validate_param_specs)
             self._layout = spec_layout if spec_layout is not None \
                 else SpecLayout()
-            for name in self.attn_names:
+            for name in self.kv_names:
                 validate_decode_mesh(
                     mesh, num_heads=conf.vertices[name].layer.num_heads,
                     data_axis=self._layout.data_axis,
@@ -292,6 +312,11 @@ class TransformerDecoder:
             self._cache_sharding = NamedSharding(mesh,
                                                  self._layout.kv_cache())
             self._impl_suffix = "__m" + mesh_tag(mesh)
+
+    @property
+    def kv_names(self) -> List[str]:
+        """The sequence-state vertices whose cache is rows by position."""
+        return [n for n in self.attn_names if n not in self.state_names]
 
     # ------------------------------------------------------------ sharding
     @property
@@ -377,7 +402,7 @@ class TransformerDecoder:
         tp = 1 if self.mesh is None else \
             int(self.mesh.shape.get(self._layout.tp_axis, 1))
         return min(self.net.conf.vertices[n].layer.heads_per_row(tp)
-                   for n in self.attn_names)
+                   for n in self.kv_names)
 
     @property
     def latent_cache_bytes_per_token(self) -> int:
@@ -385,7 +410,7 @@ class TransformerDecoder:
         (each holds one ``[c_kv ; k_rope]`` row a token, nothing per
         head); 0 for a model whose cache is per-head k/v."""
         return sum(self.net.conf.vertices[n].layer.latent_bytes_per_token(
-            self.net.compute_dtype) for n in self.attn_names)
+            self.net.compute_dtype) for n in self.kv_names)
 
     def program_peak_bytes(self, impl_name: str) -> Optional[int]:
         """:func:`compiled_peak_bytes` of an impl that has been
@@ -460,7 +485,8 @@ class TransformerDecoder:
                     params[name], xs[0],
                     None if caches is None else caches[name], window)
             elif name == self.output_name and every:
-                logits = v.layer.preoutput(params[name], xs[0])
+                logits = v.layer.preoutput(head_params(conf, params, name),
+                                           xs[0])
             elif name == self.output_name:
                 h = xs[0]
                 if window.valid is not None:
@@ -469,7 +495,8 @@ class TransformerDecoder:
                     # a 32k vocab; [B, 1, V] is what sampling needs
                     idx = jnp.clip(window.valid - 1, 0)[:, None, None]
                     h = jnp.take_along_axis(h, idx, axis=1)
-                logits = v.layer.preoutput(params[name], h)[:, 0]
+                logits = v.layer.preoutput(head_params(conf, params, name),
+                                           h)[:, 0]
             elif name in counting:
                 acts[name], load = v.forward(
                     params[name], state[name], xs, train=False, rng=None,
@@ -554,25 +581,42 @@ class TransformerDecoder:
             jnp.sum(here)]).astype(jnp.int32)
 
     # graftlint: traced
-    def _block_columns(self, toks, fault, moe):
+    def _ssm_sums(self, stop):
+        """One decode step's SSM_COUNTERS int32: alive lanes and every lane,
+        each times the state-space layers."""
+        n = len(self.state_names)
+        return jnp.stack([jnp.sum(~stop) * n,
+                          jnp.int32(stop.shape[0] * n)]).astype(jnp.int32)
+
+    # graftlint: traced
+    def _block_columns(self, toks, fault, moe, ssm=None):
         """A decode block's ONE read-back matrix: its tokens [B, K], then
         the sentinel's verdict column (sentinel decoders), then the
-        MOE_COUNTERS sums, the same four in every row (models with expert
-        layers). A model with neither reads back [B, K], as ever."""
+        MOE_COUNTERS sums (models with expert layers), then the
+        SSM_COUNTERS sums (models with state-space layers), the same in
+        every row. A model with none reads back [B, K], as ever."""
         cols = [toks]
         if self.sentinel:
             cols.append(fault.astype(jnp.int32)[:, None])
-        if self.moe_names:
-            cols.append(jnp.broadcast_to(moe[None, :],
-                                         (toks.shape[0], moe.shape[0])))
+        for on, sums in ((self.moe_names, moe), (self.state_names, ssm)):
+            if on:
+                cols.append(jnp.broadcast_to(sums[None, :],
+                                             (toks.shape[0], sums.shape[0])))
         return toks if len(cols) == 1 else jnp.concatenate(cols, axis=1)
 
+    @property
+    def counter_names(self) -> Tuple[str, ...]:
+        """The counters a decode block's matrix ends in, in order."""
+        return (MOE_COUNTERS if self.moe_names else ()) + \
+            (SSM_COUNTERS if self.state_names else ())
+
     def split_block(self, host: np.ndarray):
-        """(matrix without the MOE_COUNTERS columns, their sums or None) of
-        a fetched decode-block matrix (see :meth:`_block_columns`)."""
-        if not self.moe_names:
+        """(matrix without the counter columns, their sums in the order of
+        :attr:`counter_names`, or None) of a fetched decode-block matrix
+        (see :meth:`_block_columns`)."""
+        n = len(self.counter_names)
+        if not n:
             return host, None
-        n = len(MOE_COUNTERS)
         return host[:, :-n], host[0, -n:]
 
     # graftlint: traced
@@ -749,7 +793,16 @@ class TransformerDecoder:
                                     c1[n][kk], i, 1, axis=0)[:, :, :tp],
                                 (slots[i], z, z, z))
                             for kk in caches[n]}
-                        for n in self.attn_names}
+                        for n in self.kv_names} | {
+                        n: merged[n] for n in self.state_names}
+                # a fixed-size state is OVERWRITTEN whole, so that the
+                # slot's last request leaves nothing behind: one scatter a
+                # leaf, not a write a row (pad rows repeat row 0, the same
+                # write twice; 36 layers x M rows unrolled took most of
+                # an admission program's lowering)
+                merged |= {n: {kk: caches[n][kk].at[slots].set(c1[n][kk])
+                               for kk in caches[n]}
+                           for n in self.state_names}
                 sel = self._select(logits, temps, key)
                 if self.sentinel:
                     # verdict rides the SAME readback as the sampled
@@ -890,13 +943,17 @@ class TransformerDecoder:
                 # same lines, which is what token-for-token parity
                 # paged-vs-slab rests on
                 def body(carry, _):
-                    caches, ids, pos, stop, fault, moe, step = carry
+                    caches, ids, pos, stop, fault, moe, step = carry[:7]
                     pos_c = jnp.minimum(pos, self.t_max - 1)
                     logits, caches, tally = self._walk(
                         params, state, caches, ids,
                         Window(start=pos_c, pages=ptables, alive=~stop))
                     if tally:
                         moe = moe + self._moe_sums(tally)
+                    # a model with state-space layers carries their counts
+                    # as one more element (none: the carry is as it was)
+                    ssm = (carry[7] + self._ssm_sums(stop),) \
+                        if self.state_names else ()
                     if self.sentinel:
                         fault = fault | self._fault_of(logits, stop)
                     kk = jax.random.fold_in(
@@ -910,14 +967,18 @@ class TransformerDecoder:
                     new_pos = jnp.where(stop, pos, pos + 1)
                     new_stop = stop | hit_eos | (new_pos >= self.t_max)
                     return (caches, nxt, new_pos, new_stop, fault, moe,
-                            step + 1), nxt
+                            step + 1) + ssm, nxt
                 fault0 = jnp.zeros_like(stopped)
                 moe0 = jnp.zeros(len(MOE_COUNTERS), jnp.int32)
-                (caches, ids, positions, stopped, fault, moe, _), toks = \
-                    jax.lax.scan(
-                        body, (caches, ids, positions, stopped, fault0,
-                               moe0, step0), None, length=k_steps)
-                out = self._block_columns(toks.T, fault, moe)
+                ssm0 = (jnp.zeros(len(SSM_COUNTERS), jnp.int32),) \
+                    if self.state_names else ()
+                carry, toks = jax.lax.scan(
+                    body, (caches, ids, positions, stopped, fault0, moe0,
+                           step0) + ssm0, None, length=k_steps)
+                caches, ids, positions, stopped, fault, moe = carry[:6]
+                out = self._block_columns(
+                    toks.T, fault, moe,
+                    carry[7] if self.state_names else None)
                 return out, ids, positions, stopped, caches
             # per-K name: the compile auditor attributes by __name__, and
             # two K values share every input shape — one shared name
@@ -1036,9 +1097,10 @@ class TransformerDecoder:
                 # CHAOS ONLY (device.corrupt_logits, slab path): poison
                 # one slot's cache CELL at an always-attended position —
                 # the next decode step's attention reads it and the
-                # logits go non-finite (NaN) or wrong (flip)
+                # logits go non-finite (NaN) or wrong (flip). A state
+                # layer has no positions: its slot's state goes whole
                 out = {}
-                for n in self.attn_names:
+                for n in self.kv_names:
                     out[n] = {}
                     for kk in caches[n]:
                         cell = caches[n][kk][slot, :, pos, :]
@@ -1047,7 +1109,15 @@ class TransformerDecoder:
                                            -cell)
                         out[n][kk] = \
                             caches[n][kk].at[slot, :, pos, :].set(poison)
-                return out
+                for n in self.state_names:
+                    out[n] = {}
+                    for kk in caches[n]:
+                        state = caches[n][kk][slot]
+                        poison = jnp.where(mode == 0,
+                                           jnp.full_like(state, jnp.nan),
+                                           -state)
+                        out[n][kk] = caches[n][kk].at[slot].set(poison)
+                return {n: out[n] for n in self.attn_names}
             fn = self._jit_sharded(corrupt_cache_impl,
                                    train_donate_argnums((0,)),
                                    in_specs=(csh, None, None, None),
@@ -1742,6 +1812,23 @@ class SlotGenerationEngine:
                 logit_bound=None if self._integrity is None
                 else self._integrity.logit_bound)
         self._sentinel_on = want_sentinel
+        if self.decoder.state_names:
+            # a state-space layer's cache is one fixed-size state a slot:
+            # it cannot be rewound to a position, shared between slots or
+            # filled a window at a time (ROADMAP R-M7)
+            missing = [what for what, asked in (
+                ("the paged KV pool (paged=True)", paged),
+                ("a prefix cache (paged=True, prefix_cache=True)",
+                 paged and prefix_cache),
+                ("a speculative drafter (speculative=True)", speculative),
+                ("chunked prefill (prefill_chunk)",
+                 prefill_chunk is not None)) if asked]
+            if missing:
+                raise ValueError(
+                    f"state-space layers {self.decoder.state_names} keep a "
+                    "fixed-size state a slot, which nothing can rewind, "
+                    "share or fill in windows yet: "
+                    + "; ".join(missing) + " (ROADMAP R-M7)")
         # chain-digest-keyed content checksums (recorded at prefix
         # registration, verified on hits/adopts at the sampled rate)
         self._kv_verifier = None
@@ -3420,6 +3507,8 @@ class SlotGenerationEngine:
                     return   # batch stays parked in _admitting; the
                              # quarantine/shutdown drain owns it now
                 self._m["prefills"].inc(m)
+                self._m["ssm_state_resets"].inc(
+                    m * len(self.decoder.state_names))
                 batch_no = self._m["prefill_batches"].inc()
             bid = tracing.next_block_id()
             # a decode block in flight: the prefill queues behind it on
@@ -4539,7 +4628,7 @@ class SlotGenerationEngine:
         toks_dev, snapshot, k, disp, qdepth, overlapped = block
         bid, t_disp, lanes = disp.block, disp.t0, len(snapshot)
         with self._seam(tracing.BLOCK_READBACK, bid, lanes, k) as rb:
-            host, moe = self.decoder.split_block(
+            host, counts = self.decoder.split_block(
                 device_fetch(toks_dev, tag="engine.decode"))
         t_ret = rb.t1
         fault_col = None
@@ -4559,8 +4648,8 @@ class SlotGenerationEngine:
                 return   # the drain owns the requests; recovery
                          # re-prefills and regenerates these tokens
             self._m["host_readbacks"].inc()
-            if moe is not None:
-                for name, n in zip(MOE_COUNTERS, moe):
+            if counts is not None:
+                for name, n in zip(self.decoder.counter_names, counts):
                     self._m[name].inc(int(n))
             emitted = 0
             for s, req in snapshot:

@@ -18,10 +18,11 @@ import numpy as np
 
 from ..nn.conf.config import NeuralNetConfiguration
 from ..nn.conf.layers import (GatedFeedForward, LatentAttentionLayer,
-                              LayerNormalization, RMSNormalization,
-                              RnnOutputLayer, RoutedExpertsLayer,
-                              SelfAttentionLayer, TokenAndPositionEmbedding,
-                              TokenEmbedding, TransformerFeedForward)
+                              LayerNormalization, Mamba2Layer,
+                              RMSNormalization, RnnOutputLayer,
+                              RoutedExpertsLayer, SelfAttentionLayer,
+                              TokenAndPositionEmbedding, TokenEmbedding,
+                              TransformerFeedForward)
 from ..nn.graph.computation_graph import ComputationGraph
 from ..nn.graph.vertices import ElementWiseVertex
 
@@ -223,6 +224,78 @@ def shortcut_moe_lm_conf(vocab_size: int, d_model: int, num_heads: int,
     g.add_layer("out",
                 RnnOutputLayer(n_in=d_model, n_out=vocab_size, loss="mcxent",
                                activation="softmax", has_bias=False), "lnf")
+    g.set_outputs("out")
+    return g.build()
+
+
+def hybrid_ssm_lm_conf(vocab_size: int, d_model: int, num_heads: int,
+                       num_kv_heads: int, layer_types, *, ffn_hidden: int,
+                       ssm_heads: int, ssm_head_dim: int, ssm_state: int,
+                       conv_kernel: int = 4, chunk_size: int = 256,
+                       attention_scale: float = 0.0,
+                       embedding_scale: float = 1.0,
+                       residual_scale: float = 1.0,
+                       logit_divisor: float = 1.0, eps: float = 1e-5,
+                       tie_embeddings: bool = True, max_length: int = 4096,
+                       learning_rate: float = 3e-4, seed: int = 42):
+    """ComputationGraphConfiguration for a causal LM of pre-RMSNorm blocks
+    whose mixer is, layer by layer as ``layer_types`` says, a Mamba-2
+    state-space layer (``"mamba"``: vertex ``ssm{i}``) or grouped-query
+    attention with no positional encoding (``"attention"``: ``attn{i}``,
+    ``num_kv_heads`` KV heads, logits scaled by ``attention_scale``, no
+    bias); every layer then a gated FFN of width ``ffn_hidden``:
+
+        h <- h + r * Mixer(RMSNorm(h));   h <- h + r * FFN(RMSNorm(h))
+
+    with ``r = residual_scale`` applied where the graph adds the branch
+    (``res{i}a`` / ``res{i}b``). A token-only embedding times
+    ``embedding_scale``; a final RMSNorm; the head tied to the embedding's
+    table (``tie_embeddings``) with its logits divided by
+    ``logit_divisor``. Norms and the Mamba gate norm use ``eps``."""
+    g = (NeuralNetConfiguration.Builder().seed(seed)
+         .learning_rate(learning_rate).updater("adam").weight_init("xavier")
+         .graph_builder()
+         .add_inputs("tokens"))
+    g.add_layer("embed", TokenEmbedding(n_in=vocab_size, n_out=d_model,
+                                        max_length=max_length,
+                                        multiplier=embedding_scale), "tokens")
+    norm = lambda: RMSNormalization(n_in=d_model, n_out=d_model, eps=eps)
+    add = lambda: ElementWiseVertex(op="add", branch_scale=residual_scale)
+    x = "embed"
+    for i, kind in enumerate(layer_types):
+        g.add_layer(f"ln{i}a", norm(), x)
+        if kind == "mamba":
+            mixer = f"ssm{i}"
+            layer = Mamba2Layer(n_in=d_model, n_out=d_model,
+                                num_heads=ssm_heads, head_dim=ssm_head_dim,
+                                state_size=ssm_state, conv_kernel=conv_kernel,
+                                chunk_size=chunk_size, eps=eps,
+                                activation="identity")
+        elif kind == "attention":
+            mixer = f"attn{i}"
+            layer = SelfAttentionLayer(n_in=d_model, n_out=d_model,
+                                       num_heads=num_heads,
+                                       num_kv_heads=num_kv_heads,
+                                       scale=attention_scale, causal=True,
+                                       bias=False, activation="identity")
+        else:
+            raise ValueError(f"layer {i}: unknown layer type {kind!r} "
+                             "(\"mamba\" or \"attention\")")
+        g.add_layer(mixer, layer, f"ln{i}a")
+        g.add_vertex(f"res{i}a", add(), x, mixer)
+        g.add_layer(f"ln{i}b", norm(), f"res{i}a")
+        g.add_layer(f"ffn{i}", GatedFeedForward(n_in=d_model, n_out=d_model,
+                                                hidden=ffn_hidden,
+                                                activation="identity"),
+                    f"ln{i}b")
+        g.add_vertex(f"res{i}b", add(), f"res{i}a", f"ffn{i}")
+        x = f"res{i}b"
+    g.add_layer("lnf", norm(), x)
+    g.add_layer("out",
+                RnnOutputLayer(n_in=d_model, n_out=vocab_size, loss="mcxent",
+                               activation="softmax", has_bias=False,
+                               tied_to="embed" if tie_embeddings else "",
+                               logit_divisor=logit_divisor), "lnf")
     g.set_outputs("out")
     return g.build()
 

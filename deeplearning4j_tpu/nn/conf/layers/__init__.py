@@ -15,6 +15,7 @@ from .attention import (SelfAttentionLayer, LayerNormalization,
                         TokenEmbedding, Window)
 from .latent_attention import LatentAttentionLayer
 from .experts import RoutedExpertsLayer
+from .state_space import Mamba2Layer
 from .variational import VariationalAutoencoder
 
 __all__ = [
@@ -28,5 +29,5 @@ __all__ = [
     "SelfAttentionLayer", "LayerNormalization",
     "TransformerFeedForward", "TokenAndPositionEmbedding",
     "RMSNormalization", "GatedFeedForward", "TokenEmbedding",
-    "LatentAttentionLayer", "RoutedExpertsLayer", "Window",
+    "LatentAttentionLayer", "RoutedExpertsLayer", "Window", "Mamba2Layer",
 ]

@@ -59,14 +59,24 @@ class Window:
 @register_config
 @dataclasses.dataclass
 class SelfAttentionLayer(BaseRecurrentLayerConf):
-    """Input [N, T, n_in] → [N, T, n_out]; n_out = num_heads * head_size."""
+    """Input [N, T, n_in] → [N, T, n_out]; n_out = num_heads * head_size.
+    ``num_kv_heads`` (0: ``num_heads``) groups the keys and values: query
+    head ``i`` reads KV head ``i // (num_heads // num_kv_heads)``.
+    ``scale`` (0: ``1/sqrt(head_size)``) multiplies the logits."""
     num_heads: int = 4
     head_size: int = 0            # inferred as n_out // num_heads
     causal: bool = False
     project_out: bool = True
+    num_kv_heads: int = 0
+    scale: float = 0.0
+    #: False: the output projection has no bias
+    bias: bool = True
 
     def _head_size(self) -> int:
         return self.head_size or max(self.n_out // self.num_heads, 1)
+
+    def _kv_heads(self) -> int:
+        return self.num_kv_heads or self.num_heads
 
     def get_output_type(self, it: InputType) -> InputType:
         return InputType.recurrent(self.n_out, it.timesteps)
@@ -74,35 +84,46 @@ class SelfAttentionLayer(BaseRecurrentLayerConf):
     def init_params(self, key, dtype=jnp.float32) -> Dict:
         hs = self._head_size()
         inner = self.num_heads * hs
+        kv_inner = self._kv_heads() * hs
         kq, kk, kv, ko = jax.random.split(key, 4)
         p = {"Wq": self._winit(kq, (self.n_in, inner), self.n_in, inner, dtype),
-             "Wk": self._winit(kk, (self.n_in, inner), self.n_in, inner, dtype),
-             "Wv": self._winit(kv, (self.n_in, inner), self.n_in, inner, dtype)}
+             "Wk": self._winit(kk, (self.n_in, kv_inner), self.n_in,
+                               kv_inner, dtype),
+             "Wv": self._winit(kv, (self.n_in, kv_inner), self.n_in,
+                               kv_inner, dtype)}
         if self.project_out:
             p["Wo"] = self._winit(ko, (inner, self.n_out), inner, self.n_out,
                                   dtype)
-            p["bo"] = jnp.zeros((self.n_out,), dtype)
+            if self.bias:
+                p["bo"] = jnp.zeros((self.n_out,), dtype)
         return p
 
     def regularizable(self):
         return ("Wq", "Wk", "Wv", "Wo")
 
     def _project_qkv(self, params, x):
-        """x [N, T, n_in] → (q, k, v) each [N, T, H, Dh]."""
+        """x [N, T, n_in] → q [N, T, H, Dh], k and v [N, T, H_kv, Dh]."""
         n, t, _ = x.shape
         hcount, hs = self.num_heads, self._head_size()
         q = (x @ params["Wq"]).reshape(n, t, hcount, hs)
-        k = (x @ params["Wk"]).reshape(n, t, hcount, hs)
-        v = (x @ params["Wv"]).reshape(n, t, hcount, hs)
+        k = (x @ params["Wk"]).reshape(n, t, self._kv_heads(), hs)
+        v = (x @ params["Wv"]).reshape(n, t, self._kv_heads(), hs)
         return q, k, v
 
     # graftlint: traced
     def _attend(self, q, k, v, mask, dtype):
         """Full [N, T, H, Dh] attention through the helper seam (flash /
         short-T Pallas kernels) with the materialized-softmax path as the
-        always-available fallback. Returns [N, T, H, Dh]."""
+        always-available fallback. Grouped KV heads are repeated to the
+        query heads and an explicit ``scale`` rides on q (the kernels apply
+        ``1/sqrt(Dh)``). Returns [N, T, H, Dh]."""
         hs = self._head_size()
         t = q.shape[1]
+        rep = self.num_heads // self._kv_heads()
+        if rep > 1:
+            k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
+        if self.scale:
+            q = q * jnp.asarray(self.scale * math.sqrt(hs), q.dtype)
         helper = get_helper("attention")
         out = helper(self, q, k, v, mask) if helper is not None else None
         if out is None:
@@ -127,7 +148,9 @@ class SelfAttentionLayer(BaseRecurrentLayerConf):
         n, t = out.shape[:2]
         out = out.reshape(n, t, self.num_heads * self._head_size())
         if self.project_out:
-            out = out @ params["Wo"] + params["bo"][None, None, :]
+            out = out @ params["Wo"]
+            if self.bias:
+                out = out + params["bo"][None, None, :]
         return self.activation_fn()(out)
 
     def forward(self, params, state, x, *, train=False, rng=None, mask=None):
@@ -188,13 +211,13 @@ class SelfAttentionLayer(BaseRecurrentLayerConf):
     def init_cache(self, batch: int, t_max: int, dtype=jnp.float32,
                    sharding=None) -> Dict:
         """Preallocated decode cache: {"k", "v"} each
-        [B, H/g, T_max, g·Dh] — ``g`` heads side by side in one row, so
+        [B, H_kv/g, T_max, g·Dh] — ``g`` KV heads side by side in one row, so
         that the minor dimension is a whole 128-lane row and the decode
         programs update and read the slab in the layout it is stored in
         (``g`` from :meth:`heads_per_row`; 1 keeps [B, H, T_max, Dh]).
-        Head ``h`` lives in row group ``h // g`` at lanes
+        KV head ``h`` lives in row group ``h // g`` at lanes
         ``[(h % g)·Dh, (h % g + 1)·Dh)``. Every reader and writer takes
-        ``g`` from the cache it is handed (``H // shape[1]``), so the one
+        ``g`` from the cache it is handed (``shape[3] // Dh``), so the one
         decision is made here.
         ``sharding`` (a NamedSharding, slots over data / head groups over
         tp) places the buffers distributed at birth — the cache is the
@@ -208,7 +231,7 @@ class SelfAttentionLayer(BaseRecurrentLayerConf):
                 and sharding.spec[1] is not None:
             tp = sharding.mesh.shape[sharding.spec[1]]
         g = self.heads_per_row(tp)
-        shape = (batch, self.num_heads // g, t_max, g * self._head_size())
+        shape = (batch, self._kv_heads() // g, t_max, g * self._head_size())
         if sharding is not None:
             # allocate UNDER the sharding: zeros-then-device_put would
             # materialize the full buffer on one device first — the
@@ -258,28 +281,37 @@ class SelfAttentionLayer(BaseRecurrentLayerConf):
     # graftlint: traced
     def _slab_attend(self, q, ck, cv, qpos):
         """Length-masked attention of a window's queries over the slab:
-        q [B, C, H, Dh], ck/cv [B, H/g, T, g·Dh], ``qpos`` [B, C] the
+        q [B, C, H, Dh], ck/cv [B, H_kv/g, T, g·Dh], ``qpos`` [B, C] the
         absolute position of each query (it attends cells ``<= qpos``).
-        Both contractions run over whole rows: the logits of a head
-        group are ``K_row[T, g·Dh] · Qblk[g·Dh, g]`` with ``Qblk``
-        block-diagonal (head j's query in lanes [j·Dh, (j+1)·Dh), zeros
-        elsewhere — the zeros contribute exact 0.0), the weighted sum is
-        ``P[g, T] · V_row[T, g·Dh]`` of which head j keeps its own Dh
-        lanes. f32 logits and softmax, every position under the mask;
-        ``g = 1`` is plain ``bqhd,bhtd->bhqt``. The ``slab_attention``
+        Both contractions run over whole rows: the logits of a row group
+        are ``K_row[T, g·Dh] · Qblk[g·Dh, G]`` over its ``G = H/(H_kv/g)``
+        query heads with ``Qblk`` block-diagonal (query head j reads KV
+        head ``j // rep`` of the row, ``rep = H/H_kv``: its query in lanes
+        [(j//rep)·Dh, (j//rep+1)·Dh), zeros elsewhere — the zeros
+        contribute exact 0.0), the weighted sum is ``P[G, T] · V_row[T,
+        g·Dh]`` of which head j keeps its KV head's Dh lanes. f32 logits
+        and softmax, every position under the mask; ``g = 1`` is plain
+        ``bqhd,bhtd->bhqt`` (every query head of a group reads its one KV
+        head). The ``slab_attention``
         helper (kernels/slab_attention.py on the TPU: K and V streamed in
         position tiles, an online softmax across them) takes the call
         where it serves the shapes; the einsum body below is the
         always-available path. Returns [B, C, H, Dh]."""
         b, c, h, hs = q.shape
         hg = ck.shape[1]
-        g = h // hg
-        scale = 1.0 / math.sqrt(hs)          # math.sqrt: GL004 (x64)
+        g = h // hg                          # query heads of a row group
+        kv_g = ck.shape[3] // hs             # KV heads of a row
+        rep = g // kv_g                      # query heads of a KV head
+        # math.sqrt: GL004 (x64)
+        scale = self.scale or 1.0 / math.sqrt(hs)
         qg = q.reshape(b, c, hg, g, 1, hs)
-        if g > 1:
-            own = jnp.eye(g, dtype=q.dtype)[None, None, None, :, :, None]
+        if kv_g > 1:
+            own = jnp.eye(kv_g, dtype=q.dtype)
+            if rep > 1:
+                own = jnp.repeat(own, rep, axis=0)
+            own = own[None, None, None, :, :, None]
             qg = qg * own                    # [B, C, H/g, g, g, Dh]
-        qblk = qg.reshape(b, c, hg, g, g * hs)
+        qblk = qg.reshape(b, c, hg, g, kv_g * hs)
         helper = get_helper("slab_attention")
         rows = helper(self, qblk, ck, cv, qpos, scale) \
             if helper is not None else None
@@ -295,10 +327,18 @@ class SelfAttentionLayer(BaseRecurrentLayerConf):
             probs = jax.nn.softmax(logits, axis=-1)           # f32
             rows = jnp.einsum("bgjqt,bgtl->bqgjl", probs.astype(cv.dtype),
                               cv)
-        if g > 1:
-            # head j's own lanes of its row: the diagonal blocks
+        if kv_g > 1 and rep == 1:
+            # head j's own lanes of its row: the diagonal blocks. The branch
+            # below computes the same with rep = 1; this one is kept only so
+            # that the MHA models (gpt2-large) lower to the text they did
+            # before grouped heads (scripts/program_fingerprints.py checks it)
             rows = jnp.diagonal(rows.reshape(b, c, hg, g, g, hs),
                                 axis1=3, axis2=4)    # [B, C, H/g, Dh, g]
+            rows = jnp.moveaxis(rows, -1, 3)
+        elif kv_g > 1:
+            # query head (j, r) keeps the lanes of its KV head j
+            rows = jnp.diagonal(rows.reshape(b, c, hg, kv_g, rep, kv_g, hs),
+                                axis1=3, axis2=5)  # [B, C, hg, rep, Dh, kv_g]
             rows = jnp.moveaxis(rows, -1, 3)
         return rows.reshape(b, c, h, hs)
 
@@ -416,6 +456,10 @@ class SelfAttentionLayer(BaseRecurrentLayerConf):
         if not self.causal:
             raise ValueError("KV-cache decoding needs causal=True "
                              "(autoregressive attention)")
+        if self._kv_heads() != self.num_heads or self.scale:
+            raise NotImplementedError(
+                "the paged pool has no grouped KV heads and no explicit "
+                "scale yet (ROADMAP R-M2): serve this layer from the slab")
         hs = self._head_size()
         shape = (num_pages, self.num_heads, page_size, hs)
         if sharding is not None:
@@ -772,9 +816,17 @@ class TokenAndPositionEmbedding(BaseRecurrentLayerConf):
 class TokenEmbedding(BaseRecurrentLayerConf):
     """Token ids [N, T] → embeddings [N, T, n_out] and nothing else: a
     lookup with no position table, for models whose positions enter inside
-    attention (rotary). ``max_length`` is only the context the model
-    declares — what bounds a decoder's ``t_max``."""
+    attention (rotary) or nowhere. ``max_length`` is only the context the
+    model declares — what bounds a decoder's ``t_max``. ``multiplier``
+    scales the rows looked up."""
     max_length: int = 512
+    multiplier: float = 1.0
+
+    # graftlint: traced
+    def _scaled(self, rows):
+        if self.multiplier == 1.0:
+            return rows
+        return rows * jnp.asarray(self.multiplier, rows.dtype)
 
     def get_output_type(self, it: InputType) -> InputType:
         return InputType.recurrent(self.n_out, it.timesteps)
@@ -787,8 +839,8 @@ class TokenEmbedding(BaseRecurrentLayerConf):
         ids = x.astype(jnp.int32)
         if ids.ndim == 3:              # one-hot [N, T, V]
             ids = jnp.argmax(ids, axis=-1)
-        return self.maybe_dropout(params["W"][ids], train=train,
-                                  rng=rng), state
+        return self.maybe_dropout(self._scaled(params["W"][ids]),
+                                  train=train, rng=rng), state
 
     # graftlint: traced
     def embed(self, params, ids, window: Window):
@@ -796,5 +848,6 @@ class TokenEmbedding(BaseRecurrentLayerConf):
         if window.start is None:
             return self.forward(params, None, ids)[0]
         if window.valid is None:
-            return params["W"][jnp.asarray(ids, jnp.int32).reshape(-1)][:, None]
-        return params["W"][jnp.asarray(ids, jnp.int32)]
+            return self._scaled(
+                params["W"][jnp.asarray(ids, jnp.int32).reshape(-1)][:, None])
+        return self._scaled(params["W"][jnp.asarray(ids, jnp.int32)])

@@ -46,8 +46,16 @@ class OutputLayer(DenseLayer):
     #: False: a projection with no bias (the head of an RMSNorm decoder);
     #: such a head takes the materialized loss, not the fused sparse CE
     has_bias: bool = True
+    #: a vertex name: the head holds no weights and reads that vertex's
+    #: table ``W`` [n_out, n_in] transposed (a head tied to the embedding);
+    #: the graph hands it over as ``params["W_tied"]`` (:func:`head_params`)
+    tied_to: str = ""
+    #: logits divided by this (a tied head's ``logits_scaling``)
+    logit_divisor: float = 1.0
 
     def init_params(self, key, dtype=jnp.float32) -> Dict:
+        if self.tied_to:
+            return {}
         p = super().init_params(key, dtype)
         if not self.has_bias:
             del p["b"]
@@ -59,6 +67,11 @@ class OutputLayer(DenseLayer):
                             self.activation or "identity", mask, average)
 
     def preoutput(self, params, x):
+        if self.tied_to:
+            out = jnp.einsum("...d,vd->...v", x, params["W_tied"])
+            if self.logit_divisor != 1.0:
+                out = out / jnp.asarray(self.logit_divisor, out.dtype)
+            return out
         if not self.has_bias:
             return x @ params["W"]
         return x @ params["W"] + chan(params["b"], x.ndim)
@@ -269,3 +282,13 @@ class CenterLossOutputLayer(OutputLayer):
         sums = labels.T @ diff                               # [nOut, nIn]
         new_centers = centers + self.alpha * sums / counts[:, None]
         return loss, {"centers": new_centers}
+
+
+def head_params(conf, params: Dict, name: str) -> Dict:
+    """What vertex ``name``'s layer reads as its parameters: its own, or —
+    for a head tied to another vertex's table (``OutputLayer.tied_to``) —
+    that table under ``W_tied`` (one array, held once, trained by both)."""
+    tied = getattr(getattr(conf.vertices[name], "layer", None), "tied_to", "")
+    if not tied:
+        return params[name]
+    return {"W_tied": params[tied]["W"]}

@@ -24,6 +24,7 @@ from ...ops.dataset import DataSet, MultiDataSet
 from ...ops.updaters import make_updater, normalize_gradient, schedule_lr
 from .fusion import build_fusion_plan
 from .graph_config import ComputationGraphConfiguration
+from ..conf.layers.feedforward import head_params
 from .vertices import LayerVertex
 
 
@@ -194,11 +195,13 @@ class ComputationGraph:
                 new_state[name] = vstate
                 if name in skip_preoutput:
                     continue            # projection fused into the loss
-                pre = v.layer.preoutput(params[name], x)
+                pre = v.layer.preoutput(head_params(self.conf, params, name),
+                                        x)
                 preouts[name] = pre
                 acts[name] = v.layer.activation_fn()(pre)
             else:
-                y, nstate = v.forward(params[name], vstate, xs,
+                y, nstate = v.forward(head_params(self.conf, params, name),
+                                      vstate, xs,
                                       train=train, rng=vrng, masks=ms)
                 acts[name] = y
                 new_state[name] = nstate

@@ -98,8 +98,11 @@ class MergeVertex(GraphVertexConf):
 @register_config
 @dataclasses.dataclass
 class ElementWiseVertex(GraphVertexConf):
-    """Pointwise add/subtract/product/average/max (reference ElementWiseVertex)."""
+    """Pointwise add/subtract/product/average/max (reference ElementWiseVertex).
+    ``branch_scale`` multiplies every input after the first of an ``add``
+    (a residual branch scaled where the graph adds it)."""
     op: str = "add"
+    branch_scale: float = 1.0
 
     def forward(self, params, state, inputs, *, train=False, rng=None,
                 masks=None):
@@ -107,6 +110,8 @@ class ElementWiseVertex(GraphVertexConf):
         if op == "add":
             out = inputs[0]
             for x in inputs[1:]:
+                if self.branch_scale != 1.0:
+                    x = x * jnp.asarray(self.branch_scale, x.dtype)
                 out = out + x
         elif op == "subtract":
             out = inputs[0] - inputs[1]

@@ -99,6 +99,9 @@ _DEFAULT_PROVIDERS: Dict[str, str] = {
     # position tiles (TPU only; the layer's einsum body elsewhere and for
     # the shapes the kernel declines)
     "slab_attention": "deeplearning4j_tpu.kernels.slab_attention",
+    # a Mamba-2 decode step's state update, each slot's state read once and
+    # written in place (TPU only; the layer's jnp body elsewhere)
+    "ssm_update": "deeplearning4j_tpu.kernels.ssm_update",
     # "lstm" is deliberately NOT a default provider: honest r2 measurements
     # (BASELINE.md) show XLA's scan lowering beats the Pallas kernel at
     # char-RNN shapes in both f32 (11.5 vs 12.5 ms/step) and bf16 (8.0 vs

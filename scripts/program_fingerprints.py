@@ -14,12 +14,14 @@ texts themselves, to ``diff`` the ones that differ.
 
 Models: GPT-2-shaped in float32 (heads too narrow to pack) and in bfloat16
 with two 64-wide heads to a cache row; latent attention + routed experts;
-plain attention with one routed-experts layer. Each with the sentinel off
-and on; the paged programs where the model has a paged pool. Programs:
-``prefill``, ``step``, ``prefill_slots``, ``("block", 1 | 4)``, ``("chunk",
-8)``, ``("verify", 4)``, ``paged_prefill``, ``("paged_block", 1 | 4)``,
-``("paged_verify", 4)`` from the signatures the cost seam records at one
-dispatch, and ``recompute`` (jitted outside ``_fn``)."""
+plain attention with one routed-experts layer; the shortcut-connected
+double block. Each with the sentinel off and on; the paged programs where
+the model has a paged pool. Programs: ``prefill``, ``step``,
+``prefill_slots``, ``("block", 1 | 4)``, ``("chunk", 8)``, ``("verify",
+4)``, ``paged_prefill``, ``("paged_block", 1 | 4)``, ``("paged_verify",
+4)`` from the signatures the cost seam records at one dispatch, and
+``recompute`` (jitted outside ``_fn``); and the training step of the
+bfloat16 GPT-2 on sparse labels (``gpt2-bf16-g2|train``)."""
 
 import hashlib
 import json
@@ -33,7 +35,8 @@ import jax.numpy as jnp                                       # noqa: E402
 import numpy as np                                            # noqa: E402
 
 from deeplearning4j_tpu.models import (                       # noqa: E402
-    TransformerDecoder, latent_moe_lm_conf, transformer_lm_conf)
+    TransformerDecoder, latent_moe_lm_conf, shortcut_moe_lm_conf,
+    transformer_lm_conf)
 from deeplearning4j_tpu.nn.conf.layers import RoutedExpertsLayer  # noqa: E402
 from deeplearning4j_tpu.nn.graph import ComputationGraph     # noqa: E402
 
@@ -60,10 +63,30 @@ def mixed():
     return ComputationGraph(conf).init()
 
 
+def shortcut():
+    return ComputationGraph(shortcut_moe_lm_conf(
+        V, 32, 4, 2, q_rank=24, kv_rank=16, nope_dim=8, rope_dim=4, v_dim=8,
+        dense_hidden=64, num_experts=8, zero_experts=4, top_k=3,
+        expert_hidden=16, routed_scaling=6.0, first_expert=0,
+        experts_held=4, q_scale=1.5, kv_scale=2.0, max_length=T_MAX,
+        rope_theta=1e4)).init()
+
+
 MODELS = (("gpt2-f32", lambda: gpt2(jnp.float32), True),
           ("gpt2-bf16-g2", lambda: gpt2(jnp.bfloat16, 128, 2), True),
           ("latent-moe", latent, False),
-          ("mixed", mixed, True))
+          ("mixed", mixed, True),
+          ("shortcut-moe", shortcut, False))
+
+
+def train_text(net):
+    """The lowered training step on sparse labels (fused CE), as the
+    training cell runs it."""
+    step = net._get_train_step()
+    toks = jnp.zeros((2, 16), jnp.int32)
+    return step.lower(net.params, net.updater_state, net.state,
+                      {"tokens": toks}, {"out": toks}, None, None, 0,
+                      None).as_text()
 
 
 def drive(dec, paged):
@@ -126,6 +149,9 @@ def main():
                             dump, key.replace("|", "__") + ".mlir"),
                             "w", encoding="utf-8") as f:
                         f.write(text)
+    text = train_text(gpt2(jnp.bfloat16, 128, 2))
+    table["gpt2-bf16-g2|train"] = hashlib.sha256(
+        text.encode()).hexdigest()[:16]
     with open(out_path, "w", encoding="utf-8") as f:
         json.dump(table, f, indent=1, sort_keys=True)
     print(len(table), "programs")
