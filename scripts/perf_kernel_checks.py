@@ -38,6 +38,13 @@ Checks:
                       printed, not gated: microseconds a call, the experts
                       it read, and what their bytes take at 819 GB/s — the
                       baseline of ROADMAP S12 (c))
+  ssm-update          (kernels/ssm_update.py against ``ssm_step`` at
+                      granite-4.0-h-micro.chat-short's [32, 64, 64, 128]
+                      bf16 state: errors in units of the tests' tolerances;
+                      printed, not gated: microseconds a call over 48 calls
+                      in one program, eight states in turn, beside the
+                      head-loop body it replaced, and what the call's bytes
+                      take at 819 GB/s)
 
 Error metric: max|a−b| / (max|b| + 1e-30) over fwd outputs and each
 gradient; thresholds sized for bf16 matmul noise (attention) and f32
@@ -464,6 +471,141 @@ def check_expert_mask(rows):
         rows.append((f"expert-mask@{name}", errs, 3e-2))
 
 
+#: granite-4.0-h-micro.chat-short: one state-space layer's state, 32 slots
+SSM_SHAPE = (32, 64, 64, 128)
+
+
+def _head_loop_kernel(s_ref, xt_ref, dt_ref, bc_ref, ad_ref, so_ref, yt_ref):
+    """The state-update body before the row layout, kept to be timed beside
+    it: one slot a grid step, a static loop over its heads, each a [P, N]
+    tile decayed by a lane of dt_ref [1, H], given a column of x [P, H]
+    broadcast along the lanes, read out by a sum across the lanes and
+    selected into lane h of yt [P, H]."""
+    heads = s_ref.shape[0]
+    xt = xt_ref[...]
+    dt = dt_ref[...]
+    decay = jnp.exp(dt * ad_ref[0:1, :])
+    u = xt * dt
+    bvec, cvec = bc_ref[0:1, :], bc_ref[1:2, :]
+    lane = jax.lax.broadcasted_iota(jnp.int32, xt.shape, 1)
+    y = xt * ad_ref[1:2, :]
+    for h in range(heads):
+        s = s_ref[h].astype(jnp.float32) * decay[:, h:h + 1] \
+            + u[:, h:h + 1] * bvec
+        so_ref[h] = s.astype(so_ref.dtype)
+        col = jnp.sum(s * cvec, axis=1, keepdims=True)
+        y = y + jnp.where(lane == h, col, 0.0)
+    yt_ref[...] = y
+
+
+def head_loop_update(state, x, dt, a, b, c, d):
+    """``ssm_step``'s contract through :func:`_head_loop_kernel`, its
+    operands laid out as its helper laid them out."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    s, h, p, n = state.shape
+    f32 = jnp.float32
+    slot = lambda i: (i, 0, 0)
+    new, yt = pl.pallas_call(
+        _head_loop_kernel,
+        name="ssm_head_loop",
+        grid=(s,),
+        in_specs=[pl.BlockSpec((None, h, p, n), lambda i: (i, 0, 0, 0)),
+                  pl.BlockSpec((None, p, h), slot),
+                  pl.BlockSpec((None, 1, h), slot),
+                  pl.BlockSpec((None, 2, n), slot),
+                  pl.BlockSpec((2, h), lambda i: (0, 0))],
+        out_specs=[pl.BlockSpec((None, h, p, n), lambda i: (i, 0, 0, 0)),
+                   pl.BlockSpec((None, p, h), slot)],
+        out_shape=[jax.ShapeDtypeStruct(state.shape, state.dtype),
+                   jax.ShapeDtypeStruct((s, p, h), f32)],
+        input_output_aliases={0: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+    )(state, jnp.swapaxes(x.astype(f32), 1, 2), dt.astype(f32)[:, None, :],
+      jnp.stack([b.astype(f32), c.astype(f32)], axis=1),
+      jnp.stack([a.astype(f32), d.astype(f32)]))
+    return new, jnp.swapaxes(yt, 1, 2)
+
+
+def _ssm_operands(loops, layers=1, shape=SSM_SHAPE, seed=0):
+    """``layers`` bfloat16 states of ``shape``, A and D, and ``loops``
+    tokens' x, dt, B and C drawn as the layer makes them (dt a softplus, A
+    negative)."""
+    s, h, p, n = shape
+    ks = jax.random.split(jax.random.PRNGKey(seed), 7)
+    states = [jax.random.normal(k, shape).astype(jnp.bfloat16)
+              for k in jax.random.split(ks[0], layers)]
+    xs = jax.random.normal(ks[1], (loops, s, h, p))
+    dts = jax.nn.softplus(jax.random.normal(ks[2], (loops, s, h)) - 2.0)
+    a = -jnp.exp(jax.random.normal(ks[3], (h,)))
+    bs, cs = (jax.random.normal(k, (loops, s, n)) for k in ks[4:6])
+    d = jax.random.normal(ks[6], (h,))
+    return states, xs, dts, a, bs, cs, d
+
+
+def ssm_update_call(update, loops=48, layers=8):
+    """``update(state, x, dt, a, b, c, d) -> (state, y)`` at SSM_SHAPE:
+    (its error against ``ssm_step`` in units of the test's tolerances — a
+    bfloat16 state within one rounding, y within rtol 1e-5 / atol 1e-4 —
+    and microseconds a call). The ``loops`` calls run inside one program,
+    each on a token of its own and on the state the call ``layers`` calls
+    before left: ``layers`` states in turn, as the layers of a decode step
+    take theirs, 268 MB that fast memory cannot keep between calls as it
+    could keep one."""
+    from deeplearning4j_tpu.nn.conf.layers.state_space import ssm_step
+    states, xs, dts, a, bs, cs, d = _ssm_operands(loops, layers)
+    one = lambda f: jax.jit(lambda st, i: f(st, xs[i], dts[i], a, bs[i],
+                                           cs[i], d))
+    got_s, got_y = one(update)(states[0], 0)
+    ref_s, ref_y = one(ssm_step)(states[0], 0)
+    got_s, ref_s = (np.asarray(v, np.float32) for v in (got_s, ref_s))
+    got_y, ref_y = np.asarray(got_y), np.asarray(ref_y)
+    errs = {"state": float(np.max(np.abs(got_s - ref_s)
+                                  / (2 ** -7 * (1 + np.abs(ref_s))))),
+            "y": float(np.max(np.abs(got_y - ref_y)
+                              / (1e-4 + 1e-5 * np.abs(ref_y))))}
+    by_layer = lambda v: v.reshape((loops // layers, layers) + v.shape[1:])
+
+    @jax.jit
+    def chained(states, xs, dts, bs, cs):
+        def body(carry, toks):
+            states, acc = carry
+            out = []
+            for st, x, dt, b, c in zip(states, *toks):
+                st, y = update(st, x, dt, a, b, c, d)
+                out.append(st)
+                acc = acc + y[0, 0, 0]
+            return (out, acc), None
+        return jax.lax.scan(body, (states, jnp.float32(0)),
+                            tuple(map(by_layer, (xs, dts, bs, cs))))[0]
+    seconds = _timed(chained, states, xs, dts, bs, cs, calls=3) / loops
+    return errs, seconds * 1e6
+
+
+def check_ssm_update(rows):
+    from benchmark.families.mamba2_hybrid.flops import ssm_decode_need
+    from benchmark.harness.manifest import peaks
+    from deeplearning4j_tpu.kernels.ssm_update import make_ssm_update_helper
+    s, h, p, n = SSM_SHAPE
+    need = ssm_decode_need({"ssm_heads": h, "ssm_head_dim": p,
+                            "ssm_state": n}, s)
+    bytes_us = need["bytes"] / peaks(
+        jax.devices()[0].device_kind)["hbm_bytes_per_s"] * 1e6
+    helper = make_ssm_update_helper(interpret=False)
+    kernel = lambda *args: helper(None, *args)
+    errs, us = ssm_update_call(kernel)
+    head_errs, head_us = ssm_update_call(head_loop_update)
+    print(f"  ssm-update {list(SSM_SHAPE)} bf16: {us:.1f} us a call (the "
+          f"head loop before it {head_us:.1f} us); its bytes "
+          f"{bytes_us:.1f} us at the memory's rate; errors in tolerances: "
+          + ", ".join(f"{k} {v:.2f}" for k, v in errs.items())
+          + " (head loop " + ", ".join(f"{k} {v:.2f}"
+                                       for k, v in head_errs.items())
+          + ")", flush=True)
+    rows.append(("ssm-update", errs, 1.0))
+
+
 def main():
     from deeplearning4j_tpu.kernels.pallas_attention import \
         pallas_flash_attention
@@ -501,6 +643,7 @@ def main():
     check_decode_block_layout(rows)
     check_slab_stream(rows)
     check_expert_mask(rows)
+    check_ssm_update(rows)
 
     ok_all = True
     print(f"{'check':22s} {'threshold':>9s}  errors")

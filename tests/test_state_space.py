@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 
 from deeplearning4j_tpu.kernels import slab_attention as sa
+from deeplearning4j_tpu.kernels import ssm_update
 from deeplearning4j_tpu.kernels.ssm_update import make_ssm_update_helper
 from deeplearning4j_tpu.models import (SlotGenerationEngine,
                                        TransformerDecoder,
@@ -191,9 +192,8 @@ def test_engine_counts_alive_and_every_lane_per_state_layer(net, dec):
     assert len(hs[0].result(0)) == 10
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_state_update_kernel_interpreted_equals_the_jnp_body(dtype):
-    s, h, p, n = 3, 4, 16, 128
+def _update_operands(shape, dtype):
+    s, h, p, n = shape
     ks = jax.random.split(jax.random.PRNGKey(7), 7)
     state = jax.random.normal(ks[0], (s, h, p, n)).astype(dtype)
     x = jax.random.normal(ks[1], (s, h, p))
@@ -201,6 +201,19 @@ def test_state_update_kernel_interpreted_equals_the_jnp_body(dtype):
     a = -jnp.exp(jax.random.normal(ks[3], (h,)))
     b, c = (jax.random.normal(k, (s, n)) for k in ks[4:6])
     d = jax.random.normal(ks[6], (h,))
+    return state, x, dt, a, b, c, d
+
+
+@pytest.mark.parametrize("shape", [
+    (3, 4, 16, 128),      # four whole heads a 64-row chunk, three slots a step
+    (2, 64, 64, 128),     # a Granite-4.0-H slot: two heads a chunk, two slots
+    (2, 5, 64, 128),      # five heads, which two-head chunks do not divide
+    (3, 48, 64, 128),     # three slots too large to pair: one a step
+    (2, 2, 256, 128),     # a chunk inside one head
+], ids=lambda s: "x".join(map(str, s)))
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_state_update_kernel_interpreted_equals_the_jnp_body(dtype, shape):
+    state, x, dt, a, b, c, d = _update_operands(shape, dtype)
     got_s, got_y = make_ssm_update_helper(interpret=True)(
         None, state, x, dt, a, b, c, d)
     ref_s, ref_y = ssm_step(state, x, dt, a, b, c, d)
@@ -213,6 +226,44 @@ def test_state_update_kernel_interpreted_equals_the_jnp_body(dtype):
                                atol=tol)
     np.testing.assert_allclose(np.asarray(got_y), np.asarray(ref_y),
                                rtol=1e-5, atol=1e-4)
+
+
+def test_state_update_plan_pairs_granite_slots_and_steps_an_odd_count_alone():
+    bf16 = jnp.bfloat16
+    assert ssm_update.plan(32, 64, 64, 128, bf16) == (128, 2)
+    assert ssm_update.plan(3, 48, 64, 128, bf16) == (128, 1)
+    assert ssm_update.plan(2, 5, 64, 128, bf16) == (64, 2)
+    assert ssm_update.plan(3, 4, 16, 128, bf16) == (64, 3)
+
+
+def _pallas_call_of(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            return eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found = _pallas_call_of(sub)
+            if found is not None:
+                return found
+    return None
+
+
+def test_state_update_kernel_writes_a_slots_state_in_place():
+    """The kernel's one call names the state operand as the buffer of its
+    new state, and a donated state's buffer is the one the new state
+    occupies: no second copy of every slot's state."""
+    state, x, dt, a, b, c, d = _update_operands((2, 4, 16, 128),
+                                                jnp.bfloat16)
+    helper = make_ssm_update_helper(interpret=True)
+    update = lambda st, *rest: helper(None, st, *rest)
+    eqn = _pallas_call_of(jax.make_jaxpr(update)(
+        state, x, dt, a, b, c, d).jaxpr)
+    assert eqn.params["input_output_aliases"] == ((0, 0),)
+    assert eqn.invars[0].aval.shape == eqn.outvars[0].aval.shape \
+        == state.shape
+    assert eqn.outvars[0].aval.dtype == jnp.bfloat16
+    where = state.unsafe_buffer_pointer()
+    new, _ = jax.jit(update, donate_argnums=0)(state, x, dt, a, b, c, d)
+    assert state.is_deleted() and new.unsafe_buffer_pointer() == where
 
 
 def test_state_update_helper_declines_a_state_that_is_not_whole_lanes():
