@@ -54,7 +54,7 @@ from ..nn.conf.layers import Window
 from ..nn.conf.layers.feedforward import head_params
 from ..nn.graph.computation_graph import scoped
 from ..nn.graph.vertices import LayerVertex
-from ..nn.helpers import attention_spmd
+from ..nn.helpers import attention_spmd, slab_read_tally
 from ..observability.flightrec import default_flight_recorder
 from ..observability.metrics import default_registry
 from ..observability.profiler import default_profiler
@@ -142,6 +142,14 @@ _ENGINE_COUNTERS = {
                        "the state updates a block computed",
     "ssm_state_resets": "slot states overwritten by admission (admitted "
                         "requests x state-space layers)",
+    # slab attention (SLAB_COUNTERS ride each block's one readback): what
+    # the decode steps' attention read of the k/v slab, and what it holds
+    "slab_positions_read": "decode steps x slab attention layers: "
+                           "positions read, summed over slots (the "
+                           "kernel: a slot's tiles up to its position, "
+                           "none of a stopped lane's)",
+    "slab_positions_held": "decode steps x slab attention layers: "
+                           "positions the slab holds (slots x T_max)",
 }
 #: the columns a decode block of a model with expert layers appends to its
 #: token matrix, in this order (every row carries the same sums)
@@ -151,6 +159,9 @@ MOE_COUNTERS = ("moe_step_layers", "moe_assignments", "moe_experts_hit",
 #: the columns a decode block of a model with state-space layers appends
 #: after those, in this order
 SSM_COUNTERS = ("ssm_step_layers", "ssm_lane_layers")
+#: the columns a decode block of a model with slab attention layers appends
+#: last, in this order
+SLAB_COUNTERS = ("slab_positions_read", "slab_positions_held")
 #: unique per-engine metric label values (e0, e1, ...)
 _ENGINE_SEQ = itertools.count()
 
@@ -236,6 +247,10 @@ class TransformerDecoder:
         # slot whole, and their update counts leave a block as
         # SSM_COUNTERS columns
         self.state_names: List[str] = []
+        # vertices whose class says ``slab_reads`` (a k/v slab read in a
+        # decode step): among attn_names; what their attention reads leaves
+        # a block as SLAB_COUNTERS columns
+        self.slab_names: List[str] = []
         embed = None
         for name in conf.topological_order:
             v = conf.vertices[name]
@@ -253,6 +268,8 @@ class TransformerDecoder:
                 self.attn_names.append(name)
                 if getattr(v.layer, "fixed_state", False):
                     self.state_names.append(name)
+                if getattr(v.layer, "slab_reads", False):
+                    self.slab_names.append(name)
             elif hasattr(v.layer, "embed"):
                 embed, self._embed_name = v.layer, name
             elif getattr(v.layer, "counts_tokens", False):
@@ -581,34 +598,49 @@ class TransformerDecoder:
             jnp.sum(here)]).astype(jnp.int32)
 
     # graftlint: traced
-    def _ssm_sums(self, stop):
-        """One decode step's SSM_COUNTERS int32: alive lanes and every lane,
-        each times the state-space layers."""
-        n = len(self.state_names)
-        return jnp.stack([jnp.sum(~stop) * n,
-                          jnp.int32(stop.shape[0] * n)]).astype(jnp.int32)
+    def _step_sums(self, stop, reads):
+        """One decode step's :attr:`step_counters` int32: of the
+        state-space layers, alive lanes and every lane, each times the
+        layers (SSM_COUNTERS); of the slab attention layers, the
+        ``(read, held)`` notes of their calls summed
+        (``nn.helpers.slab_read_tally``; none noted, a paged pool, reads 0,
+        0: SLAB_COUNTERS)."""
+        sums = []
+        if self.state_names:
+            n = len(self.state_names)
+            sums += [jnp.sum(~stop) * n, stop.shape[0] * n]
+        if self.slab_names:
+            sums += [sum(r for r, _ in reads), sum(h for _, h in reads)]
+        return jnp.stack([jnp.asarray(x, jnp.int32) for x in sums])
 
     # graftlint: traced
-    def _block_columns(self, toks, fault, moe, ssm=None):
+    def _block_columns(self, toks, fault, moe, counted=None):
         """A decode block's ONE read-back matrix: its tokens [B, K], then
         the sentinel's verdict column (sentinel decoders), then the
         MOE_COUNTERS sums (models with expert layers), then the
-        SSM_COUNTERS sums (models with state-space layers), the same in
-        every row. A model with none reads back [B, K], as ever."""
+        :attr:`step_counters` sums ``counted``, the same in every row. A
+        model with none reads back [B, K], as ever."""
         cols = [toks]
         if self.sentinel:
             cols.append(fault.astype(jnp.int32)[:, None])
-        for on, sums in ((self.moe_names, moe), (self.state_names, ssm)):
-            if on:
+        for sums in (moe if self.moe_names else None, counted):
+            if sums is not None:
                 cols.append(jnp.broadcast_to(sums[None, :],
                                              (toks.shape[0], sums.shape[0])))
         return toks if len(cols) == 1 else jnp.concatenate(cols, axis=1)
 
     @property
+    def step_counters(self) -> Tuple[str, ...]:
+        """The counters a decode block carries step by step after the
+        expert layers': SSM_COUNTERS (models with state-space layers),
+        then SLAB_COUNTERS (models with slab attention layers)."""
+        return (SSM_COUNTERS if self.state_names else ()) + \
+            (SLAB_COUNTERS if self.slab_names else ())
+
+    @property
     def counter_names(self) -> Tuple[str, ...]:
         """The counters a decode block's matrix ends in, in order."""
-        return (MOE_COUNTERS if self.moe_names else ()) + \
-            (SSM_COUNTERS if self.state_names else ())
+        return (MOE_COUNTERS if self.moe_names else ()) + self.step_counters
 
     def split_block(self, host: np.ndarray):
         """(matrix without the counter columns, their sums in the order of
@@ -945,15 +977,17 @@ class TransformerDecoder:
                 def body(carry, _):
                     caches, ids, pos, stop, fault, moe, step = carry[:7]
                     pos_c = jnp.minimum(pos, self.t_max - 1)
-                    logits, caches, tally = self._walk(
-                        params, state, caches, ids,
-                        Window(start=pos_c, pages=ptables, alive=~stop))
+                    with slab_read_tally() as reads:
+                        logits, caches, tally = self._walk(
+                            params, state, caches, ids,
+                            Window(start=pos_c, pages=ptables, alive=~stop))
                     if tally:
                         moe = moe + self._moe_sums(tally)
-                    # a model with state-space layers carries their counts
-                    # as one more element (none: the carry is as it was)
-                    ssm = (carry[7] + self._ssm_sums(stop),) \
-                        if self.state_names else ()
+                    # a model with state-space or slab attention layers
+                    # carries their counts as one more element (neither:
+                    # the carry is as it was)
+                    counted = (carry[7] + self._step_sums(stop, reads),) \
+                        if self.step_counters else ()
                     if self.sentinel:
                         fault = fault | self._fault_of(logits, stop)
                     kk = jax.random.fold_in(
@@ -967,18 +1001,18 @@ class TransformerDecoder:
                     new_pos = jnp.where(stop, pos, pos + 1)
                     new_stop = stop | hit_eos | (new_pos >= self.t_max)
                     return (caches, nxt, new_pos, new_stop, fault, moe,
-                            step + 1) + ssm, nxt
+                            step + 1) + counted, nxt
                 fault0 = jnp.zeros_like(stopped)
                 moe0 = jnp.zeros(len(MOE_COUNTERS), jnp.int32)
-                ssm0 = (jnp.zeros(len(SSM_COUNTERS), jnp.int32),) \
-                    if self.state_names else ()
+                counted0 = (jnp.zeros(len(self.step_counters), jnp.int32),) \
+                    if self.step_counters else ()
                 carry, toks = jax.lax.scan(
                     body, (caches, ids, positions, stopped, fault0, moe0,
-                           step0) + ssm0, None, length=k_steps)
+                           step0) + counted0, None, length=k_steps)
                 caches, ids, positions, stopped, fault, moe = carry[:6]
                 out = self._block_columns(
                     toks.T, fault, moe,
-                    carry[7] if self.state_names else None)
+                    carry[7] if self.step_counters else None)
                 return out, ids, positions, stopped, caches
             # per-K name: the compile auditor attributes by __name__, and
             # two K values share every input shape — one shared name
@@ -2175,6 +2209,15 @@ class SlotGenerationEngine:
             lambda: (lambda s: 0 if s is None else
                      sum(r is not None for r in s._slots) +
                      len(s._chunking) + len(s._admitting))(wself()))
+        if self._pager is None and self.decoder.slab_names:
+            # what the decode steps' attention read of the slab it holds,
+            # over the engine's life (the SLAB_COUNTERS' ratio)
+            reg.gauge("generation_slab_read_share",
+                      "slab positions the decode attention read / "
+                      "positions the slab held, over retired blocks",
+                      ("engine",)).labels(self.engine_id).set_function(
+                lambda: (lambda s: 0.0 if s is None else
+                         s._slab_read_share())(wself()))
         if self._pager is not None:
             # page-granular KV accounting (ISSUE 12 satellite): pool
             # state by page, pool bytes, and the internal-fragmentation
@@ -4900,6 +4943,12 @@ class SlotGenerationEngine:
         out["mesh_shape"] = mesh_tag(self.mesh) or None
         out["kv_heads_per_row"] = self.kv_heads_per_row
         return out
+
+    def _slab_read_share(self) -> float:
+        """slab_positions_read / slab_positions_held so far (0.0 before a
+        block has been retired)."""
+        held = self._m["slab_positions_held"].value
+        return self._m["slab_positions_read"].value / held if held else 0.0
 
     @property
     def kv_heads_per_row(self) -> int:

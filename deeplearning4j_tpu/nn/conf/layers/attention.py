@@ -18,7 +18,7 @@ import numpy as np
 from ..input_type import InputType
 from ..serde import register_config
 from .base import BaseRecurrentLayerConf
-from ...helpers import get_helper, note_attention_plan
+from ...helpers import get_helper, note_attention_plan, note_slab_reads
 
 
 @dataclasses.dataclass(frozen=True)
@@ -71,6 +71,10 @@ class SelfAttentionLayer(BaseRecurrentLayerConf):
     scale: float = 0.0
     #: False: the output projection has no bias
     bias: bool = True
+
+    #: a decode step reads this layer's k/v slab (:meth:`_slab_attend`): the
+    #: decoder counts what it reads (models/generation.py SLAB_COUNTERS)
+    slab_reads = True
 
     def _head_size(self) -> int:
         return self.head_size or max(self.n_out // self.num_heads, 1)
@@ -179,7 +183,8 @@ class SelfAttentionLayer(BaseRecurrentLayerConf):
         if window.start is None:
             return self.prefill_forward(params, x, cache, mask=window.mask)
         if window.valid is None:
-            return self.decode_forward(params, x, cache, window.start)
+            return self.decode_forward(params, x, cache, window.start,
+                                       window.alive)
         return self.chunk_forward(params, x, cache, window.start,
                                   window.valid if window.masked else None)
 
@@ -279,10 +284,13 @@ class SelfAttentionLayer(BaseRecurrentLayerConf):
         return new_cache
 
     # graftlint: traced
-    def _slab_attend(self, q, ck, cv, qpos):
+    def _slab_attend(self, q, ck, cv, qpos, alive=None):
         """Length-masked attention of a window's queries over the slab:
         q [B, C, H, Dh], ck/cv [B, H_kv/g, T, g·Dh], ``qpos`` [B, C] the
-        absolute position of each query (it attends cells ``<= qpos``).
+        absolute position of each query (it attends cells ``<= qpos``),
+        ``alive`` [B] the lanes whose rows count (None: every lane; a lane
+        that does not count gets finite rows of no meaning, zeros from the
+        kernel).
         Both contractions run over whole rows: the logits of a row group
         are ``K_row[T, g·Dh] · Qblk[g·Dh, G]`` over its ``G = H/(H_kv/g)``
         query heads with ``Qblk`` block-diagonal (query head j reads KV
@@ -295,8 +303,11 @@ class SelfAttentionLayer(BaseRecurrentLayerConf):
         head). The ``slab_attention``
         helper (kernels/slab_attention.py on the TPU: K and V streamed in
         position tiles, an online softmax across them) takes the call
-        where it serves the shapes; the einsum body below is the
-        always-available path. Returns [B, C, H, Dh]."""
+        where it serves the shapes, and reads of a slot only the tiles up
+        to its queries' last position, and nothing of a lane that does not
+        count; the einsum body below is the always-available path, and
+        reads every position. Either notes what it read
+        (``nn.helpers.note_slab_reads``). Returns [B, C, H, Dh]."""
         b, c, h, hs = q.shape
         hg = ck.shape[1]
         g = h // hg                          # query heads of a row group
@@ -313,12 +324,13 @@ class SelfAttentionLayer(BaseRecurrentLayerConf):
             qg = qg * own                    # [B, C, H/g, g, g, Dh]
         qblk = qg.reshape(b, c, hg, g, kv_g * hs)
         helper = get_helper("slab_attention")
-        rows = helper(self, qblk, ck, cv, qpos, scale) \
+        rows = helper(self, qblk, ck, cv, qpos, scale, alive) \
             if helper is not None else None
         if rows is None:
             # no helper, or it declined (a row that is not whole lanes, a T
             # no tile divides, a long window): the built-in body
             note_attention_plan("slab_einsum")
+            note_slab_reads(b * ck.shape[2], b * ck.shape[2])
             logits = jnp.einsum("bqgjl,bgtl->bgjqt", qblk, ck,
                                 preferred_element_type=jnp.float32) * scale
             kpos = jnp.arange(ck.shape[2], dtype=jnp.int32)
@@ -361,7 +373,8 @@ class SelfAttentionLayer(BaseRecurrentLayerConf):
         return self._project_out(params, out), new_cache
 
     # graftlint: traced
-    def decode_forward(self, params, x, cache: Dict, positions):
+    def decode_forward(self, params, x, cache: Dict, positions,
+                       alive=None):
         """One decode step: x [B, 1, n_in] is the token at ``positions``
         ([B] int32, per-row — slots in a continuous batch sit at different
         lengths). Writes k/v into the [B, H/g, T_max, g·Dh] cache at each
@@ -370,7 +383,11 @@ class SelfAttentionLayer(BaseRecurrentLayerConf):
         fixed-shape, ONE compile serves every step) and attends q over
         cache[:, :, :pos+1] via a length mask (:meth:`_slab_attend`: f32
         logits and softmax, both contractions over whole rows — no
-        relayout of the cache). Returns (out [B, 1, n_out], new_cache).
+        relayout of the cache). ``alive`` ([B] bool, the decode block's
+        ``~stop``; None: every row) marks the rows whose output counts: the
+        kernel reads a slot's tiles up to its position and nothing of a row
+        that does not count, whose output is then zero. Returns (out
+        [B, 1, n_out], new_cache).
 
         Positions are clamped to the cache depth: a fused decode block
         (models/generation.py decode_block) lets finished lanes overshoot
@@ -382,7 +399,7 @@ class SelfAttentionLayer(BaseRecurrentLayerConf):
                           cache["k"].shape[2] - 1)
         new_cache = self._slab_write(cache, k, v, pos)
         out = self._slab_attend(q, new_cache["k"], new_cache["v"],
-                                pos[:, None])
+                                pos[:, None], alive)
         return self._project_out(params, out.astype(x.dtype)), new_cache
 
     # graftlint: traced

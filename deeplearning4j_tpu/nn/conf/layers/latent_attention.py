@@ -86,6 +86,9 @@ class LatentAttentionLayer(SelfAttentionLayer):
     eps: float = 1e-6
     q_scale: float = 1.0
     kv_scale: float = 1.0
+    #: the absorbed decode reads the latent slab, never through
+    #: ``_slab_attend``: nothing to count there
+    slab_reads = False
 
     def _head_size(self) -> int:
         """The width a score contracts over (``_attend`` scales by it)."""
@@ -242,9 +245,12 @@ class LatentAttentionLayer(SelfAttentionLayer):
         return out, {"kv": slab}
 
     # graftlint: traced
-    def decode_forward(self, params, x, cache: Dict, positions):
+    def decode_forward(self, params, x, cache: Dict, positions, alive=None):
         """One decode step, absorbed: x [B, 1, n_in] at ``positions`` [B]
-        (clamped to the slab's depth, as ``SelfAttentionLayer`` does)."""
+        (clamped to the slab's depth, as ``SelfAttentionLayer`` does).
+        ``alive`` is not read: the absorbed sums read every position (a
+        latent kernel could skip past them with the slab kernel's
+        ``live_tiles``, ROADMAP S16)."""
         pos = jnp.minimum(jnp.asarray(positions, jnp.int32).reshape(-1),
                           cache["kv"].shape[2] - 1)
         q_nope, q_rope, rows = self._latent(params, x, pos[:, None])

@@ -16,7 +16,7 @@ from __future__ import annotations
 
 import contextlib
 import threading
-from typing import Callable, Dict, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
 
@@ -48,6 +48,35 @@ def attention_spmd_context():
     """(mesh, batch_axis, head_axis) declared by :func:`attention_spmd` for
     the current trace, or None under a single-device jit."""
     return getattr(_SPMD, "ctx", None)
+
+
+_READS = threading.local()     # per thread, like _SPMD
+
+
+@contextlib.contextmanager
+def slab_read_tally():
+    """Collect, for the code TRACED inside on this thread, what each read
+    of a slab cache in attention reads: yields the list that
+    :func:`note_slab_reads` appends ``(positions read, positions held)``
+    to — traced values of the enclosing trace, which its owner sums (the
+    decode block's ``SLAB_COUNTERS``). Closed, a note goes nowhere."""
+    prev = getattr(_READS, "tally", None)
+    tally: List = []
+    _READS.tally = tally
+    try:
+        yield tally
+    finally:
+        _READS.tally = prev
+
+
+def note_slab_reads(read, held) -> None:
+    """Called while a program is traced, once per attention call over a
+    slab cache: the positions the call reads, summed over its slots, and
+    the positions the slab holds (slots × T) — scalars, traced or not."""
+    tally = getattr(_READS, "tally", None)
+    if tally is not None:
+        tally.append((read, held))
+
 
 _PLAN_LOCK = threading.Lock()
 _PLANS: Dict[str, int] = {}

@@ -27,9 +27,23 @@ Checks:
                       [16, 10, 1024, 128] bf16, 1 / 4 / 8 queries a slot:
                       largest absolute difference of the outputs; printed,
                       not gated: the isolated time of each over 100 calls,
-                      the plan counts of one traced
-                      decode_block4_impl, and the block timed at empty and
-                      at full slabs with the kernel and without it)
+                      the plan counts of one traced decode_block4_impl,
+                      and the block timed at empty slabs, 3 and 8 of 16
+                      lanes alive (the rest stopped) and full slabs, with
+                      the kernel at each tile that divides T and with the
+                      einsum body — the readings MIN_TILE / MIN_BLOCK_BYTES
+                      of kernels/slab_attention.py were set from)
+  slab-stream-live    (the same kernel at each serving cell's slab shape,
+                      16 x [10, 1024, 128] for gpt2-large.chat-open and
+                      32 x [4, 2048, 128] for granite-4.0-h-micro.chat-short:
+                      one layer's call with 3 of 16 (8 of 32) lanes alive
+                      at positions drawn from the cell's traffic mix, the
+                      rest stopped — largest absolute difference from the
+                      einsum body on the alive lanes; printed, not gated:
+                      microseconds a call at each tile that divides T, the
+                      plan's own marked, beside the full-slab call (every
+                      lane alive at T - 1), and the positions each reads
+                      of those held)
   expert-mask         (RoutedExpertsLayer.forward's decode call, 16 lanes
                       with 16 / 6 / 1 marked, at joyai-llm-flash's shape —
                       top-8 of 256 at d 2048 / h 768 — and at
@@ -344,10 +358,24 @@ def slab_attend_times(window=1, loops=100, layers=5):
     return float(np.max(np.abs(outs["kernel"] - outs["einsum"]))), times
 
 
-def decode_block_times(slots=16, at=(0, 1000), calls=10):
+#: lanes of gpt2-large.chat-open's 16 at which a decode block is timed:
+#: (positions, stopped) — empty slabs, three lanes alive mid-answer (the
+#: cell's mean load) beside thirteen stopped ones, eight alive, full slabs
+BLOCK_LOADS = {
+    "empty": ([0] * 16, [False] * 16),
+    "3 alive": ([250, 700, 700, 400] + [700] * 3 + [150] + [700] * 8,
+                [i not in (0, 3, 7) for i in range(16)]),
+    "8 alive": ([250, 700, 520, 400, 700, 700, 880, 150, 700, 300, 700,
+                 700, 610, 700, 230, 700],
+                [i not in (0, 2, 3, 6, 7, 9, 12, 14) for i in range(16)]),
+    "full": ([1000] * 16, [False] * 16),
+}
+
+
+def decode_block_times(slots=16, loads=BLOCK_LOADS, calls=10):
     """decode_block4_impl at gpt2-large's shapes with zero weights (its
-    time does not depend on them), every lane at position ``at[i]``:
-    (plans of the traced block, [milliseconds a block at each])."""
+    time does not depend on them), its lanes at each of ``loads``' positions
+    and stops: (plans of the traced block, {load: milliseconds a block})."""
     import time
     from deeplearning4j_tpu.analysis import AttentionPlanAudit
     with AttentionPlanAudit() as audit:
@@ -356,9 +384,9 @@ def decode_block_times(slots=16, at=(0, 1000), calls=10):
     params, state, caches = jax.tree_util.tree_map(
         lambda a: jnp.zeros(a.shape, a.dtype), args[:3])
 
-    def spin(caches, pos):
-        rest = [jnp.zeros(slots, jnp.int32), jnp.full(slots, pos, jnp.int32),
-                jnp.zeros(slots, bool), jnp.zeros(slots, jnp.float32),
+    def spin(caches, pos, stopped):
+        rest = [jnp.zeros(slots, jnp.int32), jnp.asarray(pos, jnp.int32),
+                jnp.asarray(stopped), jnp.zeros(slots, jnp.float32),
                 jnp.full(slots, -1, jnp.int32), jax.random.PRNGKey(0),
                 jnp.int32(0), jnp.int32(0)]
         t0 = time.perf_counter()
@@ -366,17 +394,17 @@ def decode_block_times(slots=16, at=(0, 1000), calls=10):
             caches = compiled(params, state, caches, *rest)[-1]
         jax.block_until_ready(caches)
         return caches, (time.perf_counter() - t0) / calls * 1e3
-    times = []
-    for pos in at:
-        # a block's first calls after another position's (or the compile)
-        # run a per cent or two slower: warm each position, then time it
-        caches, _ = spin(caches, pos)
-        caches, ms = spin(caches, pos)
-        times.append(ms)
+    times = {}
+    for name, (pos, stopped) in loads.items():
+        # a block's first calls after another load's (or the compile) run
+        # a per cent or two slower: warm each load, then time it
+        caches, _ = spin(caches, pos, stopped)
+        caches, times[name] = spin(caches, pos, stopped)
     return audit.plans(), times
 
 
 def check_slab_stream(rows):
+    from deeplearning4j_tpu.kernels import slab_attention as sa
     from deeplearning4j_tpu.nn import helpers
     errs = {}
     # a decode step, and the verify windows no cell runs
@@ -388,16 +416,133 @@ def check_slab_stream(rows):
                   f"{k} {v:.1f} us" for k, v in times.items()), flush=True)
     # bf16 outputs of O(1): a rounding step or two of reassociation
     rows.append(("slab-stream", errs, 3e-2))
-    for label, switch in (("kernel", helpers.enable_helper),
-                          ("einsum", helpers.disable_helper)):
-        switch("slab_attention")
+    # the block with the kernel at each tile that divides T (the plan's
+    # marked), and with the einsum body
+    tb_plan = sa.plan(1, SLAB_SHAPE[1], SLAB_SHAPE[2], SLAB_SHAPE[3],
+                      jnp.bfloat16)[1]
+    saved = sa.TILES
+    runs = [(f"kernel, tb {tb}{' (plan)' if tb == tb_plan else ''}", tb)
+            for tb in saved if SLAB_SHAPE[2] % tb == 0] + [("einsum", None)]
+    for label, tb in runs:
+        sa.TILES = saved if tb is None else (tb,)
+        if tb is None:
+            helpers.disable_helper("slab_attention")
         try:
-            plans, (empty, full) = decode_block_times()
+            plans, times = decode_block_times()
         finally:
+            sa.TILES = saved
             helpers.enable_helper("slab_attention")
-        print(f"  decode_block4_impl, {label}: plans {plans}; a block "
-              f"{empty:.3f} ms at empty slabs, {full:.3f} ms at full "
-              f"({abs(full - empty) / empty:.2%} apart)", flush=True)
+        print(f"  decode_block4_impl, {label}: plans {plans}; ms a block: "
+              + ", ".join(f"{k} {v:.3f}" for k, v in times.items()),
+              flush=True)
+
+
+#: the serving cells' slab attention layers: slab shape [slots, H_kv/g, T,
+#: g·Dh], query and KV heads, lanes alive of the slots, traffic mix
+LIVE_CELLS = {
+    "gpt2-large.chat-open": dict(shape=(16, 10, 1024, 128), heads=20,
+                                 kv_heads=20, alive=3, traffic="chat-open"),
+    "granite-4.0-h-micro.chat-short": dict(
+        shape=(32, 4, 2048, 128), heads=32, kv_heads=8, alive=8,
+        traffic="chat-short"),
+}
+
+
+def _mix_positions(traffic, n, rng):
+    """``n`` positions of lanes mid-answer under a traffic mix: a prompt
+    and an answer drawn from its log-normals (clipped as the load
+    generator clips them), the lane a uniform share of the way through
+    the answer."""
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "traffic",
+        traffic + ".json")
+    with open(path, encoding="utf-8") as f:
+        mix = json.load(f)
+
+    def draw(d):
+        v = d["median"] * np.exp(d["sigma"] * rng.standard_normal(n))
+        return np.clip(np.rint(v), d["min"], d["max"]).astype(np.int64)
+    prompt, answer = draw(mix["prompt_tokens"]), draw(mix["new_tokens"])
+    return prompt + (rng.random(n) * answer).astype(np.int64)
+
+
+def slab_live_times(cell, loops=100, layers=5):
+    """One slab attention layer of ``cell`` (LIVE_CELLS) with the kernel:
+    (the plan's tile, largest absolute difference from the einsum body on
+    the alive lanes, {tile: {"live" / "full": (microseconds a call,
+    positions read)}}, positions held). The ``loops`` calls run inside one
+    program over ``layers`` slabs in turn, as slab_attend_times does."""
+    from deeplearning4j_tpu.kernels import slab_attention as sa
+    from deeplearning4j_tpu.nn import helpers
+    from deeplearning4j_tpu.nn.conf.layers import SelfAttentionLayer
+    c = LIVE_CELLS[cell]
+    b, hg, t, lanes = c["shape"]
+    h, dh = c["heads"], 64
+    layer = SelfAttentionLayer(n_in=h * dh, n_out=h * dh, num_heads=h,
+                               num_kv_heads=c["kv_heads"], causal=True)
+    rng = np.random.default_rng(0)
+    mk = lambda shape: jnp.asarray(rng.normal(size=shape) * 0.5,
+                                   jnp.bfloat16)
+    q = mk((b, 1, h, dh))
+    slabs = [(mk(c["shape"]), mk(c["shape"])) for _ in range(layers)]
+    pos = np.minimum(_mix_positions(c["traffic"], b, rng), t - 1)
+    alive = np.zeros(b, bool)
+    alive[rng.choice(b, c["alive"], replace=False)] = True
+    cases = {"live": (jnp.asarray(pos[:, None], jnp.int32),
+                      jnp.asarray(alive)),
+             "full": (jnp.full((b, 1), t - 1, jnp.int32),
+                      jnp.ones(b, bool))}
+
+    def chained(q, slabs, qpos, live):
+        def body(i, q):
+            for ck, cv in slabs:
+                q = q + (layer._slab_attend(q, ck, cv, qpos, live)
+                         * 1e-3).astype(q.dtype)
+            return q
+        return jax.lax.fori_loop(0, loops // layers, body, q)
+
+    saved = sa.TILES
+    tb_plan = sa.plan(1, hg, t, lanes, jnp.bfloat16)[1]
+    helpers.enable_helper("slab_attention")
+    times = {}
+    try:
+        for tb in [n for n in saved if t % n == 0]:
+            sa.TILES = (tb,)
+            times[tb] = {}
+            for name, (qpos, live) in cases.items():
+                last = sa.live_tiles(qpos, live, tb, t // tb)
+                us = _timed(jax.jit(chained), q, slabs, qpos, live,
+                            calls=3) / (loops // layers * layers) * 1e6
+                times[tb][name] = (us, int(jnp.sum(last + 1)) * tb)
+    finally:
+        sa.TILES = saved
+    qpos, live = cases["live"]
+    got = jax.jit(lambda *a: layer._slab_attend(*a))(q, *slabs[0], qpos,
+                                                      live)
+    helpers.disable_helper("slab_attention")
+    try:
+        want = jax.jit(lambda *a: layer._slab_attend(*a))(q, *slabs[0],
+                                                          qpos)
+    finally:
+        helpers.enable_helper("slab_attention")
+    err = float(np.max(np.abs(np.asarray(got, np.float32)[alive]
+                              - np.asarray(want, np.float32)[alive])))
+    return tb_plan, err, times, b * t
+
+
+def check_slab_live(rows):
+    errs = {}
+    for cell in LIVE_CELLS:
+        tb_plan, errs[cell], times, held = slab_live_times(cell)
+        print(f"  slab-stream, {cell}: {LIVE_CELLS[cell]['alive']} of "
+              f"{LIVE_CELLS[cell]['shape'][0]} lanes alive; "
+              f"max|kernel-einsum| on them={errs[cell]:.2e}; a call by "
+              f"tile (positions read of {held}):", flush=True)
+        for tb, got in times.items():
+            print(f"    tb {tb:5d}{' (plan)' if tb == tb_plan else '':7s}"
+                  + "; ".join(f" {k} {us:.1f} us, {n} read"
+                              for k, (us, n) in got.items()), flush=True)
+    rows.append(("slab-stream-live", errs, 3e-2))
 
 
 #: the two drawn configurations' expert layers as their cells serve them
@@ -642,6 +787,7 @@ def main():
     check_layernorm(rows)
     check_decode_block_layout(rows)
     check_slab_stream(rows)
+    check_slab_live(rows)
     check_expert_mask(rows)
     check_ssm_update(rows)
 

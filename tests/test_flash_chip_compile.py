@@ -214,7 +214,7 @@ def compiled_decode_layer(one_chip):
 
 def test_the_decode_layer_streams_the_slab(compiled_decode_layer):
     compiled, plans = compiled_decode_layer
-    assert plans == {"slab_stream,g=2,hb=10,tb=1024": 1}
+    assert plans == {"slab_stream,g=2,hb=10,tb=512": 1}
     call = [l for l in compiled.splitlines()
             if re.match(r"\s*%slab_decode_attn\.?\d* = ", l)]
     assert len(call) == 1 and "tpu_custom_call" in call[0]
@@ -372,7 +372,7 @@ def test_every_slab_call_of_chat_opens_decode_block_streams(
         traced_with_slab_kernel):
     """36 of 36: the block scans its four steps over one traced body."""
     plans, config = _decode_block_plans("gpt2-large")
-    assert plans == {"slab_stream,g=2,hb=10,tb=1024": config["n_layer"]}
+    assert plans == {"slab_stream,g=2,hb=10,tb=512": config["n_layer"]}
 
 
 def test_no_slab_call_of_chat_2k_streams(traced_with_slab_kernel):

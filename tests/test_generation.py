@@ -209,9 +209,11 @@ class TestPackedSlab:
         # one fused decode block of 4
         toks, ids, pos, stop, caches = dec.decode_block(
             caches, np.asarray(nxt), lengths, block_size=4)
+        # the tokens; the slab attention's counters follow them
+        toks = dec.split_block(np.asarray(toks))[0]
         for i, (r, p) in enumerate(zip(refs, prompts)):
             np.testing.assert_array_equal(
-                np.asarray(toks)[i], r[len(p) + 1:len(p) + 5])
+                toks[i], r[len(p) + 1:len(p) + 5])
         # its logits, one step on, against the full forward
         seqs = [r[:len(p) + 5] for r, p in zip(refs, prompts)]
         _, step_logits, _ = dec.decode_step(

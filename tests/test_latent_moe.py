@@ -191,8 +191,13 @@ def test_model_without_experts_reads_back_what_it_did():
     nxt, _, caches = dec.prefill(dec.init_cache(2), np.zeros((2, 8), np.int32),
                                  np.array([3, 5]))
     out, *_ = dec.decode_block(caches, nxt, np.array([3, 5]), block_size=4)
-    assert out.shape == (2, 4)
-    assert dec.split_block(np.asarray(out))[1] is None
+    # the tokens as ever, then only the slab attention's two counters: the
+    # einsum body reads every position it holds, 4 steps × 2 slots × T_MAX
+    toks, counts = dec.split_block(np.asarray(out))
+    assert out.shape == (2, 6) and toks.shape == (2, 4)
+    assert dec.counter_names == ("slab_positions_read",
+                                 "slab_positions_held")
+    assert counts.tolist() == [4 * 2 * T_MAX] * 2
 
 
 def test_paged_paths_raise_and_name_the_mechanism(net, dec):

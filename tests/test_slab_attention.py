@@ -1,10 +1,13 @@
 """The streaming decode-attention kernel (kernels/slab_attention.py) against
 the einsum body of ``SelfAttentionLayer._slab_attend``, in Pallas interpret
 mode at tiny sizes: parity over head packings, slab types, query positions
-and windows, with the online softmax crossing position tiles; the helper's
-declines, recorded as ``slab_einsum``; and a tiny decoder whose fused decode
-block, verify window and meshed twin give the same greedy tokens with the
-helper forced on and with it disabled."""
+and windows, with the online softmax crossing position tiles; what it reads
+(NaN past a slot's last live tile, and in a stopped lane's whole row, never
+reaches an output that counts); the tile rule; the helper's declines,
+recorded as ``slab_einsum``; a tiny decoder whose fused decode block, verify
+window, engine and meshed twin give the same greedy tokens with the helper
+forced on and with it disabled; and the positions the decode steps read,
+counted by hand."""
 
 import jax.numpy as jnp
 import numpy as np
@@ -12,7 +15,8 @@ import pytest
 
 from deeplearning4j_tpu.analysis import AttentionPlanAudit
 from deeplearning4j_tpu.kernels import slab_attention as sa
-from deeplearning4j_tpu.models import (TransformerDecoder,
+from deeplearning4j_tpu.models import (SlotGenerationEngine,
+                                       TransformerDecoder,
                                        generate as nocache_generate,
                                        transformer_lm_conf)
 from deeplearning4j_tpu.nn import helpers
@@ -121,11 +125,96 @@ def test_several_head_groups_to_a_block(forced_on, monkeypatch):
 
 
 def test_plan_at_the_cells_shape():
-    """gpt2-large.chat-open: a slot's ten head groups × 1024 positions a
-    grid step, 2.6 MB of K and of V."""
-    assert sa.plan(1, 10, 1024, 128, jnp.bfloat16) == (10, 1024)
+    """The smallest tile of at least 256 positions whose K block holds at
+    least 1 MiB: gpt2-large.chat-open's ten head groups × 512 positions
+    (1.25 MiB of K and of V a grid step), Granite's four × 1024 (1 MiB);
+    where no tile's block reaches it, the largest tile that divides T."""
+    assert sa.plan(1, 10, 1024, 128, jnp.bfloat16) == (10, 512)
+    assert sa.plan(1, 4, 2048, 128, jnp.bfloat16) == (4, 1024)
     assert sa.plan(1, 10, 2560, 128, jnp.bfloat16) == (10, 512)
-    assert sa.plan(1, 64, 1024, 128, jnp.bfloat16) == (16, 1024)
+    assert sa.plan(1, 64, 1024, 128, jnp.bfloat16) == (64, 256)
+    assert sa.plan(1, 1, 1024, 128, jnp.bfloat16) == (1, 1024)
+    assert sa.plan(4, 4, 2048, 128, jnp.float32) == (4, 512)
+
+
+def _poisoned(ck, cv, last, tb=TB):
+    """K and V with every position past slot b's tile ``last[b]`` NaN (a
+    slot at -1: its whole row)."""
+    kpos = np.arange(ck.shape[2])
+    bad = kpos[None, :] >= (np.asarray(last)[:, None] + 1) * tb
+    bad = jnp.asarray(bad[:, None, :, None])
+    return (jnp.where(bad, jnp.nan, ck).astype(ck.dtype),
+            jnp.where(bad, jnp.nan, cv).astype(cv.dtype))
+
+
+@pytest.mark.parametrize("qpos", [[0, 200], [TB - 1, TB], [T - 1, 70]])
+@pytest.mark.parametrize("g", [1, 2, 4])
+def test_tiles_past_the_live_length_are_never_read(forced_on, small_tiles,
+                                                   g, qpos):
+    """NaN past each slot's last live tile: the kernel still gives what
+    the einsum body gives on the clean slab (0 · NaN would be NaN)."""
+    layer, q, ck, cv, pos = _operands(g, jnp.float32, 1, qpos)
+    helpers.disable_helper("slab_attention")
+    try:
+        want = layer._slab_attend(q, ck, cv, pos)
+    finally:
+        helpers.enable_helper("slab_attention")
+    got = layer._slab_attend(q, *_poisoned(ck, cv, np.asarray(qpos) // TB),
+                             pos)
+    _close(got, want, jnp.float32)
+
+
+@pytest.mark.parametrize("g", [1, 2])
+def test_a_lane_that_is_not_alive_reads_nothing(forced_on, small_tiles, g):
+    """A stopped lane's whole row NaN: its rows come out zero, and the
+    alive lanes' what the einsum body gives on the clean slab."""
+    layer, q, ck, cv, pos = _operands(g, jnp.float32, 1, [300, 40, 130],
+                                      slots=3)
+    alive = jnp.asarray([True, False, True])
+    helpers.disable_helper("slab_attention")
+    try:
+        want = layer._slab_attend(q, ck, cv, pos)
+    finally:
+        helpers.enable_helper("slab_attention")
+    got = layer._slab_attend(q, *_poisoned(ck, cv, [2, -1, 1]), pos, alive)
+    assert np.all(np.asarray(got[1]) == 0.0)
+    _close(got[::2], want[::2], jnp.float32)
+
+
+def test_a_verify_window_across_a_tile_edge_with_every_lane_counted(
+        forced_on, small_tiles):
+    """C = 4 queries from TB - 2 (the last two in the next tile) and from
+    T - 4, no ``alive``: each slot reads up to its last query's tile, NaN
+    past it."""
+    layer, q, ck, cv, pos = _operands(2, jnp.float32, 4, [TB - 2, T - 4])
+    helpers.disable_helper("slab_attention")
+    try:
+        want = layer._slab_attend(q, ck, cv, pos)
+    finally:
+        helpers.enable_helper("slab_attention")
+    got = layer._slab_attend(q, *_poisoned(ck, cv, [1, 2]), pos)
+    _close(got, want, jnp.float32)
+
+
+def test_live_tiles_and_the_work_list_are_what_the_kernel_reads():
+    """A slot's last tile (-1: none), and the grid's steps made of them:
+    each slot's live tiles in turn, then the last live step repeated (no
+    live step at all: slot B - 1's first tile, never worked)."""
+    qpos = jnp.asarray([[0], [127], [128], [383], [500]], jnp.int32)
+    alive = jnp.asarray([True, True, False, True, True])
+    last = sa.live_tiles(qpos, alive, 128, 3)
+    np.testing.assert_array_equal(last, [0, 0, -1, 2, 2])
+    np.testing.assert_array_equal(sa.live_tiles(qpos, None, 256, 2),
+                                  [0, 0, 0, 1, 1])
+    slot, tile, n = sa.work_list(last, 15)
+    assert n.tolist() == [8]
+    np.testing.assert_array_equal(
+        slot, [0, 1, 3, 3, 3, 4, 4, 4] + [4] * 7)
+    np.testing.assert_array_equal(
+        tile, [0, 0, 0, 1, 2, 0, 1, 2] + [2] * 7)
+    slot, tile, n = sa.work_list(jnp.full(5, -1, jnp.int32), 15)
+    assert n.tolist() == [0]
+    assert set(slot.tolist()) == {4} and set(tile.tolist()) == {0}
 
 
 DECLINES = [
@@ -183,6 +272,18 @@ def _decode(net, prompts, new, mesh=None):
     return [np.asarray(o) for o in out], audit.plans()
 
 
+def _serve(net, waves):
+    """(every request's tokens, the engine's stats) of a 4-slot engine
+    serving ``waves`` of (prompt, new tokens, eos) in turn, blocks of 4."""
+    eng = SlotGenerationEngine(net, num_slots=4, block_size=4, seed=0)
+    out = []
+    for wave in waves:
+        reqs = [eng.submit(p, n, eos_id=e) for p, n, e in wave]
+        eng.run_until_drained()
+        out += [r.result(0) for r in reqs]
+    return out, eng.stats()
+
+
 @pytest.mark.parametrize("heads,g", [(2, 2), (4, 4), (1, 1)])
 def test_decode_block_tokens_with_the_helper_and_without(forced_on,
                                                          small_tiles, heads,
@@ -205,6 +306,79 @@ def test_decode_block_tokens_with_the_helper_and_without(forced_on,
     for a, b, want in zip(plain, streamed, refs):
         np.testing.assert_array_equal(a, b)
         np.testing.assert_array_equal(b, want)
+    # an engine: the long prompt's request stops on its eos in the first
+    # block's second step (a lane stopped mid-block), the short ones free
+    # their slots while it decodes and those keep their stale positions;
+    # a second wave lands in the freed slots
+    gen = refs[1][len(prompts[1]):]
+    eos = int(gen[1])
+    assert eos not in gen[:1]
+    waves = [[(prompts[0], 3, None), (prompts[1], 9, eos),
+              (prompts[2], 9, None)],
+             [(prompts[2], 6, None), (prompts[0], 9, None)]]
+    helpers.disable_helper("slab_attention")
+    try:
+        plain, plain_stats = _serve(net, waves)
+    finally:
+        helpers.enable_helper("slab_attention")
+    streamed, stats = _serve(net, waves)
+    for a, b in zip(plain, streamed):
+        np.testing.assert_array_equal(a, b)
+    np.testing.assert_array_equal(streamed[1], refs[1][:len(prompts[1]) + 2])
+    np.testing.assert_array_equal(streamed[2], refs[2])
+    assert plain_stats["slab_positions_read"] == \
+        plain_stats["slab_positions_held"] == stats["slab_positions_held"]
+    assert 0 < stats["slab_positions_read"] < stats["slab_positions_held"]
+
+
+def test_the_counters_are_the_positions_the_kernel_reads(forced_on,
+                                                         small_tiles):
+    """One block of four steps over four slots of T = 384 (tiles of 128):
+    an alive lane at 126 crosses into its second tile at the third step,
+    one at 300 reads three tiles a step, two stopped lanes read nothing —
+    2304 positions a layer of the 4 × 4 × 384 held; the einsum body reads
+    what is held."""
+    net = _tiny_lm()
+    tokens = np.ones((4, 8), np.int32)
+    lengths = np.full(4, 8, np.int32)
+    positions = np.asarray([126, 5, 300, 200], np.int32)
+    stopped = np.asarray([False, True, False, True])
+    layers = 2
+    held = 4 * 4 * T * layers
+    for on, read in ((True, (128 + 128 + 256 + 256 + 4 * 384) * layers),
+                     (False, held)):
+        dec = TransformerDecoder(net)       # a program traced anew
+        assert dec.counter_names == ("slab_positions_read",
+                                     "slab_positions_held")
+        (helpers.enable_helper if on else helpers.disable_helper)(
+            "slab_attention")
+        try:
+            nxt, _, caches = dec.prefill(dec.init_cache(4), tokens, lengths)
+            toks = dec.decode_block(caches, nxt, positions, stopped=stopped,
+                                    block_size=4)[0]
+        finally:
+            helpers.enable_helper("slab_attention")
+        arr, counts = dec.split_block(np.asarray(toks))
+        assert arr.shape == (4, 4)
+        assert counts.tolist() == [read, held]
+
+
+def test_an_engine_counts_what_its_blocks_read(forced_on, small_tiles):
+    """A 126-token prompt on one of four lanes, five new tokens: the one
+    retired block's steps sit at 126-129 (tiles 0, 0, 1, 1 of 128), the
+    three free lanes read nothing; ``stats()`` and the gauge say so."""
+    net = _tiny_lm()
+    eng = SlotGenerationEngine(net, num_slots=4, block_size=4, seed=0)
+    req = eng.submit(np.arange(126) % 12, 5)
+    eng.run_until_drained()
+    assert len(req.result(0)) == 131
+    st = eng.stats()
+    layers = 2
+    assert st["slab_positions_held"] == 4 * 4 * T * layers
+    assert st["slab_positions_read"] == (128 + 128 + 256 + 256) * layers
+    share = eng._registry.get("generation_slab_read_share").labels(
+        eng.engine_id).value
+    assert share == pytest.approx(768 / (4 * 4 * T))
 
 
 def test_verify_window_tokens_with_the_helper(forced_on, small_tiles):
